@@ -59,8 +59,7 @@ def main():
 
     import jax
     if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        # some accelerator plugins rewrite JAX_PLATFORMS at startup; the
-        # config override makes the documented CPU-rig invocation stick
+        # also covers a JAX imported before this script set the variable
         jax.config.update("jax_platforms", "cpu")
     import mxnet_tpu as mx
 

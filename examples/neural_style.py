@@ -8,8 +8,8 @@ gradient descent runs on the *image pixels* — ``x.attach_grad()`` +
 the training APIs never exercise.
 
 Fixed random conv features stand in for VGG (random-feature style
-statistics are a known-good approximation, and this rig has no
-pretrained-download egress); the gate checks the optimization moved the
+statistics are a known-good approximation, and the example needs no
+pretrained download); the gate checks the optimization moved the
 image's Gram statistics decisively toward the style target while
 keeping content correlation.
 

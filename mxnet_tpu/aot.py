@@ -1,34 +1,26 @@
 """AOT warm starts: serialized executables so restarts skip compilation.
 
-Two independent layers, both fenced against the known jax bug where a
-**deserialized multi-device executable mis-executes** on this jax/XLA
-version (root-caused in PR 2: collective-bearing CPU executables loaded
-from the persistent compile cache intermittently compute wrong results
-— diffs ~2.0 with a warm cache, zero with a cold one):
+**Executable cache** (``MXNET_TPU_COMPILE_CACHE=<dir>``): the fused
+train step and the executor forward serialize their compiled executables
+(``jax.experimental.serialize_executable``) keyed on the framework-level
+program signature — symbol JSON, bound shapes/dtypes, optimizer statics,
+compile-affecting knobs, and the jax/device fingerprint — so a restarted
+``fit``/``serve`` process skips trace AND lower AND backend-compile for
+warm programs (``aot_hit``; the CI ``compile-time`` job asserts a warm
+second process records zero backend-compile phases for the fused step in
+the obs compile accounting). Single-device programs only
+(``aot_skip_multidevice``), and only after :func:`supported` proves a
+serialize → deserialize → execute → compare round-trip on this backend
+(``aot_unsupported``).
 
-1. **Executable cache** (``MXNET_TPU_COMPILE_CACHE=<dir>``): the fused
-   train step and the executor forward serialize their compiled
-   executables (``jax.experimental.serialize_executable``) keyed on the
-   framework-level program signature — symbol JSON, bound
-   shapes/dtypes, optimizer statics, compile-affecting knobs, and the
-   jax/device fingerprint — so a restarted ``fit``/``serve`` process
-   skips trace AND lower AND backend-compile for warm programs
-   (``aot_hit``; the CI ``compile-time`` job asserts a warm second
-   process records zero backend-compile phases for the fused step in
-   the obs compile accounting). Single-device programs only
-   (``aot_skip_multidevice``), and only after :func:`supported` proves
-   a serialize → deserialize → execute → compare round-trip on this
-   backend (``aot_unsupported``).
-
-2. **Persistent-cache fence** (:func:`install_persistent_cache_fence`):
-   jax's own persistent compile cache (``MXNET_COMPILATION_CACHE_DIR``,
-   ``tests/.jax_cache``) gets a root-cause fence instead of the old
-   conftest module-name exclusion: the cache get/put entry points skip
-   any executable whose ``num_replicas * num_partitions > 1``
-   (``compile_cache_fence_skip``), so multi-device programs always
-   compile fresh while single-device programs keep warm starts
-   everywhere. Fail-closed: anything unexpected about the compile
-   options skips the cache (a fresh compile is always correct).
+JAX's own persistent compile cache is separate and always on: its
+directory is chosen in ``config._apply_import_knobs``
+(``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``). It
+caches multi-device programs too — the warm-cache mis-execution of
+collective-bearing CPU executables that an earlier jax needed a fence
+for does not reproduce on jax 0.9.0
+(``tests/test_pipeline_module.py::test_1f1b_matches_gpipe_one_step``,
+warm cache, both ``jit_step`` programs read from it, 10 of 10 runs pass).
 
 Layout: one ``<name>-<sha256>.aotx`` pickle per executable (payload +
 pytree defs + fingerprint), written atomically (`checkpoint.atomic`) so
@@ -41,16 +33,13 @@ import hashlib
 import logging
 import os
 import pickle
-import threading
 from typing import Any, Callable, Iterable, Optional
 
-from . import lockcheck as _lockcheck
 from . import profiler as _profiler
 
 __all__ = [
     "enabled", "supported", "fingerprint", "digest", "load", "store",
-    "load_or_compile", "install_persistent_cache_fence",
-    "config_store_dir",
+    "load_or_compile", "config_store_dir",
 ]
 
 log = logging.getLogger(__name__)
@@ -224,116 +213,19 @@ def load_or_compile(name: str, key: str, jitted, *args):
     """The warm-start recipe the executor forward and fused step
     hand-roll, as one call: return the cached executable for
     ``(name, key)`` when present, else seed the cache — lower + compile
-    ``jitted`` on ``args`` with jax's persistent compile cache bypassed
-    (a cache-loaded executable serializes to an unloadable payload) and
-    ``store`` the result.
+    ``jitted`` on ``args`` and ``store`` the result (a no-op when the
+    cache is off or unsupported; an executable jax served from its own
+    persistent compile cache serializes to an unloadable payload, which
+    ``store``'s verify refuses).
 
     Returns ``(compiled, hit)``. Callers keep the first post-``load``
     invocation on COPIES of donated buffers (a bad cache entry must not
-    invalidate live state — the ``_fused`` discipline). When the cache is
-    off/unsupported the compile still happens (without the bypass), so
-    the caller always gets an executable.
+    invalidate live state — the ``_fused`` discipline). The caller
+    always gets an executable.
     """
     loaded = load(name, key)
     if loaded is not None:
         return loaded, True
-    if enabled() is not None and supported():
-        with bypass_persistent_cache():
-            compiled = jitted.lower(*args).compile()
-        store(name, key, compiled)
-    else:
-        compiled = jitted.lower(*args).compile()
+    compiled = jitted.lower(*args).compile()
+    store(name, key, compiled)
     return compiled, False
-
-
-# ------------------------------------------------- persistent-cache fence
-
-_fence_lock = _lockcheck.Lock(name="aot.fence_lock")
-_fence_installed = False
-_tls = threading.local()
-
-
-class bypass_persistent_cache:
-    """Compile fresh, ignoring jax's persistent compile cache, on this
-    thread. The AOT store path needs this: an executable jax loaded from
-    its persistent cache serializes to a payload without kernel symbols
-    (unloadable), so the one compile that seeds the executable cache
-    must be a real backend compile. Requires the fence (best-effort
-    installed on entry); without it the bypass is a no-op and
-    ``store()``'s deserialize-verify refuses the bad payload instead."""
-
-    def __enter__(self):
-        install_persistent_cache_fence()
-        _tls.bypass = True
-        return self
-
-    def __exit__(self, *exc):
-        _tls.bypass = False
-        return False
-
-
-def install_persistent_cache_fence() -> bool:
-    """Fence jax's persistent compile cache to single-device executables.
-
-    Root cause (PR 2): on this jax/XLA version a deserialized
-    multi-device (collective-bearing) CPU executable intermittently
-    mis-executes; the conftest used to exclude whole test modules from
-    the cache by NAME. This fence moves the exclusion to the actual
-    hazard: the cache's get/put entry points skip any program whose
-    compile options say ``num_replicas * num_partitions > 1``
-    (``compile_cache_fence_skip``), and anything unexpected about the
-    options **fails closed** (skip the cache — a fresh compile is
-    always correct). Idempotent; returns False when the jax internals
-    drifted past the capability probe (callers should then disable the
-    persistent cache wholesale)."""
-    global _fence_installed
-    with _fence_lock:
-        if _fence_installed:
-            return True
-        try:
-            from jax._src import compilation_cache as cc
-            orig_get = cc.get_executable_and_time
-            orig_put = cc.put_executable_and_time
-            if not callable(orig_get) or not callable(orig_put):
-                raise TypeError("compilation_cache API drifted")
-        except Exception:                                   # noqa: BLE001
-            log.warning("persistent-cache fence: jax internals drifted; "
-                        "NOT installed — disable the persistent cache "
-                        "for multi-device work")
-            return False
-
-        def _multi(compile_options) -> bool:
-            try:
-                ebo = compile_options.executable_build_options
-                return int(ebo.num_replicas) * int(ebo.num_partitions) > 1
-            except Exception:                               # noqa: BLE001
-                return True        # fail closed: treat as multi-device
-
-        def fenced_get(cache_key, compile_options, backend):
-            if getattr(_tls, "bypass", False):
-                return None, None     # AOT seeding compile: stay fresh
-            if _multi(compile_options):
-                _profiler.incr_counter("compile_cache_fence_skip")
-                return None, None
-            return orig_get(cache_key, compile_options, backend)
-
-        def fenced_put(cache_key, module_name, executable, backend,
-                       compile_time):
-            # the get fence is the correctness fence (nothing skipped
-            # here is ever loaded); skipping the put as well keeps the
-            # cache free of unusable multi-device entries
-            try:
-                multi = int(getattr(executable, "num_replicas", 1)) * \
-                    int(getattr(executable, "num_partitions", 1)) > 1
-            except Exception:                               # noqa: BLE001
-                multi = True
-            if multi:
-                _profiler.incr_counter("compile_cache_fence_skip")
-                return None
-            return orig_put(cache_key, module_name, executable, backend,
-                            compile_time)
-
-        cc.get_executable_and_time = fenced_get
-        cc.put_executable_and_time = fenced_put
-        _fence_installed = True
-        return True

@@ -862,7 +862,7 @@ def read_checkpoint(path: str, verify: bool = True, mesh=None,
     except Exception as exc:                               # noqa: BLE001
         # KeyError/TypeError from a bit-rotted tensor table (JSON that
         # still parses but references arrays that don't exist) must stay
-        # inside the CheckpointCorrupt taxonomy or load_latest's
+        # inside the CheckpointCorrupt hierarchy or load_latest's
         # fallback chain breaks
         raise CheckpointCorrupt("%s: corrupt tensor table: %r"
                                 % (path, exc)) from None

@@ -18,9 +18,6 @@ wired to a real control point (not parity theater):
   forward under ``jax.checkpoint``: trades recompute FLOPs for activation
   HBM (the TPU form of the reference's memory-saving exec knobs,
   MXNET_EXEC_ENABLE_INPLACE / bulk-exec family).
-* ``MXNET_COMPILATION_CACHE_DIR`` — persistent XLA compile cache
-  directory (reference: MXNET_CUDNN_AUTOTUNE et al. cache compiled
-  choices across runs).
 * ``MXNET_PROFILER_AUTOSTART`` — start the profiler at import
   (reference: same knob).
 * ``MXNET_KVSTORE_HEARTBEAT_STALE_SECS`` — seconds without a heartbeat
@@ -81,8 +78,6 @@ register("MXNET_PREFETCH_BUFFER", int, 4,
 register("MXNET_EXEC_ENABLE_REMAT", _parse_bool, False,
          "jax.checkpoint the fused train step's forward (less HBM, more "
          "FLOPs)")
-register("MXNET_COMPILATION_CACHE_DIR", str, "",
-         "persistent XLA compile cache directory")
 register("MXNET_PROFILER_AUTOSTART", _parse_bool, False,
          "start mx.profiler at import")
 register("MXNET_KVSTORE_HEARTBEAT_STALE_SECS", float, 20.0,
@@ -362,12 +357,15 @@ def _parse_scan_layers(v) -> str:
         "got %r" % (v,))
 
 
-register("MXNET_TPU_SCAN_LAYERS", _parse_scan_layers, "auto",
+register("MXNET_TPU_SCAN_LAYERS", _parse_scan_layers, "off",
          "scan-over-layers: lower repeated homogeneous blocks "
          "(transformer layers) through jax.lax.scan so trace/compile "
          "time stops growing with depth; auto = chains of >= 4 verified-"
          "isomorphic blocks, an integer overrides that minimum, off = "
-         "always unroll (the scan module is never imported)")
+         "always unroll (the scan module is never imported). Off by "
+         "default: the scanned step holds a stacked copy of the "
+         "per-layer parameters and of their gradients, and the 0.67B "
+         "LM's does not fit a 16 GB chip (PERF.md, PR 21)")
 register("MXNET_TPU_GROUP_UPDATE", _parse_bool, True,
          "with a scan plan bound, trace the fused optimizer update as "
          "ONE vmapped body per per-layer parameter family (stacked "
@@ -550,16 +548,26 @@ def describe() -> str:
     return "\n".join(lines)
 
 
+# where JAX's persistent compile cache lives when the environment does not
+# place it: a fixed path beside the package (the path is part of the cache
+# key, so a directory that moves between runs never hits)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
 def _apply_import_knobs() -> None:
-    """Knobs that act once at package import."""
-    cache_dir = get("MXNET_COMPILATION_CACHE_DIR")
-    if cache_dir:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        # HLO only: the AOT kernel cache embeds exact host CPU features
-        # and spews loader errors when they drift
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
+    """Settings that act once at package import.
+
+    The one place the program chooses a compile-cache directory: JAX's
+    own ``JAX_COMPILATION_CACHE_DIR`` wins when it is in the environment
+    (nothing here touches the setting then); otherwise the cache goes to
+    :data:`DEFAULT_COMPILE_CACHE_DIR`."""
+    import jax
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        jax.config.update("jax_compilation_cache_dir",
+                          DEFAULT_COMPILE_CACHE_DIR)
+    # cache every program: a step is many small compiles, not one big one
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     if get("MXNET_PROFILER_AUTOSTART"):
         from . import profiler
         profiler.set_state("run")

@@ -5,7 +5,8 @@ stack, ``cpu()``/``gpu()`` constructors). The TPU build maps a Context onto a
 concrete ``jax.Device``:
 
 * ``cpu(i)``  -> i-th host (CPU) device
-* ``tpu(i)``  -> i-th accelerator device (TPU on real hardware)
+* ``tpu(i)``  -> i-th accelerator device; an error when the default backend
+  is the CPU (an accelerator context never resolves to a host device)
 * ``gpu(i)``  -> alias of ``tpu(i)`` so reference-era scripts that say
   ``mx.gpu(0)`` run unchanged on TPU.
 
@@ -18,6 +19,8 @@ import threading
 from typing import Optional
 
 import jax
+
+from .base import MXNetError
 
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_devices"]
 
@@ -55,16 +58,12 @@ class Context:
         # other hosts' non-addressable devices
         if self.device_type == "cpu":
             return jax.local_devices(backend="cpu")[self.device_id]
-        # accelerator: prefer the default backend's devices when it is not CPU
         devs = jax.local_devices()
-        if devs and devs[0].platform != "cpu":
-            return devs[self.device_id]
-        # No accelerator present (pure-CPU test run): fall back to host devices
-        # so tpu(i) still resolves — mirrors the reference test trick of running
-        # "multi-device" suites on cpu(0)/cpu(1) (tests/python/unittest/
-        # test_multi_device_exec.py, SURVEY.md §4).
-        cpus = jax.local_devices(backend="cpu")
-        return cpus[self.device_id % len(cpus)]
+        if devs[0].platform == "cpu":
+            raise MXNetError(
+                "%r: no accelerator — the default JAX backend is %r; use "
+                "mx.cpu(i) to run on the host" % (self, devs[0].platform))
+        return devs[self.device_id]
 
     def __eq__(self, other):
         return (
@@ -116,13 +115,8 @@ def current_context() -> Context:
 
 def num_devices(device_type: str = "tpu") -> int:
     """Number of visible devices of a type — replaces the reference's
-    mx.context.num_gpus()."""
-    try:
-        if device_type == "cpu":
-            return len(jax.local_devices(backend="cpu"))
-        devs = jax.local_devices()
-        if devs and devs[0].platform != "cpu":
-            return len(devs)
-        return 0
-    except RuntimeError:
-        return 0
+    mx.context.num_gpus(). A backend that fails to initialize raises."""
+    if device_type == "cpu":
+        return len(jax.local_devices(backend="cpu"))
+    devs = jax.local_devices()
+    return len(devs) if devs[0].platform != "cpu" else 0

@@ -42,18 +42,24 @@ from .obs import compiles as _obs_compiles
 __all__ = ["Executor", "graph_function"]
 
 
-def _accepts_is_train(op) -> bool:
-    cached = getattr(op, "_accepts_is_train", None)
+_IMPLICIT_ATTRS = ("_is_train", "_batch_rows")
+
+
+def _implicit_attrs(op) -> frozenset:
+    """The implicit attributes ``op.fn`` declares as parameters."""
+    cached = getattr(op, "_implicit_attrs", None)
     if cached is None:
         try:
-            cached = "_is_train" in inspect.signature(op.fn).parameters
+            params = inspect.signature(op.fn).parameters
         except (TypeError, ValueError):
-            cached = False
-        op._accepts_is_train = cached
+            params = ()
+        cached = frozenset(a for a in _IMPLICIT_ATTRS if a in params)
+        op._implicit_attrs = cached
     return cached
 
 
-def graph_function(symbol, node_device=None, scan_plan=None):
+def graph_function(symbol, node_device=None, scan_plan=None,
+                   batch_rows=None):
     """Compile a Symbol into a pure function
     ``fn(args_dict, aux_dict, rng_key, is_train) -> (outputs, new_aux_dict)``.
 
@@ -76,6 +82,13 @@ def graph_function(symbol, node_device=None, scan_plan=None):
     stacked per-layer parameters instead of unrolled per-layer tracing,
     so trace time and HLO size stop growing with depth
     (docs/architecture/program_model.md, compile-time control).
+
+    ``batch_rows`` (optional): ``(mesh, axes)`` — the mesh the graph's
+    values are placed on and the axes the batch dimension is sharded over
+    (``()`` when the batch is replicated). GSPMD partitions ordinary ops
+    from the operand shardings alone; the one kind of op it cannot see
+    into (a Mosaic kernel) declares a ``_batch_rows`` parameter, receives
+    this, and partitions itself.
     """
     from .symbol.symbol import _topo_order
 
@@ -102,7 +115,8 @@ def graph_function(symbol, node_device=None, scan_plan=None):
                 vals[(id(node), 0)] = v
                 return
             ins = [vals[(id(n), i)] for n, i in node.inputs]
-            outs = _run_node(node, ins, key, idx, is_train, node_device)
+            outs = _run_node(node, ins, key, idx, is_train, node_device,
+                             batch_rows)
             for i, o in enumerate(outs):
                 vals[(id(node), i)] = o
             n_aux = node.op.num_aux
@@ -117,7 +131,8 @@ def graph_function(symbol, node_device=None, scan_plan=None):
                 exec_node(node)
             scan_plan.execute(vals, args, key, is_train,
                               lambda node, ins, k, idx, it:
-                              _run_node(node, ins, k, idx, it, None))
+                              _run_node(node, ins, k, idx, it, None,
+                                        batch_rows))
             for node in scan_plan.post_nodes:
                 exec_node(node)
         else:
@@ -129,15 +144,20 @@ def graph_function(symbol, node_device=None, scan_plan=None):
     return fn
 
 
-def _run_node(node, ins, key, idx, is_train, node_device=None):
-    """Execute one graph node: implicit attrs (_is_train, per-node RNG),
-    group2ctx boundary transfer, tuple-normalized outputs. The single
-    definition both graph_function and Executor.monitor_values dispatch
-    through, so monitored values cannot drift from executed values."""
+def _run_node(node, ins, key, idx, is_train, node_device=None,
+              batch_rows=None):
+    """Execute one graph node: implicit attrs (_is_train, _batch_rows,
+    per-node RNG), group2ctx boundary transfer, tuple-normalized outputs.
+    The single definition both graph_function and Executor.monitor_values
+    dispatch through, so monitored values cannot drift from executed
+    values."""
     attrs = dict(node.attrs)
     attrs.pop("name", None)
-    if _accepts_is_train(node.op):
+    implicit = _implicit_attrs(node.op)
+    if "_is_train" in implicit:
         attrs["_is_train"] = is_train
+    if "_batch_rows" in implicit:
+        attrs["_batch_rows"] = batch_rows
     if node.op.needs_rng:
         attrs["_rng"] = jax.random.fold_in(key, idx)
     if node_device is not None:
@@ -168,7 +188,7 @@ class Executor:
 
     def __init__(self, symbol, ctx: Context, args, args_grad=None,
                  grad_req="write", aux_states=None, group2ctx=None,
-                 shared_exec=None):
+                 shared_exec=None, batch_rows=None):
         self._symbol = symbol
         self._ctx = ctx
         self._arg_names = symbol.list_arguments()
@@ -228,8 +248,10 @@ class Executor:
             self._output_names[0] if self._output_names else "?")
         self._remat_name = "off"
         self._scan_plan = self._build_scan_plan(_config)
+        self._batch_rows = batch_rows
         self._fn = graph_function(symbol, self._node_device_fn(),
-                                  scan_plan=self._scan_plan)
+                                  scan_plan=self._scan_plan,
+                                  batch_rows=batch_rows)
         # programs embedding host-callback custom ops must run
         # synchronously with the frontend: async execution + concurrent
         # eager dispatch deadlocks the CPU runtime (the train_rcnn eval
@@ -388,11 +410,8 @@ class Executor:
                 digest = _aot.digest(sig)
                 runner = _aot.load("graph_fwd", digest)
                 if runner is None:
-                    # fresh compile (bypass jax's persistent cache): a
-                    # cache-loaded executable cannot be re-serialized
-                    with _aot.bypass_persistent_cache():
-                        compiled = self._jit_fwd.lower(
-                            arg_vals, aux_vals, key, is_train).compile()
+                    compiled = self._jit_fwd.lower(
+                        arg_vals, aux_vals, key, is_train).compile()
                     _aot.store("graph_fwd", digest, compiled)
                     runner = compiled
             except Exception:                               # noqa: BLE001
@@ -425,7 +444,7 @@ class Executor:
 
     # ------------------------------------------------------------ scan
     def _build_scan_plan(self, _config):
-        """Scan-over-layers (MXNET_TPU_SCAN_LAYERS, default auto): detect
+        """Scan-over-layers (MXNET_TPU_SCAN_LAYERS, default off): detect
         a repeated homogeneous chain and lower it through one
         ``jax.lax.scan`` so bind time stops growing with depth. Detection
         that does not verify falls back to the unrolled path silently;
@@ -680,7 +699,7 @@ class Executor:
                 _nd.NDArray(np.zeros(s, dtype=cur.dtype), ctx=self._ctx)
         return Executor(self._symbol, self._ctx, new_args, new_grads,
                         self._grad_req, new_aux, group2ctx=self._group2ctx,
-                        shared_exec=self)
+                        shared_exec=self, batch_rows=self._batch_rows)
 
     # ------------------------------------------------------------ monitor
     def monitor_values(self):
@@ -706,7 +725,8 @@ class Executor:
                 vals[(id(node), 0)] = src_nd.data
                 continue
             ins = [vals[(id(n), i)] for n, i in node.inputs]
-            outs = _run_node(node, ins, key, idx, is_train, node_device)
+            outs = _run_node(node, ins, key, idx, is_train, node_device,
+                             self._batch_rows)
             for i, o in enumerate(outs):
                 vals[(id(node), i)] = o
                 suffix = "_output" if len(outs) == 1 else "_output%d" % i

@@ -4,7 +4,7 @@
 both fronts speak the same protocol) and hands out the same
 :class:`~mxnet_tpu.serve.server.GenerateHandle` a local
 ``GenerativeServer`` would: iterate it for streaming, ``result()`` for
-the whole sequence, and the serve exception taxonomy (``QueueFull``,
+the whole sequence, and the serve exception hierarchy (``QueueFull``,
 ``DeadlineExceeded``, ``ServerClosed``) re-raises rehydrated from ERR
 frames. Code written against a local server moves behind a fleet by
 changing one constructor.

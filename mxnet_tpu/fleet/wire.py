@@ -88,7 +88,7 @@ def _exc_kind(exc: BaseException) -> str:
 
 
 def kind_to_exc(payload: Dict[str, Any]) -> ServeError:
-    """Rehydrate an ERR frame into the serve exception taxonomy so
+    """Rehydrate an ERR frame into the serve exception hierarchy so
     fleet callers catch the same classes as local serve callers."""
     kind = payload.get("kind", "error")
     msg = str(payload.get("msg", "replica error"))

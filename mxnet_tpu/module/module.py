@@ -342,6 +342,19 @@ class Module(BaseModule):
                 return NamedSharding(self._mesh, spec)
         return replicated_sharding(self._mesh)
 
+    def _batch_rows(self):
+        """``(mesh, axes)`` for ``graph_function(batch_rows=)``: the axes
+        ``_place_value`` shards the batch dimension over."""
+        if self._mesh is None:
+            return None
+        if self._batch_sharding is not None:
+            first = self._batch_sharding.spec[0]
+        else:
+            first = "data" if "data" in self._mesh.axis_names else None
+        if first is None:
+            return self._mesh, ()
+        return self._mesh, first if isinstance(first, tuple) else (first,)
+
     def _replicate_params(self):
         """Place parameters on the mesh: replicated over ``data``, and
         partitioned per param_shardings over ``model`` (replaces per-device
@@ -451,7 +464,7 @@ class Module(BaseModule):
                      self._label_shapes}
         self._exec = self._symbol.simple_bind(
             self._context[0], grad_req=req, type_dict=type_dict,
-            **shape_hints)
+            batch_rows=self._batch_rows(), **shape_hints)
         self.binded = True
 
         if self.params_initialized:
@@ -1310,10 +1323,7 @@ class Module(BaseModule):
                 self._fused_aot_key = None
                 return out
         try:
-            # compile fresh (bypassing jax's persistent cache): a
-            # cache-loaded executable cannot be re-serialized
-            with _aot.bypass_persistent_cache():
-                compiled = self._fused_jit.lower(*call_args).compile()
+            compiled = self._fused_jit.lower(*call_args).compile()
         except Exception:                                   # noqa: BLE001
             # lowering path failed (never expected); keep plain dispatch
             self._fused_aot_key = None

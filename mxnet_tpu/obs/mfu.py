@@ -42,7 +42,7 @@ from .. import config as _config
 from .. import lockcheck as _lockcheck
 from .. import profiler as _profiler
 
-__all__ = ["peak_flops", "register_executor", "collect",
+__all__ = ["peak_flops", "table_peak_flops", "register_executor", "collect",
            "OBS_WARMUP_STEPS", "TRAIN_FLOP_MULTIPLIER"]
 
 # steps skipped before the rate window opens (the compile steps)
@@ -58,7 +58,6 @@ TRAIN_FLOP_MULTIPLIER = 3.0
 PEAK_FLOPS_BY_DEVICE_KIND = [
     ("v5 lite", 197e12), ("v5e", 197e12), ("v5p", 459e12),
     ("v6", 918e12), ("v4", 275e12), ("v3", 123e12), ("v2", 45e12)]
-_PEAK = PEAK_FLOPS_BY_DEVICE_KIND
 
 _reg_lock = _lockcheck.Lock(name="obs.mfu.reg_lock")
 # serializes whole collects: two concurrent collectors (report() + a
@@ -71,6 +70,16 @@ _reg_lock = _lockcheck.Lock(name="obs.mfu.reg_lock")
 # a concurrent scraper at the same process during the timed region.
 _collect_lock = _lockcheck.Lock(name="obs.mfu.collect_lock")
 _executors: List[weakref.ref] = []
+
+
+def table_peak_flops(device_kind: str) -> Optional[float]:
+    """The peaks table alone: None for a device it does not list (which
+    ``bench.py`` and ``chip_smoke.py`` treat as an error)."""
+    dk = device_kind.lower()
+    for sub, peak in PEAK_FLOPS_BY_DEVICE_KIND:
+        if sub in dk:
+            return peak
+    return None
 
 
 def peak_flops(device_kind: Optional[str] = None) -> Optional[float]:
@@ -86,11 +95,7 @@ def peak_flops(device_kind: Optional[str] = None) -> Optional[float]:
             device_kind = jax.devices()[0].device_kind
         except Exception:                                  # noqa: BLE001
             return None
-    dk = (device_kind or "").lower()
-    for sub, peak in _PEAK:
-        if sub in dk:
-            return peak
-    return None
+    return table_peak_flops(device_kind or "")
 
 
 def register_executor(mod) -> None:

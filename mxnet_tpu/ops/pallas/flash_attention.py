@@ -20,9 +20,18 @@ in either direction. ``exp(s - lse)`` needs no running rescale: lse is
 the final statistic, making the backward tiles embarrassingly
 order-independent (unlike the forward's online softmax).
 
+A Mosaic custom call is opaque to the SPMD partitioner (jax refuses to
+lower one under a multi-device jit: "cannot be automatically
+partitioned. Please wrap the call in a shard_map"). So a graph bound to
+a mesh hands the op ``batch_rows`` (``executor.graph_function``) and the
+whole differentiable attention runs inside one ``shard_map`` over the
+batch axes: each batch row is independent, so every chip runs the forward
+and both backward kernels on its own rows.
+
 Off-TPU the same kernel runs in interpreter mode (exact, slow) so the
-CPU test rig can check numerics; ``flash_attention`` falls back to plain
-XLA attention when ``interpret=False`` is forced on a non-TPU backend.
+CPU tests can check numerics; ``interpret=False`` off-TPU is an error.
+The mode is ``rtc.resolve_interpret``'s: the inputs' devices, or under
+tracing ``jax.default_backend()``.
 """
 from __future__ import annotations
 
@@ -36,16 +45,22 @@ from jax import lax
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
-# lse/delta ride as (BH, S, _LANES) with the row value replicated across
-# lanes: Mosaic wants >=2D tiles whose last block dim divides 128 OR equals
-# the array dim — 8 lanes satisfies the latter at 1/16th the HBM of 128
+# Inside the kernels lse/delta ride as (BH, S, _LANES) with the row value
+# replicated across lanes: Mosaic wants >=2D tiles whose last block dim
+# divides 128 OR equals the array dim. In HBM the tiled layout pads that
+# minor dim to 128 lanes whatever _LANES is (16x for 8), so the lane form
+# is only ever a transient around a kernel call: the residual the forward
+# saves for the backward is the (BH, S) column.
 _LANES = 8
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-               *, scale, causal, block_q, block_k, skip_masked):
+               *, scale, causal, block_q, block_k):
     import jax.experimental.pallas as pl
 
+    # program ids are read at the top level only: a pl.when body is a cond
+    # branch, where the interpreter cannot resolve program_id
+    q_step = pl.program_id(1)
     kv_step = pl.program_id(2)
     n_kv = pl.num_programs(2)
 
@@ -56,11 +71,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     # causal: a KV tile strictly above the diagonal band contributes
-    # nothing — skip its matmuls entirely (~2x for long sequences).
-    # Compiled mode only: the HLO interpreter can't lower a traced
-    # pl.when predicate.
-    live = (kv_step * block_k <= (pl.program_id(1) + 1) * block_q - 1) \
-        if (causal and skip_masked) else True
+    # nothing — skip its matmuls entirely (~2x for long sequences)
+    live = (kv_step * block_k <= (q_step + 1) * block_q - 1) \
+        if causal else True
 
     @pl.when(live)
     def _update():
@@ -72,7 +85,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             preferred_element_type=jnp.float32) * scale  # (block_q, block_k)
 
         if causal:
-            q_pos = pl.program_id(1) * block_q + \
+            q_pos = q_step * block_q + \
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_pos = kv_step * block_k + \
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -108,8 +121,7 @@ def _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     nq = S // block_q
     nk = Sk // block_k
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               skip_masked=not interpret)
+                               block_q=block_q, block_k=block_k)
     return pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((BH, S, D), q.dtype),
@@ -134,8 +146,7 @@ def _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret):
 
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dq_scr, *, scale, causal, block_q, block_k,
-                      skip_masked):
+                      dq_ref, dq_scr, *, scale, causal, block_q, block_k):
     """dQ accumulator: grid (BH, nq, nk), KV tiles innermost.
 
     Rebuilds P = exp(s - lse) from the saved logsumexp (exact — lse is the
@@ -144,6 +155,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     """
     import jax.experimental.pallas as pl
 
+    i = pl.program_id(1)
     j = pl.program_id(2)
     n_kv = pl.num_programs(2)
 
@@ -151,8 +163,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    live = (j * block_k <= (pl.program_id(1) + 1) * block_q - 1) \
-        if (causal and skip_masked) else True
+    live = (j * block_k <= (i + 1) * block_q - 1) if causal else True
 
     @pl.when(live)
     def _update():
@@ -164,7 +175,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            q_pos = pl.program_id(1) * block_q + \
+            q_pos = i * block_q + \
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_pos = j * block_k + \
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -185,7 +196,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal,
-                       block_q, block_k, skip_masked):
+                       block_q, block_k):
     """dK/dV accumulator: grid (BH, nk, nq), Q tiles innermost.
 
     dV += Pᵀ·dO and dK += dSᵀ·Q per Q tile; writing per-KV-tile outputs
@@ -193,6 +204,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     """
     import jax.experimental.pallas as pl
 
+    j = pl.program_id(1)
     i = pl.program_id(2)
     n_q = pl.num_programs(2)
 
@@ -202,8 +214,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     # causal: a Q tile entirely above (before) this KV tile sees none of it
-    live = ((i + 1) * block_q - 1 >= pl.program_id(1) * block_k) \
-        if (causal and skip_masked) else True
+    live = ((i + 1) * block_q - 1 >= j * block_k) if causal else True
 
     @pl.when(live)
     def _update():
@@ -217,7 +228,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             q_pos = i * block_q + \
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = pl.program_id(1) * block_k + \
+            k_pos = j * block_k + \
                 jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
         p = jnp.exp(s - lse_ref[0][:, 0:1])              # (bq, bk)
@@ -251,11 +262,11 @@ def _fa_backward(q, k, v, out, lse, do, scale, causal, block_q, block_k,
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                              # (BH, S)
     delta = jnp.broadcast_to(delta[:, :, None], (BH, S, _LANES))
-    common = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, skip_masked=not interpret)
+    lse = jnp.broadcast_to(lse[:, :, None], (BH, S, _LANES))
 
     dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, **common),
+        functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         grid=(BH, nq, nk),
         in_specs=[
@@ -272,7 +283,8 @@ def _fa_backward(q, k, v, out, lse, do, scale, causal, block_q, block_k,
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, **common),
+        functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
         out_shape=(jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, Sk, D), v.dtype)),
         grid=(BH, nk, nq),
@@ -295,31 +307,16 @@ def _fa_backward(q, k, v, out, lse, do, scale, causal, block_q, block_k,
     return dq, dk, dv
 
 
-def _xla_attention(q, k, v, scale, causal):
-    s = jnp.einsum("bqd,bkd->bqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
-    if causal:
-        # top-aligned mask (k <= q in absolute positions) — must agree with
-        # the kernel's q_pos >= k_pos even when q carries block padding,
-        # since this path is also the recompute backward of the kernel
-        S, Sk = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((S, Sk), bool))
-        s = jnp.where(mask, s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _fa(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, _ = _fa_forward(q, k, v, scale, causal, block_q, block_k,
-                         interpret)
-    return out
+    return _fa_forward(q, k, v, scale, causal, block_q, block_k,
+                       interpret)[0]
 
 
 def _fa_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     out, lse = _fa_forward(q, k, v, scale, causal, block_q, block_k,
                            interpret)
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, out, lse[:, :, 0])
 
 
 def _fa_bwd(scale, causal, block_q, block_k, interpret, res, g):
@@ -332,7 +329,7 @@ _fa.defvjp(_fa_fwd, _fa_bwd)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
-                    block_k=512, interpret=None):
+                    block_k=512, interpret=None, batch_rows=None):
     """Flash attention over (B, H, S, D) inputs.
 
     The query length is padded to ``block_q`` (padded rows are computed
@@ -343,8 +340,12 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
     Gradients flow through fused Pallas dQ and dK/dV kernels (the forward
     saves the per-row logsumexp); the S×S matrix never reaches HBM in
     either direction.
+
+    ``batch_rows``: ``(mesh, axes)`` when the inputs live on a mesh with
+    the batch dimension sharded over ``axes`` (module docstring); heads
+    sharded over another axis are gathered first.
     """
-    B, H, S, D = q.shape
+    S, D = q.shape[2], q.shape[3]
     Sk = k.shape[2]
     if scale is None:
         scale = 1.0 / np.sqrt(D)
@@ -352,13 +353,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
     if interpret is None:
         interpret = resolve_interpret((q, k, v))
     elif not interpret and resolve_interpret((q, k, v)):
-        # compiled Mosaic requested but the data is off-TPU: fall back to
-        # plain XLA attention instead of failing to lower
-        out = _xla_attention(q.reshape(B * H, S, D),
-                             k.reshape(B * H, Sk, D),
-                             v.reshape(B * H, Sk, D), float(scale),
-                             bool(causal))
-        return out.reshape(B, H, S, D)
+        raise ValueError(
+            "flash_attention(interpret=False): the compiled Mosaic kernel "
+            "needs a TPU, but the inputs are off-TPU (default backend %r)"
+            % jax.default_backend())
 
     bq = min(block_q, S)
     bk = min(block_k, Sk)
@@ -367,16 +365,27 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
             "flash_attention: key length %d must be a multiple of block_k "
             "%d (padded keys would join the softmax)" % (Sk, bk))
     pad_q = (-S) % bq
-    qf = q.reshape(B * H, S, D)
-    kf = k.reshape(B * H, Sk, D)
-    vf = v.reshape(B * H, Sk, D)
-    if pad_q:
-        qf = jnp.pad(qf, ((0, 0), (0, pad_q), (0, 0)))
-    out = _fa(qf, kf, vf, float(scale), bool(causal), bq, bk,
-              bool(interpret))
-    if pad_q:
-        out = out[:, :S]
-    return out.reshape(B, H, S, D)
+
+    def on_rows(q, k, v):
+        B, H = q.shape[:2]
+        qf = q.reshape(B * H, S, D)
+        kf = k.reshape(B * H, Sk, D)
+        vf = v.reshape(B * H, Sk, D)
+        if pad_q:
+            qf = jnp.pad(qf, ((0, 0), (0, pad_q), (0, 0)))
+        out = _fa(qf, kf, vf, float(scale), bool(causal), bq, bk,
+                  bool(interpret))
+        if pad_q:
+            out = out[:, :S]
+        return out.reshape(B, H, S, D)
+
+    if batch_rows is None:
+        return on_rows(q, k, v)
+    from jax.sharding import PartitionSpec as P
+    mesh, axes = batch_rows
+    spec = P(axes or None)
+    return jax.shard_map(on_rows, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 # registered as an ordinary framework op so Symbol/Gluon graphs can use it
@@ -386,12 +395,12 @@ from ..registry import register as _register  # noqa: E402
 @_register("FlashAttention", num_inputs=3,
            aliases=("_contrib_FlashAttention",))
 def _flash_attention_op(q, k, v, causal=False, scale=None, block_q=512,
-                        block_k=512, interpret=None):
+                        block_k=512, interpret=None, _batch_rows=None):
     """Pallas flash attention over (B, H, S, D) q/k/v (see module
-    docstring; the mx.rtc escape-hatch showcase kernel). Pass
-    ``interpret=True`` when building a CPU-bound symbol graph (tracers
-    carry no device, so auto-detection falls back to the default
-    backend)."""
+    docstring; the mx.rtc escape-hatch showcase kernel). Inside a bound
+    symbol graph the inputs are tracers, so ``interpret=None`` follows
+    ``jax.default_backend()``, and a graph bound to a mesh supplies
+    ``_batch_rows``."""
     return flash_attention(q, k, v, causal=causal, scale=scale,
                            block_q=block_q, block_k=block_k,
-                           interpret=interpret)
+                           interpret=interpret, batch_rows=_batch_rows)

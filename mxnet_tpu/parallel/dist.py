@@ -363,10 +363,7 @@ def _psum_fn(shape, dtype):
     if fn is None:
         import jax
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         mesh, _ = _comm()
         shard = partial(shard_map, mesh=mesh, in_specs=P("proc"),
                         out_specs=P())

@@ -79,11 +79,6 @@ def sharding_island():
 def mesh_devices(contexts: Optional[Sequence[Context]] = None) -> List[jax.Device]:
     if contexts is not None:
         return [c.jax_device for c in contexts]
-    import os
-    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
-        # explicit CPU request (the virtual-mesh test rig, SURVEY.md §4) —
-        # some accelerator plugins register even when JAX_PLATFORMS says cpu
-        return list(jax.devices("cpu"))
     return list(jax.devices())
 
 
@@ -106,15 +101,8 @@ def make_mesh(shape: Optional[Dict[str, int]] = None,
         sizes[sizes.index(-1)] = len(devs) // known
     total = int(np.prod(sizes))
     if total > len(devs):
-        # fall back to the host's virtual CPU devices — the TPU twin of the
-        # reference running multi-device suites on cpu(0)/cpu(1)
-        # (tests/python/unittest/test_multi_device_exec.py, SURVEY.md §4)
-        cpus = list(jax.devices("cpu"))
-        if devices is None and contexts is None and total <= len(cpus):
-            devs = cpus
-        else:
-            raise ValueError("mesh needs %d devices, only %d visible"
-                             % (total, len(devs)))
+        raise ValueError("mesh needs %d devices, only %d visible"
+                         % (total, len(devs)))
     arr = np.array(devs[:total]).reshape(sizes)
     return Mesh(arr, tuple(names))
 

@@ -111,7 +111,7 @@ def pipeline_apply(stage_fn, stage_params, inputs, *, mesh, axis=None,
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     axis = _resolve_axis(mesh, axis)
     n_stages = mesh.shape[axis]
@@ -227,7 +227,7 @@ def pipeline_apply(stage_fn, stage_params, inputs, *, mesh, axis=None,
     fn = shard_map(spmd, mesh=mesh,
                    in_specs=(param_spec, rep(first_params),
                              rep(last_params), P()),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     return fn(stage_params, first_params, last_params, inputs)
 
 
@@ -279,7 +279,7 @@ def pipeline_1f1b(stage_fns, stage_params, inputs, *, mesh, axis=None,
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     axis = _resolve_axis(mesh, axis)
     N = mesh.shape[axis]
@@ -511,7 +511,7 @@ def pipeline_1f1b(stage_fns, stage_params, inputs, *, mesh, axis=None,
                              P(), P()),
                    out_specs=(P(), rep(first_params), stage_out_spec[0],
                               rep(last_params), stage_out_spec[1]),
-                   check_rep=False)
+                   check_vma=False)
     outs, gF, gS, gL, new_aux = fn(stage_params, stage_aux,
                                    first_params, last_params,
                                    inputs, key)

@@ -19,10 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["ring_attention", "ring_self_attention",
            "local_attention_block", "chunked_causal_attention",

@@ -10,7 +10,11 @@ as a single ``lax.scan`` whose xs are the per-layer parameters stacked
 on a leading axis: the block traces and compiles once, whatever the
 depth.
 
-Detection is structural (``MXNET_TPU_SCAN_LAYERS``, default ``auto``):
+Off by default (``MXNET_TPU_SCAN_LAYERS=off``): the scanned step holds a
+stacked copy of the per-layer parameters and of their gradients beside
+the originals, and at 0.67B parameters that does not fit a 16 GB chip
+(PERF.md, PR 21). With ``auto`` or a minimum repeat count, detection is
+structural:
 
 1. **Layer families** from parameter names: the framework auto-names
    per-layer parameters with the layer index embedded

@@ -417,9 +417,11 @@ class Symbol:
                         group2ctx=group2ctx, shared_exec=shared_exec)
 
     def simple_bind(self, ctx, grad_req="write", type_dict=None,
-                    group2ctx=None, shared_exec=None, **kwargs):
+                    group2ctx=None, shared_exec=None, batch_rows=None,
+                    **kwargs):
         """(reference: symbol.py:1266 → 40-arg MXExecutorSimpleBind; here:
-        infer shapes, allocate args/grads/aux, construct the Executor)."""
+        infer shapes, allocate args/grads/aux, construct the Executor).
+        ``batch_rows``: see ``executor.graph_function``."""
         from .. import ndarray as nd
         from ..executor import Executor
         arg_shapes, _, aux_shapes = self.infer_shape(**kwargs)
@@ -441,7 +443,8 @@ class Symbol:
             args_grad = {n: nd.NDArray(np.zeros(s, dtype=np.float32), ctx=ctx)
                          for n, s in zip(arg_names, arg_shapes)}
         return Executor(self, ctx, args, args_grad, grad_req, aux,
-                        group2ctx=group2ctx, shared_exec=shared_exec)
+                        group2ctx=group2ctx, shared_exec=shared_exec,
+                        batch_rows=batch_rows)
 
     # attached op methods (sum, reshape, ...) installed by _attach_methods()
 

@@ -115,7 +115,7 @@ def static_rank(sym, input_shapes: Dict[str, tuple],
         score = (comm_rank,
                  0 if cand.remat == "off" else 1,
                  cand.grad_accum,
-                 0 if cand.scan_layers == "auto" else 1,
+                 0 if cand.scan_layers == "off" else 1,
                  0 if cand.group_update else 1,
                  0 if cand.async_window else 1,
                  cand.order_key())

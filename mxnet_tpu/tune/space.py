@@ -27,7 +27,7 @@ class Candidate:
     raises TypeError, exactly when candidates tie on a score prefix."""
     remat: str = "off"            # off | auto | a checkpoint-policy name
     grad_accum: int = 1
-    scan_layers: str = "auto"     # off | auto
+    scan_layers: str = "off"      # off | auto
     group_update: bool = True
     async_window: int = 2
     layout: Optional[Tuple[int, int, int]] = None   # (data, fsdp, tp)
@@ -65,7 +65,7 @@ class Candidate:
         lay = d.get("layout")
         return cls(remat=str(d.get("remat", "off")),
                    grad_accum=int(d.get("grad_accum", 1)),
-                   scan_layers=str(d.get("scan_layers", "auto")),
+                   scan_layers=str(d.get("scan_layers", "off")),
                    group_update=bool(d.get("group_update", True)),
                    async_window=int(d.get("async_window", 2)),
                    layout=tuple(int(x) for x in lay) if lay else None)
@@ -92,7 +92,7 @@ def enumerate_space(batch_size: int, n_devices: int = 1,
     for lay in lays:
         for remat in remat_policies:
             for accum in accums:
-                for scan in ("auto", "off"):
+                for scan in ("off", "auto"):
                     for group in (True, False):
                         for window in (2, 0):
                             c = Candidate(

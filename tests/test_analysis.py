@@ -284,16 +284,14 @@ def test_compile_cache_sigs_differ_for_closure_constants():
 
 
 def test_f64_promotion_detected_under_x64():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         r = analyze_program(lambda x: x * np.float64(3.0),
                             jnp.ones((4,), jnp.float32))
     assert codes(r, "f64-promotion")
 
 
 def test_f64_all_f64_is_intentional():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         r = analyze_program(lambda x: x * np.float64(3.0),
                             jnp.ones((4,), jnp.float64))
     assert not codes(r, "f64-promotion")

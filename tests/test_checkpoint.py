@@ -311,8 +311,8 @@ def test_resume_payload_preserves_dtype(tmp_path):
     assert nd_args["w16"].dtype == np.float16
     # f64 models only exist under x64 (jax stores f32 otherwise), so the
     # f64 leg of the round-trip is asserted there
-    from jax.experimental import enable_x64
-    with enable_x64():
+    import jax
+    with jax.enable_x64():
         nd64 = ck.arg_params_nd()["w64"]
         assert nd64.dtype == np.float64
         np.testing.assert_array_equal(nd64.asnumpy(), t["arg:w64"])

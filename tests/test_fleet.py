@@ -2,7 +2,7 @@
 (ISSUE 20 tentpole).
 
 The contract under test: the wire round-trips the serve API (streaming
-tokens + the exception taxonomy) over real sockets; the gateway routes
+tokens + the exception hierarchy) over real sockets; the gateway routes
 least-loaded and keeps sequences sticky; a replica death mid-stream
 fails over with an EXACT at-most-once continuation (the scripted
 decoder's pure-autoregressive token function makes bit-equality

@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 import jax
-from jax import experimental as jax_experimental
 import jax.numpy as jnp
 
 from mxnet_tpu.parallel import make_mesh
@@ -31,7 +30,7 @@ def _dense_oracle(params, x, k=2):
 
 
 def test_moe_matches_dense_oracle_f64():
-    with jax_experimental.enable_x64():
+    with jax.enable_x64():
         rng = np.random.RandomState(0)
         T, D, H, E = 64, 16, 32, 8
         params = moe_init(rng, D, H, E, dtype=np.float64)
@@ -44,7 +43,7 @@ def test_moe_matches_dense_oracle_f64():
 
 
 def test_moe_capacity_drops_tokens():
-    with jax_experimental.enable_x64():
+    with jax.enable_x64():
         rng = np.random.RandomState(1)
         T, D, H, E = 32, 8, 16, 4
         params = moe_init(rng, D, H, E, dtype=np.float64)
@@ -62,7 +61,7 @@ def test_moe_capacity_drops_tokens():
 
 def test_moe_sharded_matches_unsharded():
     mesh = make_mesh({"expert": 8})
-    with jax_experimental.enable_x64():
+    with jax.enable_x64():
         rng = np.random.RandomState(2)
         T, D, H, E = 64, 16, 32, 8
         params = moe_init(rng, D, H, E, dtype=np.float64)

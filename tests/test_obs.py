@@ -654,18 +654,26 @@ def test_bench_merge_carries_per_section_bind_and_obs():
             os.path.abspath(__file__))), "bench.py"))
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
+    dev = {"platform": "tpu", "device_kind": "TPU v5 lite",
+           "device_count": 1}
     merged = bench._merge({
-        "resnet": {"section": "resnet", "value": 100.0, "mfu": 0.3,
-                   "bind_secs": 12.5, "obs_mfu": 0.29,
-                   "obs_bind_ms_total": 12500},
-        "transformer": {"section": "transformer", "transformer_mfu": 0.62,
-                        "bind_secs": 30.1, "obs_mfu": 0.60,
-                        "obs_bind_ms_total": 30100},
+        "resnet": dict(dev, section="resnet", value=100.0, mfu=0.3,
+                       bind_secs=12.5, obs_mfu=0.29,
+                       obs_bind_ms_total=12500),
+        "transformer": dict(dev, section="transformer",
+                            transformer_mfu=0.62, bind_secs=30.1,
+                            obs_mfu=0.60, obs_bind_ms_total=30100),
     })
     assert merged["bind_secs"] == {"resnet": 12.5, "transformer": 30.1}
     assert merged["obs_mfu"] == {"resnet": 0.29, "transformer": 0.60}
     assert merged["obs_bind_ms_total"]["transformer"] == 30100
     assert merged["mfu"] == 0.3 and merged["transformer_mfu"] == 0.62
-    # a wedged section surfaces as an error, not silence
-    merged2 = bench._merge({"resnet": {"error": "timeout after 600s"}})
+    # every record names the device it was taken on
+    assert {k: merged[k] for k in dev} == dev
+    assert "errors" not in merged
+    # a failed section surfaces as an error (and a non-zero exit), never
+    # as a record
+    merged2 = bench._merge({"resnet": {"section": "resnet",
+                                       "error": "timeout after 600s"}})
     assert merged2["errors"]["resnet"].startswith("timeout")
+    assert merged2["value"] is None and merged2["platform"] is None

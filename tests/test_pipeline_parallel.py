@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 import jax
-from jax import experimental as jax_experimental
 import jax.numpy as jnp
 
 from mxnet_tpu.parallel import make_mesh, pipeline_apply, stack_stage_params
@@ -51,7 +50,7 @@ def test_pipeline_gradients_match_sequential_f64():
     # float64 removes scan-order rounding: forward AND backward must be
     # bit-tight vs the sequential program
     mesh = make_mesh({"pipe": N_STAGES})
-    with jax_experimental.enable_x64():
+    with jax.enable_x64():
         stages, x = _setup(dtype=np.float64, n_micro=6, mb=2, dim=8)
         stacked = stack_stage_params(stages)
 

@@ -38,7 +38,7 @@ def _bind_pair(net, data_shapes, label_shapes=None, seed=3):
                 kw.update(label_shapes)
             executors.append(net.simple_bind(mx.cpu(), **kw))
         finally:
-            mx.config.set("MXNET_TPU_SCAN_LAYERS", "auto")
+            mx.config.reset("MXNET_TPU_SCAN_LAYERS")
     ex0, ex1 = executors
     rs = np.random.RandomState(seed)
     for n, a in ex0.arg_dict.items():
@@ -110,16 +110,18 @@ def test_internal_output_consumed_outside_falls_back():
 
 def test_executor_knob_off_and_auto_threshold():
     net = _tf(4)
-    mx.config.set("MXNET_TPU_SCAN_LAYERS", "off")
+    # off is the default: nothing scans unless asked
+    assert mx.config.get("MXNET_TPU_SCAN_LAYERS") == "off"
+    ex = net.simple_bind(mx.cpu(), data=(B, T), softmax_label=(B, T))
+    assert ex._scan_plan is None
+    mx.config.set("MXNET_TPU_SCAN_LAYERS", "auto")
     try:
+        # auto: min repeat 4 -> a 4-layer chain scans
         ex = net.simple_bind(mx.cpu(), data=(B, T),
                              softmax_label=(B, T))
-        assert ex._scan_plan is None
+        assert ex._scan_plan is not None and ex._scan_plan.n_layers == 4
     finally:
-        mx.config.set("MXNET_TPU_SCAN_LAYERS", "auto")
-    # auto default: min repeat 4 -> a 4-layer chain scans
-    ex = net.simple_bind(mx.cpu(), data=(B, T), softmax_label=(B, T))
-    assert ex._scan_plan is not None and ex._scan_plan.n_layers == 4
+        mx.config.reset("MXNET_TPU_SCAN_LAYERS")
 
 
 # ----------------------------------------------------------- bit parity
@@ -191,7 +193,7 @@ def _fit(net, scan_mode, X, Y, init, epochs=2, accum=None):
                 grad_accum=accum)
         return {n: v.asnumpy() for n, v in mod.get_params()[0].items()}
     finally:
-        mx.config.set("MXNET_TPU_SCAN_LAYERS", "auto")
+        mx.config.reset("MXNET_TPU_SCAN_LAYERS")
 
 
 @pytest.fixture(scope="module")

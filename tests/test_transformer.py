@@ -75,26 +75,34 @@ def test_symbol_positional_attrs():
 
 def test_transformer_flash_attention_matches_dense():
     """attention='flash' (Pallas kernel path) must produce the same
-    logits as the dense composition under shared parameters."""
+    logits as the dense composition under shared parameters — on one
+    device and, sharded by the kernel's own shard_map, over a mesh."""
+    one, two = mx.cpu(), [mx.cpu(0), mx.cpu(1)]
     mods = {}
-    for att in ("dense", "flash"):
+    for name, att, ctx in (("dense", "dense", one), ("flash", "flash", one),
+                           ("flash_mesh", "flash", two)):
         sym = transformer.get_symbol(vocab_size=50, num_layers=1,
                                      d_model=32, n_heads=2, seq_len=128,
                                      attention=att)
-        mod = mx.mod.Module(sym, context=mx.cpu())
+        mod = mx.mod.Module(sym, context=ctx)
         mod.bind(data_shapes=[("data", (2, 128))],
                  label_shapes=[("softmax_label", (2, 128))])
         mod.init_params(mx.init.Xavier())
-        mods[att] = mod
+        mods[name] = mod
     args, auxs = mods["dense"].get_params()
     mods["flash"].set_params(args, auxs)
+    mods["flash_mesh"].set_params(args, auxs)
+    assert mods["flash"]._exec._batch_rows is None
+    mesh, axes = mods["flash_mesh"]._exec._batch_rows
+    assert mesh.devices.size == 2 and axes == ("data",)
     rng = np.random.RandomState(0)
     x = rng.randint(0, 50, (2, 128)).astype(np.float32)
     db = mx.io.DataBatch(data=[mx.nd.array(x)],
                          label=[mx.nd.array(np.zeros_like(x))])
     outs = {}
-    for att, mod in mods.items():
+    for name, mod in mods.items():
         mod.forward(db, is_train=False)
-        outs[att] = mod.get_outputs()[0].asnumpy()
-    np.testing.assert_allclose(outs["flash"], outs["dense"],
-                               rtol=1e-4, atol=1e-5)
+        outs[name] = mod.get_outputs()[0].asnumpy()
+    for name in ("flash", "flash_mesh"):
+        np.testing.assert_allclose(outs[name], outs["dense"],
+                                   rtol=1e-4, atol=1e-5)

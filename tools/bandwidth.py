@@ -37,7 +37,7 @@ def main(argv=None):
         jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     n = len(devs)
@@ -45,16 +45,8 @@ def main(argv=None):
 
     def smap(fn, in_specs, out_specs):
         # the replication checker can't infer psum outputs; disable it
-        # (kwarg name varies across jax versions). The bare call runs
-        # outside try so a genuine signature error propagates.
-        for kw in ({"check_vma": False}, {"check_rep": False}):
-            try:
-                return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
-            except TypeError:
-                continue
         return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+                         out_specs=out_specs, check_vma=False)
     elems = int(args.size_mb * 1e6 / 4)
     elems -= elems % max(n, 1)
     x = jnp.ones((elems,), jnp.float32)
