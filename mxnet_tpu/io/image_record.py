@@ -17,6 +17,7 @@ import numpy as np
 
 from .. import lockcheck as _lockcheck
 from .. import ndarray as nd
+from .. import profiler as _profiler
 from ..recordio import MXRecordIO, MXIndexedRecordIO, unpack
 from .io import DataBatch, DataDesc, DataIter
 
@@ -296,26 +297,27 @@ class ImageRecordIter(DataIter):
         self._reset_evt.set()
 
     def next(self):
-        if self._native is not None:
-            imgs, labels, pad = self._native.next()   # raises StopIteration
-            if self.label_width == 1:
-                labels = labels[:, 0]
+        # two things happen on the caller's thread, told apart by their
+        # spans: the wait for the decoders, and the batch's placement
+        with _profiler.span("io_batch_wait", "io"):
+            if self._native is not None:
+                imgs, labels, pad = self._native.next()   # StopIteration
+                if self.label_width == 1:
+                    labels = labels[:, 0]
+            else:
+                kind, imgs, labels, pad = self._batch_queue.get()
+                if kind == "error":
+                    raise imgs            # exception from the loader thread
+                if kind == "stop":
+                    raise StopIteration
+        with _profiler.span("io_batch_place", "io",
+                            bytes=imgs.nbytes + labels.nbytes):
             return DataBatch(data=[nd.array(imgs.astype(self._dtype,
                                                         copy=False),
                                             dtype=self._dtype)],
                              label=[nd.array(labels)], pad=pad,
                              provide_data=self.provide_data,
                              provide_label=self.provide_label)
-        kind, imgs, labels, pad = self._batch_queue.get()
-        if kind == "error":
-            raise imgs                # exception from the loader thread
-        if kind == "stop":
-            raise StopIteration
-        return DataBatch(data=[nd.array(imgs.astype(self._dtype),
-                                        dtype=self._dtype)],
-                         label=[nd.array(labels)], pad=pad,
-                         provide_data=self.provide_data,
-                         provide_label=self.provide_label)
 
     def iter_next(self):
         try:

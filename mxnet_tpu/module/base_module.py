@@ -652,113 +652,119 @@ class BaseModule(object):
                     fid = getattr(data_batch, "_mx_flow", None)
                     if fid is None and _profiler.spans_enabled():
                         fid = _profiler.new_flow()
-                    if monitor is not None:
-                        monitor.tic()
-                    if straggler is not None:
-                        # LOCAL-work window = previous metric fetch →
-                        # this dispatch: the host-side inter-step
-                        # segment (fault sleeps, SIGSTOP pulses, input
-                        # fetch, callbacks) where a rank's OWN slowness
-                        # lands. Collective waits surface inside the
-                        # dispatch/metric regions (async dispatch
-                        # defers them to the next device sync), which
-                        # this window excludes — counting a peer-wait
-                        # as local work would equalize every rank's
-                        # rate and hide the straggler.
-                        _t_ds = time.perf_counter()
-                        if t_host_mark is not None:
-                            straggler.step(_t_ds - t_host_mark)
-                    with _profiler.span("fused_step_dispatch", "step",
-                                        flow=fid):
-                        if fused is not None and monitor is None:
-                            fused(data_batch)
-                        else:
-                            self.forward_backward(data_batch)
-                            self.update()
-                    if window > 0:
-                        inflight.push(step_token())
-                    # metric BEFORE prepare: prepare may switch the current
-                    # bucket module, whose outputs are not this batch's
-                    with _profiler.span("metric_update", "metric",
-                                        flow=fid, lane="metric"):
-                        if window > 0 and update_device is not None and \
-                                update_device(eval_metric,
-                                              data_batch.label):
-                            pass  # chained device reduction, no host sync
-                        else:
-                            if window > 0:
-                                # the async loop had to sync for this
-                                # metric: visible per-batch pipeline break
-                                _profiler.incr_counter("loop_host_sync")
-                            self.update_metric(eval_metric,
-                                               data_batch.label)
-                    if straggler is not None:
-                        t_host_mark = time.perf_counter()
-                    try:
-                        next_data_batch = next(data_iter)
-                        self.prepare(next_data_batch)
-                    except StopIteration:
-                        end_of_batch = True
-                    if straggler is not None and getattr(
-                            train_data, "_mx_offthread_fetch", False):
-                        # re-derived for the streaming data plane: an
-                        # OFF-THREAD fetch (PrefetchingIter queue pop,
-                        # DataLoader worker-queue pop) is a data-plane
-                        # wait — already surfaced as loop_prefetch_stall
-                        # / data_stall — not rank-local compute; leaving
-                        # it in the window would flag a slow LOADER as a
-                        # straggling HOST. An inline iterator's decode
-                        # happens on this thread and stays counted as
-                        # local work (the PR 13 window semantics).
-                        t_host_mark = time.perf_counter()
-                    if monitor is not None:
-                        monitor.toc_print()
-                    if batch_end_callback is not None:
-                        batch_end_params = BatchEndParam(epoch=epoch,
-                                                         nbatch=nbatch,
-                                                         eval_metric=eval_metric,
-                                                         locals=locals())
-                        for callback in _as_list(batch_end_callback):
-                            callback(batch_end_params)
-                    nbatch += 1
-                    if progress_path:
-                        _touch_progress(nbatch)
-                    if ckpt_mgr is not None:
-                        if ckpt_every_n and nbatch % ckpt_every_n == 0:
-                            # the snapshot must be a step boundary: wait
-                            # out the in-flight window, then capture (the
-                            # cheap phase) and resume the loop while the
-                            # writer drains to disk behind it
-                            inflight.drain()
-                            ckpt_mgr.save_module(
-                                self, epoch=epoch, batches_done=nbatch,
-                                metric=eval_metric,
-                                loader_state=cursor_fn(
-                                    epoch=epoch, batches_done=nbatch)
-                                if cursor_fn else None)
-                        if ckpt_mgr.preempt_requested:
-                            # SIGTERM (preemption notice): finish this
-                            # batch, land a SYNCHRONOUS save, and exit
-                            # with the conventional 128+15 status
-                            inflight.drain()
-                            ckpt_mgr.preempt_save(
-                                self, epoch=epoch, batches_done=nbatch,
-                                metric=eval_metric,
-                                loader_state=cursor_fn(
-                                    epoch=epoch, batches_done=nbatch)
-                                if cursor_fn else None)
-                            self.logger.warning(
-                                "SIGTERM: checkpoint saved at epoch %d "
-                                "batch %d; exiting with status 143",
-                                epoch, nbatch)
-                            bb = _blackbox()
-                            if bb is not None:
-                                # observed-flag context on the training
-                                # thread — never the signal handler
-                                bb.record("preempt", "sigterm",
-                                          epoch=epoch, batch=nbatch)
-                                bb.flush("sigterm")
-                            raise SystemExit(143)
+                    with _profiler.span("fit_step", "step", flow=fid,
+                                        nbatch=nbatch):
+                        if monitor is not None:
+                            monitor.tic()
+                        if straggler is not None:
+                            # LOCAL-work window = previous metric fetch →
+                            # this dispatch: the host-side inter-step
+                            # segment (fault sleeps, SIGSTOP pulses,
+                            # input fetch, callbacks) where a rank's OWN
+                            # slowness lands. Collective waits surface in
+                            # the dispatch/metric regions (async dispatch
+                            # defers them to the next device sync), which
+                            # this window excludes — counting a peer-wait
+                            # as local work would equalize every rank's
+                            # rate and hide the straggler.
+                            _t_ds = time.perf_counter()
+                            if t_host_mark is not None:
+                                straggler.step(_t_ds - t_host_mark)
+                        with _profiler.span("fused_step_dispatch", "step",
+                                            flow=fid):
+                            if fused is not None and monitor is None:
+                                fused(data_batch)
+                            else:
+                                self.forward_backward(data_batch)
+                                self.update()
+                        if window > 0:
+                            inflight.push(step_token())
+                        # metric BEFORE prepare: prepare may switch the
+                        # current bucket module, whose outputs are not this
+                        # batch's
+                        with _profiler.span("metric_update", "metric",
+                                            flow=fid, lane="metric"):
+                            if window > 0 and update_device is not None and \
+                                    update_device(eval_metric,
+                                                  data_batch.label):
+                                pass    # chained device reduction, no sync
+                            else:
+                                if window > 0:
+                                    # the async loop had to sync for
+                                    # this metric: visible per-batch
+                                    # pipeline break
+                                    _profiler.incr_counter("loop_host_sync")
+                                self.update_metric(eval_metric,
+                                                   data_batch.label)
+                        if straggler is not None:
+                            t_host_mark = time.perf_counter()
+                        try:
+                            with _profiler.span("fit_data_next", "io"):
+                                next_data_batch = next(data_iter)
+                                self.prepare(next_data_batch)
+                        except StopIteration:
+                            end_of_batch = True
+                        if straggler is not None and getattr(
+                                train_data, "_mx_offthread_fetch", False):
+                            # re-derived for the streaming data plane: an
+                            # OFF-THREAD fetch (PrefetchingIter queue pop,
+                            # DataLoader worker-queue pop) is a data-plane
+                            # wait — already surfaced as
+                            # loop_prefetch_stall / data_stall — not
+                            # rank-local compute; leaving it in the window
+                            # would flag a slow LOADER as a straggling
+                            # HOST. An inline iterator's decode
+                            # happens on this thread and stays counted as
+                            # local work (the PR 13 window semantics).
+                            t_host_mark = time.perf_counter()
+                        if monitor is not None:
+                            monitor.toc_print()
+                        if batch_end_callback is not None:
+                            batch_end_params = BatchEndParam(
+                                epoch=epoch, nbatch=nbatch,
+                                eval_metric=eval_metric, locals=locals())
+                            with _profiler.span("fit_callback", "step"):
+                                for callback in _as_list(batch_end_callback):
+                                    callback(batch_end_params)
+                        nbatch += 1
+                        if progress_path:
+                            _touch_progress(nbatch)
+                        if ckpt_mgr is not None:
+                            if ckpt_every_n and nbatch % ckpt_every_n == 0:
+                                # the snapshot must be a step boundary: wait
+                                # out the in-flight window, then capture (the
+                                # cheap phase) and resume the loop while the
+                                # writer drains to disk behind it
+                                inflight.drain()
+                                ckpt_mgr.save_module(
+                                    self, epoch=epoch, batches_done=nbatch,
+                                    metric=eval_metric,
+                                    loader_state=cursor_fn(
+                                        epoch=epoch, batches_done=nbatch)
+                                    if cursor_fn else None)
+                            if ckpt_mgr.preempt_requested:
+                                # SIGTERM (preemption notice): finish this
+                                # batch, land a SYNCHRONOUS save, and exit
+                                # with the conventional 128+15 status
+                                inflight.drain()
+                                ckpt_mgr.preempt_save(
+                                    self, epoch=epoch, batches_done=nbatch,
+                                    metric=eval_metric,
+                                    loader_state=cursor_fn(
+                                        epoch=epoch, batches_done=nbatch)
+                                    if cursor_fn else None)
+                                self.logger.warning(
+                                    "SIGTERM: checkpoint saved at epoch %d "
+                                    "batch %d; exiting with status 143",
+                                    epoch, nbatch)
+                                bb = _blackbox()
+                                if bb is not None:
+                                    # observed-flag context on the training
+                                    # thread — never the signal handler
+                                    bb.record("preempt", "sigterm",
+                                              epoch=epoch, batch=nbatch)
+                                    bb.flush("sigterm")
+                                raise SystemExit(143)
 
                 # epoch barrier: wait out in-flight steps so the epoch
                 # time is honest and checkpoints/eval see final state

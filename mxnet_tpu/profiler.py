@@ -17,9 +17,15 @@ Four layers here (docs/architecture/observability.md):
   ``span(name, flow=batch_id)`` so one batch's journey (prefetch →
   device-place → fused-step dispatch → metric sync → checkpoint write;
   serve: submit → coalesce → launch) renders as connected slices across
-  threads. Spans are recorded while the profiler runs OR while the
-  ``MXNET_TPU_OBS`` knob is on — otherwise ``span()`` returns a shared
-  no-op and allocates nothing (the ``obs_spans`` counter asserts that).
+  threads. A span is LIVE while the profiler runs, while the
+  ``MXNET_TPU_OBS`` knob is on, while a span listener is installed, or
+  while an XLA profile is being taken — otherwise ``span()`` returns a
+  shared no-op and allocates nothing (the ``obs_spans`` counter asserts
+  that). A live span gets an id, its parent (the innermost span open on
+  the same thread), optional small attributes, a record in a bounded
+  in-memory ring (:func:`spans`) and a ``jax.profiler.TraceAnnotation``
+  named ``mx.<name>``, so that in an XLA profile it sits on its thread's
+  line, on the profile's own clock, beside the device's operations.
 * The XLA-level profiler: ``start_xla_trace(logdir)`` /
   ``stop_xla_trace()`` wrap ``jax.profiler`` for TensorBoard-grade HLO
   timelines on real hardware.
@@ -45,8 +51,10 @@ internally consistent.
 from __future__ import annotations
 
 import bisect
+import collections
 import itertools
 import json
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -60,7 +68,8 @@ __all__ = [
     "incr_counter", "get_counter", "counters", "reset_counters",
     "counter_delta",
     "set_gauge", "get_gauge", "gauges", "reset_gauges",
-    "span", "record_span", "spans_enabled", "new_flow",
+    "span", "record_span", "spans_enabled", "new_flow", "spans",
+    "SpanRecord",
     "register_thread_lane", "set_span_listener", "blackbox",
     "Histogram", "histogram", "observe", "histograms", "reset_histograms",
 ]
@@ -205,7 +214,8 @@ def record_event(name: str, t_start: float, t_end: float,
 
 
 def _append_event(name, t_start, t_end, category, flow, lane,
-                  count_span: bool = False) -> None:
+                  count_span: bool = False, span_id=None, parent=None,
+                  attrs=None) -> None:
     listener = _span_listener
     if listener is not None and count_span:
         # outside _lock: the listener (flight recorder) may snapshot the
@@ -214,7 +224,21 @@ def _append_event(name, t_start, t_end, category, flow, lane,
             listener(name, t_start, t_end, category, lane)
         except Exception:                                  # noqa: BLE001
             pass
+    if count_span:
+        if span_id is None:
+            span_id = next(_span_ids)
+        record = SpanRecord(span_id, parent, name, category, t_start, t_end,
+                            flow, lane, threading.current_thread().name,
+                            attrs or {})
     with _lock:
+        if count_span:
+            # the in-memory record is kept for whichever reason the span
+            # was live (a listener or an XLA profile alone included)
+            if len(_span_ring) == _span_ring.maxlen:
+                _counters["profiler_spans_dropped"] = \
+                    _counters.get("profiler_spans_dropped", 0) + 1
+            _span_ring.append(record)
+            _counters["obs_spans"] = _counters.get("obs_spans", 0) + 1
         # authoritative re-check under the lock: a concurrent
         # set_state("stop") + dump() must not observe a half-recorded
         # tail growing behind the serialized payload
@@ -229,28 +253,35 @@ def _append_event(name, t_start, t_end, category, flow, lane,
         ts = (t_start - _t0) * 1e6
         ev = {"name": name, "cat": category, "ph": "X", "ts": ts,
               "dur": (t_end - t_start) * 1e6, "pid": 0, "tid": lid}
+        args = dict(attrs) if attrs else {}
         if flow is not None:
-            ev["args"] = {"flow": int(flow)}
+            args["flow"] = int(flow)
+        if count_span:
+            args["id"] = span_id
+            if parent is not None:
+                args["parent"] = parent
+        if args:
+            ev["args"] = args
         _events.append(ev)
         if flow is not None:
             _events.append(_flow_event_locked(int(flow), ts, lid))
-        if count_span:
-            _counters["obs_spans"] = _counters.get("obs_spans", 0) + 1
 
 
 # --------------------------------------------------------------- spans
 
-# span-close listener (one consumer: the mx.obs.blackbox flight
-# recorder). When set, span() stays LIVE even while chrome-trace span
-# recording is off, so the recorder's bounded ring sees span closes
-# without the trace buffer growing; when None (the default) the shared
-# no-op fast path is untouched — the zero-cost contract holds.
+# span-close listener (the mx.obs.blackbox flight recorder, or a
+# benchmark collecting the program's spans). When set, span() stays LIVE
+# even while chrome-trace span recording is off, so the listener sees
+# span closes without the chrome-trace list growing (the bounded span
+# ring is all that fills); when None (the default) the shared no-op fast
+# path is untouched — the zero-cost contract holds.
 _span_listener = None
 
 
 def set_span_listener(fn) -> None:
     """Install (``None`` removes) a callback invoked on every span close
-    as ``fn(name, t_start, t_end, category, lane)``. Exceptions are
+    as ``fn(name, t_start, t_end, category, lane)`` — five positional
+    arguments, whatever else a span record carries. Exceptions are
     swallowed — telemetry must never fail the traced code."""
     global _span_listener
     _span_listener = fn
@@ -272,10 +303,52 @@ def blackbox():
     return _bb
 
 
+# One record of a closed span, as :func:`spans` returns them. ``id`` is
+# unique in the process; ``parent`` is the id of the innermost span that
+# was open on the same thread when this one opened (``None`` at the top,
+# and for an after-the-fact :func:`record_span`, which may have begun
+# before whatever is open now); ``flow`` is shared by the spans of one
+# request or batch; times are ``time.perf_counter()``.
+SpanRecord = collections.namedtuple(
+    "SpanRecord", "id parent name category t_start t_end flow lane thread "
+                  "attrs")
+
+# bounded like the chrome-trace list: the newest records are kept and
+# every record pushed out is counted (``profiler_spans_dropped``)
+_MAX_SPANS = 1 << 16
+_span_ring: collections.deque = collections.deque(maxlen=_MAX_SPANS)
+_span_ids = itertools.count(1)
+
+# jax's own record of a running XLA profile, found once jax's profiler
+# module is loaded (never imported from here); False if this jax keeps
+# none, and then a profile alone makes no span live
+_xla_profile_state = None
+
+
+def _xla_profiling() -> bool:
+    global _xla_profile_state
+    st = _xla_profile_state
+    if st is None:
+        mod = sys.modules.get("jax._src.profiler")
+        if mod is None:
+            return False
+        st = _xla_profile_state = getattr(mod, "_profile_state", False)
+    return getattr(st, "profile_session", None) is not None
+
+
 def spans_enabled() -> bool:
-    """Fast, lock-free: True when span() currently records (profiler
-    running or ``MXNET_TPU_OBS`` on)."""
-    return _spans_on
+    """Fast, lock-free: True when span() currently records — the
+    profiler runs, ``MXNET_TPU_OBS`` is on, a span listener is installed
+    or an XLA profile is being taken. Call sites that must compute an
+    attribute or allocate a flow id guard it with this."""
+    return _spans_on or _span_listener is not None or _xla_profiling()
+
+
+def spans() -> List[SpanRecord]:
+    """The closed spans kept in memory, oldest first (the newest
+    ``_MAX_SPANS``; ``profiler_spans_dropped`` counts the rest)."""
+    with _lock:
+        return list(_span_ring)
 
 
 class _NoopSpan(object):
@@ -294,24 +367,66 @@ class _NoopSpan(object):
 
 _NOOP_SPAN = _NoopSpan()
 
+# jax.profiler.TraceAnnotation, imported on the first live span (this
+# module stays importable without jax); False where jax is missing
+_trace_annotation = None
+
+
+def _annotation(name, flow, attrs):
+    global _trace_annotation
+    cls = _trace_annotation
+    if cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:
+            cls = False
+        _trace_annotation = cls
+    if cls is False:
+        return None
+    if flow is not None:
+        return cls("mx." + name, flow=int(flow), **attrs)
+    return cls("mx." + name, **attrs)
+
 
 class _Span(object):
-    __slots__ = ("name", "category", "flow", "lane", "_t0")
+    __slots__ = ("name", "category", "flow", "lane", "attrs", "id",
+                 "parent", "_t0", "_ann")
 
-    def __init__(self, name, category, flow, lane):
+    def __init__(self, name, category, flow, lane, attrs):
         self.name = name
         self.category = category
         self.flow = flow
         self.lane = lane
+        self.attrs = attrs
+        self.id = next(_span_ids)
+        self.parent = None
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        stack = getattr(_tls, "open_spans", None)
+        if stack is None:
+            stack = _tls.open_spans = []
+        self.parent = stack[-1] if stack else None
+        stack.append(self.id)
+        self._ann = ann = _annotation(self.name, self.flow, self.attrs)
+        if ann is not None:
+            ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        _append_event(self.name, self._t0, time.perf_counter(),
-                      self.category, self.flow, self.lane, count_span=True)
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stack = _tls.open_spans
+        if stack and stack[-1] == self.id:
+            stack.pop()
+        elif self.id in stack:       # closed out of order: never leave
+            stack.remove(self.id)    # a dead id to adopt later spans
+        _append_event(self.name, self._t0, t1, self.category, self.flow,
+                      self.lane, count_span=True, span_id=self.id,
+                      parent=self.parent, attrs=self.attrs)
         return False
 
     def mark_flow(self, fid) -> None:
@@ -332,29 +447,32 @@ class _Span(object):
 
 def record_span(name: str, t_start: float, t_end: float,
                 category: str = "span", flow: Optional[int] = None,
-                lane: Optional[str] = None) -> None:
-    """Low-level span record for sites that time conditionally (e.g. the
-    serve coalescer, which only emits when a batch actually formed).
-    Same gating as :func:`span`."""
-    if not _spans_on and _span_listener is None:
+                lane: Optional[str] = None, **attrs) -> None:
+    """Low-level span record for sites that time conditionally or after
+    the fact (the serve coalescer, which only emits when a batch actually
+    formed; a request's wait in the queue). Same gating as :func:`span`;
+    times are ``time.perf_counter()``; no parent and no annotation in an
+    XLA profile."""
+    if not spans_enabled():
         return
     _append_event(name, t_start, t_end, category, flow, lane,
-                  count_span=True)
+                  count_span=True, attrs=attrs)
 
 
 def span(name: str, category: str = "span", flow: Optional[int] = None,
-         lane: Optional[str] = None):
+         lane: Optional[str] = None, **attrs):
     """Context manager timing one pipeline stage into the trace.
 
     ``flow`` links this slice to the other slices of the same batch or
     request across lanes; ``lane`` overrides the thread's lane with a
-    named track. No-op (shared singleton, zero allocations) unless
-    :func:`spans_enabled` or a span listener (the flight recorder) is
-    installed.
+    named track; ``attrs`` are a few small values (a bucket, a count)
+    kept with the record. No-op (shared singleton, zero allocations)
+    unless :func:`spans_enabled`: pass attributes the caller already
+    holds, or guard their computation with :func:`spans_enabled`.
     """
-    if not _spans_on and _span_listener is None:
+    if not spans_enabled():
         return _NOOP_SPAN
-    return _Span(name, category, flow, lane)
+    return _Span(name, category, flow, lane, attrs)
 
 
 # ------------------------------------------------------------- counters
@@ -574,23 +692,6 @@ def reset_histograms() -> None:
     with _lock:
         for h in _histograms.values():
             h.reset()
-
-
-class record(object):
-    """Context manager: time a region into the profile."""
-
-    def __init__(self, name: str, category: str = "region"):
-        self.name = name
-        self.category = category
-
-    def __enter__(self):
-        self._t = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        record_event(self.name, self._t, time.perf_counter(),
-                     self.category)
-        return False
 
 
 # serializes the file write of dump() without holding the hot-path

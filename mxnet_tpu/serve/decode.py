@@ -494,10 +494,14 @@ class DecodeEngine:
         (current length) of slot ``s``; inactive slots pass 0/False."""
         needed = int(pos[active].max()) + 1 if active.any() else 1
         s_b = self.seq_bucket(needed)
-        logits, new_state = self._dispatch(
-            "decode", s_b, self._build_decode,
-            (self.params, self.cache.state(),
-             np.asarray(tokens, np.int32), np.asarray(pos, np.int32),
-             np.asarray(active, bool)))
+        with _profiler.span("gen_decode_dispatch", "serve"):
+            logits, new_state = self._dispatch(
+                "decode", s_b, self._build_decode,
+                (self.params, self.cache.state(),
+                 np.asarray(tokens, np.int32), np.asarray(pos, np.int32),
+                 np.asarray(active, bool)))
         self.cache.set_state(new_state)
-        return np.asarray(logits)
+        # the fetch is the step's device fence: what the scheduler waits
+        # here is the step's device time and the copy of the logits
+        with _profiler.span("gen_logits_fetch", "serve"):
+            return np.asarray(logits)

@@ -795,7 +795,8 @@ class GenerateHandle:
 
 class _GenRequest:
     __slots__ = ("prompt", "max_new_tokens", "eos_id", "temperature",
-                 "seed", "deadline", "handle", "t_submit")
+                 "seed", "deadline", "handle", "t_submit", "t_queued",
+                 "flow")
 
     def __init__(self, prompt, max_new_tokens, eos_id, temperature, seed,
                  deadline, handle):
@@ -807,15 +808,22 @@ class _GenRequest:
         self.deadline = deadline
         self.handle = handle
         self.t_submit = monotonic()
+        # the span clock's stamp of the same moment (gen_queue_wait), and
+        # the id that the request's spans share: given at submit only
+        # while spans are live, else at admission if they are live then
+        self.t_queued = time.perf_counter()
+        self.flow = _profiler.new_flow() if _profiler.spans_enabled() \
+            else None
 
 
 class _ActiveSeq:
     __slots__ = ("slot", "handle", "pos", "generated", "max_new_tokens",
-                 "eos_id", "temperature", "rng", "token", "t_last")
+                 "eos_id", "temperature", "rng", "token", "t_last", "flow")
 
     def __init__(self, slot, handle, pos, max_new_tokens, eos_id,
-                 temperature, seed, token):
+                 temperature, seed, token, flow=None):
         self.slot = slot
+        self.flow = flow                # the request's, for gen_evict
         self.handle = handle
         self.pos = pos                  # next cache write position
         self.generated = 1              # prefill samples the first token
@@ -947,7 +955,6 @@ class GenerativeServer:
         self.engine = DecodeEngine(
             params, n_heads, self.cache, self.compile_cache, name=name,
             seq_buckets=seq_buckets, prefill_chunk=prefill_chunk)
-        self.stats_latency = None       # kept None: ttft/tpot supersede
         from .stats import DecodeLatencyStats
         self.latency = DecodeLatencyStats(name=name)
         try:
@@ -1026,8 +1033,15 @@ class GenerativeServer:
         """One continuous-batching step: admit joins under the prefill
         budget, one decode step over every resident sequence, evict the
         finished. Runs only on the scheduler thread."""
+        with _profiler.span("gen_iteration", "serve",
+                            active=len(self._active),
+                            waiting=len(self._waiting)):
+            self._step()
+
+    def _step(self):
         from .. import faults as _faults
-        self._admit()
+        with _profiler.span("gen_admit", "serve"):
+            self._admit()
         with self._lock:
             active = list(self._active)
         # cancelled handles evict at step granularity
@@ -1070,8 +1084,12 @@ class GenerativeServer:
             tokens[seq.slot] = seq.token
             pos[seq.slot] = seq.pos
             mask[seq.slot] = True
+        bucket = self.engine.seq_bucket(int(pos.max()) + 1) \
+            if _profiler.spans_enabled() else 0
         try:
-            logits = self.engine.decode_step(tokens, pos, mask)
+            with _profiler.span("gen_decode_step", "serve", bucket=bucket,
+                                active=len(active)):
+                logits = self.engine.decode_step(tokens, pos, mask)
         except Exception as exc:                            # noqa: BLE001
             # a REAL decode failure cannot be attributed to one row —
             # every resident sequence fails legibly and frees its pages
@@ -1081,20 +1099,21 @@ class GenerativeServer:
             return
         now = monotonic()
         finished = []
-        for seq in active:
-            tok = self._sample_token(logits[seq.slot], seq.temperature,
-                                     seq.rng)
-            self.latency.tpot.record(now - seq.t_last)
-            seq.t_last = now
-            seq.handle._put(tok)
-            self.cache.grow(seq.slot)
-            seq.pos += 1
-            seq.generated += 1
-            seq.token = tok
-            _profiler.incr_counter(self.name + "_tokens")
-            if seq.generated >= seq.max_new_tokens or \
-                    (seq.eos_id is not None and tok == seq.eos_id):
-                finished.append(seq)
+        with _profiler.span("gen_sample", "serve", active=len(active)):
+            for seq in active:
+                tok = self._sample_token(logits[seq.slot], seq.temperature,
+                                         seq.rng)
+                self.latency.tpot.record(now - seq.t_last)
+                seq.t_last = now
+                seq.handle._put(tok)
+                self.cache.grow(seq.slot)
+                seq.pos += 1
+                seq.generated += 1
+                seq.token = tok
+                if seq.generated >= seq.max_new_tokens or \
+                        (seq.eos_id is not None and tok == seq.eos_id):
+                    finished.append(seq)
+            _profiler.incr_counter(self.name + "_tokens", len(active))
         for seq in finished:
             self._evict(seq, exc=None)
         _profiler.incr_counter(self.name + "_decode_steps")
@@ -1140,8 +1159,17 @@ class GenerativeServer:
                                         len(self._waiting))
                 return
             budget -= bucket
+            if _profiler.spans_enabled():
+                if req.flow is None:
+                    req.flow = _profiler.new_flow()
+                _profiler.record_span("gen_queue_wait", req.t_queued,
+                                      time.perf_counter(), "serve",
+                                      flow=req.flow)
             try:
-                logits = self.engine.prefill(req.prompt, slot)
+                with _profiler.span("gen_prefill", "serve", flow=req.flow,
+                                    bucket=bucket,
+                                    prompt_len=int(req.prompt.size)):
+                    logits = self.engine.prefill(req.prompt, slot)
             except Exception as exc:                        # noqa: BLE001
                 self.cache.release(slot)
                 req.handle._finish(ServeError(
@@ -1153,7 +1181,7 @@ class GenerativeServer:
             self.latency.ttft.record(monotonic() - req.t_submit)
             seq = _ActiveSeq(slot, req.handle, int(req.prompt.size),
                              req.max_new_tokens, req.eos_id,
-                             req.temperature, req.seed, tok)
+                             req.temperature, req.seed, tok, req.flow)
             seq.rng = rng
             req.handle._put(tok)
             _profiler.incr_counter(self.name + "_tokens")
@@ -1174,28 +1202,24 @@ class GenerativeServer:
         """Remove a sequence from the running batch, ALWAYS freeing its
         pages (the injected-evict drill asserts no leak), then resolve
         its handle."""
-        from .. import faults as _faults
-        with self._lock:
-            if seq in self._active:
-                self._active.remove(seq)
-            _profiler.set_gauge(self.name + "_active_sequences",
-                                len(self._active))
-        fault_exc = None
-        try:
-            if _faults.ARMED:
-                _faults.fire("serve.evict", default_kind="raise")
-        except _faults.FaultInjected as fe:
-            fault_exc = ServeError(
-                "injected fault at serve.evict while evicting slot %d "
-                "(%s); pages were still freed" % (seq.slot, fe))
-        finally:
-            self.cache.release(seq.slot)
-            _profiler.incr_counter(self.name + "_evicted")
-        seq.handle._finish(exc if exc is not None else fault_exc)
+        with _profiler.span("gen_evict", "serve", flow=seq.flow):
+            with self._lock:
+                if seq in self._active:
+                    self._active.remove(seq)
+                _profiler.set_gauge(self.name + "_active_sequences",
+                                    len(self._active))
+            fault_exc = self._release(seq)
+            seq.handle._finish(exc if exc is not None else fault_exc)
 
     def _evict_prefill_only(self, seq: _ActiveSeq):
         """A sequence that finished at its prefill token never joined
         the active list — free its slot and resolve."""
+        with _profiler.span("gen_evict", "serve", flow=seq.flow):
+            seq.handle._finish(self._release(seq))
+
+    def _release(self, seq: _ActiveSeq) -> Optional[BaseException]:
+        """Free the sequence's slot; the injected-evict fault, if one
+        fired, is returned for the handle (the pages are freed first)."""
         from .. import faults as _faults
         fault_exc = None
         try:
@@ -1208,7 +1232,7 @@ class GenerativeServer:
         finally:
             self.cache.release(seq.slot)
             _profiler.incr_counter(self.name + "_evicted")
-        seq.handle._finish(fault_exc)
+        return fault_exc
 
     # ------------------------------------------------------------- close
     def close(self, drain: bool = True, timeout: Optional[float] = None):

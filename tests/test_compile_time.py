@@ -255,7 +255,7 @@ class TestRemat:
                 p_remat, _ = _fit(net, X, Y, init)
             assert d.get("remat_applied") >= 1
         finally:
-            mx.config.set("MXNET_TPU_REMAT", "off")
+            mx.config.reset("MXNET_TPU_REMAT")
         for n in p_plain:
             np.testing.assert_allclose(p_plain[n], p_remat[n], rtol=0,
                                        atol=1e-7, err_msg=n)
@@ -270,7 +270,7 @@ class TestRemat:
             with pytest.raises(MXNetError, match="nothing_saveable"):
                 _fit(net, X, Y, init)
         finally:
-            mx.config.set("MXNET_TPU_REMAT", "off")
+            mx.config.reset("MXNET_TPU_REMAT")
 
     def test_auto_round_trip_prediction_within_25pct(self):
         # THE ISSUE 9 satellite: the remat-opportunity suggestion,
@@ -305,7 +305,7 @@ class TestRemat:
                 m.init_params(mx.init.Xavier())
                 return m._exec
             finally:
-                mx.config.set("MXNET_TPU_REMAT", "off")
+                mx.config.reset("MXNET_TPU_REMAT")
                 mx.config.reset("MXNET_TPU_SCAN_LAYERS")
 
         def peak(ex):

@@ -247,6 +247,49 @@ def test_image_record_iter_sharding(tmp_path):
                               batch_size=5, num_parts=2, part_index=2)
 
 
+@pytest.mark.parametrize("native", [True, False])
+def test_image_record_iter_spans_tell_wait_from_placement(tmp_path, native):
+    """``next`` does two things on its caller's thread, each under its
+    own span on both of its paths, the native pipeline's and the Python
+    queue's: it waits for the decoders (io_batch_wait, also when the wait
+    ends the epoch), then places the batch (io_batch_place, with the
+    batch's bytes). Off, both sites take the shared no-op."""
+    from mxnet_tpu import profiler
+    cv2 = pytest.importorskip("cv2")
+    path = str(tmp_path / "img.rec")
+    rec = mx.recordio.MXRecordIO(path, "w")
+    rng = np.random.RandomState(0)
+    for i in range(8):
+        img = (rng.rand(12, 12, 3) * 255).astype(np.uint8)
+        rec.write(mx.recordio.pack_img(
+            mx.recordio.IRHeader(0, float(i), i, 0), img, img_fmt=".png"))
+    rec.close()
+    kw = {} if native else {"max_random_scale": 1.001}
+    it = mx.io.ImageRecordIter(path_imgrec=path, data_shape=(3, 8, 8),
+                               batch_size=4, shuffle=False, **kw)
+    if native and it._native is None:
+        pytest.skip("the native pipeline did not build here")
+    assert (it._native is not None) == native
+    with profiler.counter_delta() as d:
+        assert next(it).data[0].shape == (4, 3, 8, 8)
+    assert d.get("obs_spans") == 0
+    profiler.set_span_listener(lambda *a: None)
+    try:
+        with profiler.span("test.consumer") as consumer:
+            batch = next(it)
+            with pytest.raises(StopIteration):
+                next(it)
+    finally:
+        profiler.set_span_listener(None)
+    mine = [r for r in profiler.spans() if r.parent == consumer.id]
+    assert [r.name for r in mine] == ["io_batch_wait", "io_batch_place",
+                                      "io_batch_wait"]
+    wait, place, _last = mine
+    assert wait.t_end <= place.t_start and wait.attrs == {}
+    assert place.attrs["bytes"] >= batch.data[0].size * 4
+    assert place.category == "io"
+
+
 # ----------------------------------------------- recordio index validation
 
 def _tamper_dataset(tmp_path, n=6):

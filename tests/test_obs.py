@@ -84,6 +84,85 @@ def test_disabled_fit_records_zero_spans():
     assert d.get("obs_spans") == 0
 
 
+def test_disabled_generate_session_records_zero_spans(monkeypatch):
+    """The same for the generative server: with everything off, the
+    scheduler's span sites (gen_iteration, gen_admit, gen_prefill,
+    gen_decode_step and its two parts, gen_sample, gen_evict, and the
+    after-the-fact gen_queue_wait) all take the shared no-op, and no
+    request is given a flow id."""
+    from mxnet_tpu.models import transformer
+    from mxnet_tpu.serve import GenerativeServer
+    from mxnet_tpu.serve import server as server_mod
+    mx.profiler.set_state("stop")
+    assert not mx.obs.spans_enabled()
+    net = transformer.get_symbol(vocab_size=64, num_layers=1, d_model=16,
+                                 n_heads=2, seq_len=16)
+    mod = mx.mod.Module(net, context=mx.cpu())
+    mod.bind(data_shapes=[("data", (1, 16))],
+             label_shapes=[("softmax_label", (1, 16))])
+    mod.init_params(mx.init.Uniform(0.05))
+    flows = []
+    make = server_mod._GenRequest
+
+    def spying(*args):
+        req = make(*args)
+        flows.append(req)
+        return req
+    monkeypatch.setattr(server_mod, "_GenRequest", spying)
+    srv = GenerativeServer(mod, n_heads=2, max_sequences=2, page=4,
+                           int8=False, name="obs_off_gen")
+    try:
+        with _profiler.counter_delta() as d:
+            before = len(mx.profiler.spans())
+            handles = [srv.submit_generate([3, 1, 4], max_new_tokens=4)
+                       for _ in range(3)]
+            for h in handles:
+                assert len(h.result(timeout=120)) == 4
+        assert d.get("obs_spans") == 0
+        assert len(mx.profiler.spans()) == before
+        assert d.get("obs_off_gen_tokens") == 12    # one add a step
+        assert len(flows) == 3 and all(r.flow is None for r in flows)
+    finally:
+        srv.close()
+
+
+def test_fit_step_holds_the_loops_spans_as_children():
+    """One fit_step a batch, numbered and carrying the batch's flow; the
+    dispatch, the metric update, the wait for the next batch and the
+    callbacks are its children, so its self time is the loop's own."""
+    got = []
+    mx.profiler.set_span_listener(lambda *a: got.append(a))
+    seen = []
+    try:
+        mod = mx.mod.Module(_mlp(), context=mx.cpu())
+        first = mx.profiler.span("fit.marker")
+        with first:
+            pass
+        mod.fit(_fit_data(), optimizer="sgd", initializer=mx.init.Xavier(),
+                optimizer_params={"learning_rate": 0.1}, num_epoch=1,
+                batch_end_callback=lambda p: seen.append(p.nbatch))
+    finally:
+        mx.profiler.set_span_listener(None)
+    rec = [r for r in mx.profiler.spans() if r.id > first.id]
+    by_id = {r.id: r for r in rec}
+    steps = [r for r in rec if r.name == "fit_step"]
+    assert [r.attrs["nbatch"] for r in steps] == seen == list(range(10))
+    assert all(r.parent is None and r.flow is not None for r in steps)
+    assert len({r.flow for r in steps}) == 10
+    for name in ("fused_step_dispatch", "metric_update", "fit_data_next",
+                 "fit_callback"):
+        mine = [r for r in rec if r.name == name]
+        assert len(mine) == 10, name
+        assert all(by_id[r.parent].name == "fit_step" for r in mine)
+    for r in rec:
+        if r.name == "fused_step_dispatch":
+            assert r.flow == by_id[r.parent].flow
+        if r.name == "inflight_retire":
+            assert by_id[r.parent].name == "fit_step"
+    # the five-argument listener saw the same closes
+    assert sum(1 for a in got if a[0] == "fit_step" and len(a) == 5) == 10
+
+
 def test_span_records_under_obs_knob_without_profiler(obs_on, tmp_path):
     """MXNET_TPU_OBS enables spans while the profiler state stays
     'stop' — structured timeline without per-op sync tracing."""

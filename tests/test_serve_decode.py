@@ -183,9 +183,69 @@ def test_eos_stops_generation(module):
         eos = free[2]
         toks = srv.submit_generate([7, 3], max_new_tokens=10,
                                    eos_id=eos).result(timeout=120)
-        assert toks == free[:3]           # eos token streamed, then stop
+        # eos token streamed, then stop: at its FIRST occurrence, which is
+        # before index 2 where the model happens to repeat a token (the
+        # weights follow numpy's global state, so they vary run to run)
+        assert toks == free[:free.index(eos) + 1]
     finally:
         srv.close()
+
+
+def test_a_request_is_followed_by_its_flow_through_the_scheduler(module):
+    """With spans live, a request's queue wait, prefill and eviction
+    share its flow id, and the scheduler's spans nest: every decode step
+    and every sampling pass inside one iteration, the step's dispatch and
+    logits fetch inside the step."""
+    got = []
+    profiler.set_span_listener(lambda *a: got.append(a))
+    srv = _server(module, name="flowgen", max_sequences=2)
+    try:
+        handles = [srv.submit_generate([5, 9, 2][:n], max_new_tokens=4)
+                   for n in (2, 3, 1)]
+        for h in handles:
+            assert len(h.result(timeout=120)) == 4
+    finally:
+        srv.close()
+        profiler.set_span_listener(None)
+    rec = [r for r in profiler.spans()
+           if r.thread.endswith("[flowgen]") or r.name == "gen_queue_wait"]
+    by_id = {r.id: r for r in rec}
+    by_name = {}
+    for r in rec:
+        by_name.setdefault(r.name, []).append(r)
+    waits = [r for r in by_name["gen_queue_wait"] if r.flow is not None]
+    flows = sorted(r.flow for r in waits)[-3:]
+    assert len(set(flows)) == 3
+    for flow in flows:
+        mine = {r.name: r for r in rec if r.flow == flow}
+        assert set(mine) == {"gen_queue_wait", "gen_prefill", "gen_evict"}
+        assert mine["gen_queue_wait"].parent is None
+        # the wait ends where a slot was acquired, before the prefill
+        assert mine["gen_queue_wait"].t_end <= mine["gen_prefill"].t_start \
+            <= mine["gen_evict"].t_start
+        assert by_id[mine["gen_prefill"].parent].name == "gen_admit"
+        assert mine["gen_prefill"].attrs["prompt_len"] in (1, 2, 3)
+        assert mine["gen_prefill"].attrs["bucket"] >= \
+            mine["gen_prefill"].attrs["prompt_len"]
+    # the third request waited for a slot: two are resident at most
+    assert max(r.t_end - r.t_start for r in waits) > 0
+    steps = by_name["gen_decode_step"]
+    assert len(steps) >= 3 and len(by_name["gen_sample"]) == len(steps)
+    for r in steps + by_name["gen_sample"] + by_name["gen_admit"]:
+        parent = by_id[r.parent]
+        assert parent.name == "gen_iteration" and parent.parent is None
+        assert parent.t_start <= r.t_start and r.t_end <= parent.t_end
+    for r in steps:
+        assert r.attrs["active"] in (1, 2) and r.attrs["bucket"] > 0
+    for name in ("gen_decode_dispatch", "gen_logits_fetch"):
+        assert len(by_name[name]) == len(steps)
+        assert all(by_id[r.parent].name == "gen_decode_step"
+                   for r in by_name[name])
+    assert all(by_id[r.parent].name in ("gen_iteration", "gen_admit")
+               for r in by_name["gen_evict"])
+    # one span a sampling pass, whatever the number of sequences
+    assert sum(r.attrs["active"] for r in by_name["gen_sample"]) == 3 * 3
+    assert any(a[0] == "gen_iteration" and len(a) == 5 for a in got)
 
 
 def test_join_mid_flight_and_zero_steady_state_recompiles(module):
