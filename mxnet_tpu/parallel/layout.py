@@ -256,12 +256,16 @@ def island_specs(island: str,
         # axis ((B, H, S, D) layout)
         "ring_attention": {"batch": batch,
                            "qkv_seq": P(None, None, model, None)},
-        # generative serving: the decode KV cache shards its head axis
-        # over the model axis ((slots, H, S, D) layout — the serving
-        # analogue of tp-sharded attention heads); the int8 per-page
-        # scale planes (slots, H, n_pages) follow the same head split
+        # generative serving: the decode KV cache is (slots, S, H * D)
+        # per layer — one lane-dense row a position, heads side by side
+        # (a D-wide minor dimension made the compiler keep S minor and
+        # transpose each layer's slab twice a step). Its row axis IS the
+        # head axis, sharded over the model axis in whole heads
+        # (n_heads % tp == 0: the serving analogue of tp-sharded
+        # attention heads); the int8 per-page scale planes
+        # (slots, H, n_pages) follow the same head split
         "serve": {"batch": batch,
-                  "kv_cache": P(None, model, None, None),
+                  "kv_cache": P(None, None, model),
                   "kv_scale": P(None, model, None)},
     }
     if island not in table:

@@ -10,17 +10,29 @@ decode is a counter-asserted zero-recompile regime:
   short buckets, :func:`~mxnet_tpu.parallel.ring_attention
   .chunked_causal_attention` — the ring kernel's online-softmax block
   loop, single-device — past ``prefill_chunk``), writes the prompt's
-  K/V into the cache slot IN-PROGRAM (the state operand is donated, so
-  the update is in-place on TPU), and returns only the last real
-  token's logits (one ``(D,)`` row through the LM head, not a
-  ``(T_b, V)`` matmul).
+  K/V rows into the cache slot IN-PROGRAM as the projection made them
+  (one contiguous ``(T_b, H*d)`` ``dynamic_update_slice`` at
+  ``[layer, slot, 0:T_b, :]``; the state operand is donated, so the
+  update is in-place on TPU), and returns only the last real token's
+  logits (one ``(D,)`` row through the LM head, not a ``(T_b, V)``
+  matmul).
 * **decode** — ONE jitted step per sequence bucket ``S_b`` over the
   WHOLE slot array: embed the freshest token of every resident
-  sequence, append its K/V at the per-slot write position via a vmapped
-  ``lax.dynamic_update_slice`` (gather-free; finished/empty slots write
-  into reclaimed space that the next prefill overwrites — a masked
-  no-op by construction), attend against the static ``[0:S_b]`` cache
-  slice with per-slot length masking, and return ``(slots, V)`` logits.
+  sequence, append its K/V row at the per-slot write position with one
+  in-place update of the donated whole array
+  (``K.at[layer, arange(slots), pos].set(k_new)``; finished/empty slots
+  write into reclaimed space that the next prefill overwrites — a
+  masked no-op by construction), attend to rows ``[0:S_b]`` with
+  per-slot length masking, and return ``(slots, V)`` logits.
+
+The cache is ``(layers, slots, max_seq, H*d)`` (``kv_cache.py`` says
+why): no program takes a layer's slab out of it or puts one back. The
+float32 cache on one device is read where it lies by the Pallas kernel
+of ``ops/pallas/decode_attention.py`` (only the key blocks a sequence
+has are fetched, none for a free slot); the int8 and the mesh-sharded
+cache read ``K[layer, :, :S_b]`` through XLA, heads split out of the
+row by a reshape — one layout, and the read chosen by what the engine
+observes of its cache.
 
 The executable set is exactly |prompt buckets| + |decode buckets| (the
 server's CompileCache counters assert it), and each program is
@@ -162,15 +174,28 @@ def _fc(x, params, name):
     return x @ params[name + "_weight"].T + params[name + "_bias"]
 
 
-def _quantize_pages(x, page: int):
-    """(H, T, d) f32 -> (int8 (H, T, d), scales (H, T // page)) — one
-    symmetric scale per (head, page), the quantized-paged-KV layout."""
+def _qkv_rows(h, params, pfx, d_model):
+    """Q, K and V rows ``(T, D)`` of a prompt, each from its own third
+    of the fused projection's weight. Three products, so that K and V
+    come out contiguous and go into the cache as they are: sliced out
+    of one ``(T, 3D)`` result they are strided copies, and at bucket 256
+    the TPU compiler then tiled three layers' FFN fusions out of HBM
+    (17.9 ms a prefill where its neighbours take 5 to 6)."""
+    w = params[pfx + "_att_qkv_weight"].reshape(3, d_model, d_model)
+    b = params[pfx + "_att_qkv_bias"].reshape(3, d_model)
+    return [h @ w[i].T + b[i] for i in range(3)]
+
+
+def _quantize_pages(x, page: int, n_heads: int):
+    """(T, H*d) f32 rows -> (int8 (T, H*d), scales (H, T // page)) — one
+    symmetric scale per (head, page), taken over the page's positions
+    and the head's d_head lanes of the row."""
     import jax.numpy as jnp
-    h, t, d = x.shape
-    pg = x.reshape(h, t // page, page, d)
-    scale = jnp.maximum(jnp.max(jnp.abs(pg), axis=(2, 3)) / 127.0, 1e-8)
-    q = jnp.clip(jnp.round(pg / scale[:, :, None, None]), -127, 127)
-    return q.reshape(h, t, d).astype(jnp.int8), scale
+    t, row = x.shape
+    pg = x.reshape(t // page, page, n_heads, row // n_heads)
+    scale = jnp.maximum(jnp.max(jnp.abs(pg), axis=(1, 3)) / 127.0, 1e-8)
+    q = jnp.clip(jnp.round(pg / scale[:, None, :, None]), -127, 127)
+    return q.reshape(t, row).astype(jnp.int8), scale.T
 
 
 class DecodeEngine:
@@ -254,27 +279,16 @@ class DecodeEngine:
         page = self.cache.page
 
         def write_layer(state, li, slot, k, v):
-            # k/v: (H, T_b, d) -> cache block [li, slot, :, 0:T_b, :]
+            # k/v: (T_b, H*d) rows -> cache block [li, slot, 0:T_b, :],
+            # one contiguous update each (int8: and the pages' scales)
+            new = (k, v)
             if int8:
-                ks, vs = state[2], state[3]
-                kq, ksc = _quantize_pages(k, page)
-                vq, vsc = _quantize_pages(v, page)
-                return (
-                    lax.dynamic_update_slice(
-                        state[0], kq[None, None], (li, slot, 0, 0, 0)),
-                    lax.dynamic_update_slice(
-                        state[1], vq[None, None], (li, slot, 0, 0, 0)),
-                    lax.dynamic_update_slice(
-                        ks, ksc[None, None], (li, slot, 0, 0)),
-                    lax.dynamic_update_slice(
-                        vs, vsc[None, None], (li, slot, 0, 0)),
-                )
-            return (
-                lax.dynamic_update_slice(
-                    state[0], k[None, None], (li, slot, 0, 0, 0)),
-                lax.dynamic_update_slice(
-                    state[1], v[None, None], (li, slot, 0, 0, 0)),
-            )
+                (k, ks), (v, vs) = (_quantize_pages(x, page, cfg.n_heads)
+                                    for x in new)
+                new = (k, v, ks, vs)
+            return tuple(
+                lax.dynamic_update_slice(a, n[None, None], (li, slot, 0, 0))
+                for a, n in zip(state, new))
 
         def fn(params, state, tokens, slot, true_len):
             # tokens (T_b,) int32; slot, true_len scalar int32
@@ -284,12 +298,12 @@ class DecodeEngine:
                 pfx = "layer%d" % li
                 h = _ln(x, params[pfx + "_ln1_gamma"],
                         params[pfx + "_ln1_beta"])
-                qkv = _fc(h, params, pfx + "_att_qkv")      # (T_b, 3D)
-                qkv = qkv.reshape(t_b, 3, cfg.n_heads, cfg.d_head)
-                q = qkv[:, 0].transpose(1, 0, 2)            # (H, T_b, d)
-                k = qkv[:, 1].transpose(1, 0, 2)
-                v = qkv[:, 2].transpose(1, 0, 2)
-                state = write_layer(state, li, slot, k, v)
+                rows = _qkv_rows(h, params, pfx, cfg.d_model)
+                # the cache takes the rows as the projections made them
+                state = write_layer(state, li, slot, rows[1], rows[2])
+                q, k, v = (
+                    r.reshape(t_b, cfg.n_heads, cfg.d_head)
+                    .transpose(1, 0, 2) for r in rows)      # (H, T_b, d)
                 ctx = self._attention_full(q, k, v)         # (H, T_b, d)
                 ctx = ctx.transpose(1, 0, 2).reshape(t_b, cfg.d_model)
                 x = x + _fc(ctx, params, pfx + "_att_proj")
@@ -308,83 +322,118 @@ class DecodeEngine:
         return jax.jit(fn, donate_argnums=(1,))
 
     def _read_bucket(self, state, li: int, s_b: int):
-        """Cache slice [0:S_b] of layer ``li``, dequantized:
-        (slots, H, S_b, d) f32 pair."""
+        """Rows [0:S_b] of layer ``li``, dequantized, heads split out of
+        the row by a reshape: (slots, S_b, H, d) f32 pair."""
         import jax.numpy as jnp
+        cfg = self.cfg
         page = self.cache.page
-        k = state[0][li, :, :, :s_b, :]
-        v = state[1][li, :, :, :s_b, :]
+        heads = (state[0].shape[1], s_b, cfg.n_heads, cfg.d_head)
+        k = state[0][li, :, :s_b]                       # (slots, S_b, H*d)
+        v = state[1][li, :, :s_b]
         if not self.cache.int8:
-            return k, v
+            return k.reshape(heads), v.reshape(heads)
         pb = s_b // page
-        slots, h = k.shape[0], k.shape[1]
-        ks = state[2][li, :, :, :pb]
-        vs = state[3][li, :, :, :pb]
 
         def deq(q, sc):
-            f = q.astype(jnp.float32).reshape(slots, h, pb, page, -1)
-            return (f * sc[..., None, None]).reshape(slots, h, s_b, -1)
+            # sc (slots, H, pb): one scale per (head, page)
+            f = q.astype(jnp.float32).reshape(heads[0], pb, page,
+                                              *heads[2:])
+            f = f * sc.transpose(0, 2, 1)[:, :, None, :, None]
+            return f.reshape(heads)
 
-        return deq(k, ks), deq(v, vs)
+        return (deq(k, state[2][li, :, :, :pb]),
+                deq(v, state[3][li, :, :, :pb]))
+
+    def _kernel_reads(self, s_b: int) -> bool:
+        """Whether bucket ``s_b``'s decode program reads the cache with
+        the Pallas kernel: the float32 cache on one device, in key
+        blocks the TPU can tile. int8 pages are dequantized by the XLA
+        read, and a Mosaic call under a multi-device jit would need a
+        ``shard_map`` the engine does not give it."""
+        from ..ops.pallas.decode_attention import block_for
+        return (not self.cache.int8 and not self._multi_device
+                and block_for(s_b) % 8 == 0)
 
     def _build_decode(self, s_b: int):
         import jax
         import jax.numpy as jnp
-        from jax import lax
+        from ..ops.pallas.decode_attention import (block_for,
+                                                   decode_attention,
+                                                   fetch_plan)
         cfg = self.cfg
         int8 = self.cache.int8
         page = self.cache.page
         scale = 1.0 / np.sqrt(cfg.d_head)
+        kernel = self._kernel_reads(s_b)
 
-        def write_one_f32(cache_s, kn, p):
-            # cache_s (H, S, d), kn (H, d), p scalar write position
-            return lax.dynamic_update_slice(cache_s, kn[:, None, :],
-                                            (0, p, 0))
-
-        def write_one_i8(cache_s, scale_s, kn, p):
-            # requantize-on-write: page entry resets the scale (a fresh
-            # page must not inherit a stale tenant's dynamic range);
-            # in-page growth merges scales upward and requantizes the
-            # page — with an unchanged scale the round-trip is exact
-            h = cfg.n_heads
-            pi = p // page
-            off = p % page
-            pg = lax.dynamic_slice(cache_s, (0, pi * page, 0),
-                                   (h, page, cfg.d_head))
-            old = lax.dynamic_slice(scale_s, (0, pi), (h, 1))[:, 0]
-            entering = (off == 0)
-            deq = jnp.where(entering, 0.0,
-                            pg.astype(jnp.float32) * old[:, None, None])
+        def write_i8(cache, scales, li, new, pos):
+            # cache (L, slots, S, H*d) int8, scales (L, slots, H, pages),
+            # new (slots, H*d) f32. requantize-on-write: page entry
+            # resets the scale (a fresh page must not inherit a stale
+            # tenant's dynamic range); in-page growth merges scales
+            # upward and requantizes the page — with an unchanged scale
+            # the round-trip is exact. Only each slot's one page moves.
+            slots = new.shape[0]
+            sl = jnp.arange(slots)
+            pi = pos // page
+            off = pos % page
+            rows = pi[:, None] * page + jnp.arange(page)[None, :]
+            pg = cache[li, sl[:, None], rows]           # (slots, page, H*d)
+            old = scales[li, sl, :, pi]                 # (slots, H)
+            entering = (off == 0)[:, None]
+            new = new.reshape(slots, cfg.n_heads, cfg.d_head)
             needed = jnp.maximum(
-                jnp.max(jnp.abs(kn), axis=-1) / 127.0, 1e-8)    # (H,)
+                jnp.max(jnp.abs(new), axis=-1) / 127.0, 1e-8)
             new_scale = jnp.where(entering, needed,
                                   jnp.maximum(old, needed))
-            deq = lax.dynamic_update_slice(deq, kn[:, None, :], (0, off, 0))
-            q = jnp.clip(jnp.round(deq / new_scale[:, None, None]),
+            deq = pg.astype(jnp.float32).reshape(
+                slots, page, cfg.n_heads, cfg.d_head) \
+                * old[:, None, :, None]
+            deq = jnp.where(entering[:, None, :, None], 0.0, deq)
+            deq = deq.at[sl, off].set(new)
+            q = jnp.clip(jnp.round(deq / new_scale[:, None, :, None]),
                          -127, 127).astype(jnp.int8)
-            return (lax.dynamic_update_slice(cache_s, q, (0, pi * page, 0)),
-                    lax.dynamic_update_slice(scale_s, new_scale[:, None],
-                                             (0, pi)))
+            return (cache.at[li, sl[:, None], rows].set(
+                        q.reshape(slots, page, -1)),
+                    scales.at[li, sl, :, pi].set(new_scale))
 
         def write_token(state, li, k_new, v_new, pos):
-            # k_new/v_new (slots, H, d); pos (slots,) — vmapped over the
-            # slot axis, so every sequence writes at ITS OWN position in
-            # one gather-free program (empty slots write into reclaimed
-            # space the next prefill overwrites: a no-op by construction)
+            # k_new/v_new (slots, H*d); pos (slots,). One in-place update
+            # of the donated whole array for all slots: every sequence
+            # writes its row at ITS OWN position (empty slots write into
+            # reclaimed space the next prefill overwrites: a no-op by
+            # construction). No layer slab is taken out or put back.
             if int8:
-                nk, nks = jax.vmap(write_one_i8)(state[0][li], state[2][li],
-                                                 k_new, pos)
-                nv, nvs = jax.vmap(write_one_i8)(state[1][li], state[3][li],
-                                                 v_new, pos)
-                return (state[0].at[li].set(nk), state[1].at[li].set(nv),
-                        state[2].at[li].set(nks), state[3].at[li].set(nvs))
-            nk = jax.vmap(write_one_f32)(state[0][li], k_new, pos)
-            nv = jax.vmap(write_one_f32)(state[1][li], v_new, pos)
-            return (state[0].at[li].set(nk), state[1].at[li].set(nv))
+                nk, nks = write_i8(state[0], state[2], li, k_new, pos)
+                nv, nvs = write_i8(state[1], state[3], li, v_new, pos)
+                return (nk, nv, nks, nvs)
+            sl = jnp.arange(k_new.shape[0])
+            return (state[0].at[li, sl, pos].set(k_new),
+                    state[1].at[li, sl, pos].set(v_new))
+
+        def attend(q, state, li, pos, plan):
+            # q (slots, H*d) over keys 0..pos inclusive (the token just
+            # written attends to itself, matching the training graph)
+            if kernel:
+                return decode_attention(q, state[0], state[1], li, plan,
+                                        n_heads=cfg.n_heads, bucket=s_b,
+                                        scale=scale)
+            kb, vb = self._read_bucket(state, li, s_b)
+            s = jnp.einsum("shd,skhd->shk",
+                           q.reshape(-1, cfg.n_heads, cfg.d_head), kb,
+                           preferred_element_type=jnp.float32) * scale
+            mask = jnp.arange(s_b)[None, :] <= pos[:, None]
+            s = jnp.where(mask[:, None, :], s, -1e9)
+            att = jax.nn.softmax(s, axis=-1)
+            ctx = jnp.einsum("shk,skhd->shd", att, vb)
+            return ctx.reshape(-1, cfg.d_model)
 
         def fn(params, state, tokens, pos, active):
             # tokens/pos (slots,) int32; active (slots,) bool
             pos_c = jnp.clip(pos, 0, cfg.max_seq - 1)
+            # which key blocks each slot fetches: one plan a step
+            plan = fetch_plan(pos_c, active, block_for(s_b)) \
+                if kernel else None
             x = params["tok_embed_weight"][tokens] \
                 + params["pos_embed_weight"][pos_c]         # (slots, D)
             for li in range(cfg.num_layers):
@@ -392,19 +441,9 @@ class DecodeEngine:
                 h = _ln(x, params[pfx + "_ln1_gamma"],
                         params[pfx + "_ln1_beta"])
                 qkv = _fc(h, params, pfx + "_att_qkv")
-                qkv = qkv.reshape(-1, 3, cfg.n_heads, cfg.d_head)
-                q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-                state = write_token(state, li, k_new, v_new, pos_c)
-                kb, vb = self._read_bucket(state, li, s_b)
-                s = jnp.einsum("shd,shkd->shk", q, kb,
-                               preferred_element_type=jnp.float32) * scale
-                # keys at 0..pos inclusive (the token just written
-                # attends to itself, matching the training graph)
-                mask = jnp.arange(s_b)[None, :] <= pos_c[:, None]
-                s = jnp.where(mask[:, None, :], s, -1e9)
-                att = jax.nn.softmax(s, axis=-1)
-                ctx = jnp.einsum("shk,shkd->shd", att, vb)
-                ctx = ctx.reshape(-1, cfg.d_model)
+                qkv = qkv.reshape(-1, 3, cfg.d_model)
+                state = write_token(state, li, qkv[:, 1], qkv[:, 2], pos_c)
+                ctx = attend(qkv[:, 0], state, li, pos_c, plan)
                 x = x + _fc(ctx, params, pfx + "_att_proj")
                 h2 = _ln(x, params[pfx + "_ln2_gamma"],
                          params[pfx + "_ln2_beta"])
@@ -424,9 +463,12 @@ class DecodeEngine:
     def _sig_parts(self, kind: str, bucket: int) -> Tuple:
         shapes = tuple(sorted((k, tuple(v.shape), str(v.dtype))
                               for k, v in self.params.items()))
-        return ("serve", kind, bucket, self.cfg.sig(), shapes,
-                self.cache.int8, self.cache.page, self.cache.max_slots,
-                self.cache.max_seq, self.prefill_chunk)
+        # the state's shapes carry the cache layout: an executable
+        # stored for another layout is a miss, not a crash
+        state = tuple((tuple(a.shape), str(a.dtype))
+                      for a in self.cache.state())
+        return ("serve", kind, bucket, self.cfg.sig(), shapes, state,
+                self.cache.page, self.prefill_chunk)
 
     def _dispatch(self, kind: str, bucket: int, builder, args: Tuple):
         """Bucket-program dispatch under the CompileCache counter
@@ -501,6 +543,8 @@ class DecodeEngine:
                  np.asarray(tokens, np.int32), np.asarray(pos, np.int32),
                  np.asarray(active, bool)))
         self.cache.set_state(new_state)
+        if self._kernel_reads(s_b):
+            _profiler.incr_counter(self.name + "_decode_attn_kernel_steps")
         # the fetch is the step's device fence: what the scheduler waits
         # here is the step's device time and the copy of the logits
         with _profiler.span("gen_logits_fetch", "serve"):

@@ -3,14 +3,19 @@
 The TPU-native answer to vLLM's PagedAttention allocator under the
 finite-executable constraint: instead of a dynamic block table indexed
 by gathers (a different program per table shape), the cache is ONE
-device-resident block per layer —
+device-resident array each for K and V —
 
-    K, V: (num_layers, max_slots, n_heads, max_seq, d_head)
+    K, V: (num_layers, max_slots, max_seq, n_heads * d_head)
 
-— preallocated at server start, so geometry never changes, every decode
-step is gather-free (``lax.dynamic_update_slice`` at per-slot write
-positions), and the executable universe stays |prefill buckets| +
-|decode buckets|. What *is* paged is the accounting: a host-side
+— one lane-dense row a position, heads side by side in the row,
+preallocated at server start, so geometry never changes, a decode step
+writes every slot's new row with one in-place update of the donated
+array, and the executable universe stays |prefill buckets| + |decode
+buckets|. Why rows: with ``d_head`` minor (64 wide, half a 128-lane row)
+the TPU compiler kept ``max_seq`` minor instead and transposed each
+layer's slab twice a decode step; a row of ``n_heads * d_head`` keeps
+the plain layout, and a reshape splits the heads out where attention
+wants them. What *is* paged is the accounting: a host-side
 :class:`PageLedger` tracks per-slot sequence lengths in page-sized
 chunks (``MXNET_TPU_SERVE_KV_PAGE`` tokens per page), drives the
 occupancy gauges, and catches leaks/double-frees loudly — the property
@@ -21,8 +26,10 @@ scale per (slot, head, page) — the quantized-paged-attention layout —
 shrinking the reservation ~4x, which roughly doubles the resident
 sequences a fixed ``MXNET_TPU_ANALYZE_HBM_BUDGET`` admits (the
 acceptance test pins exactly 2x via :func:`max_slots_for`). Scales ride
-separate planes ``(L, slots, H, n_pages)``; dequantization is a reshape
-to ``(..., n_pages, page, d)`` times the broadcast scale — no gathers.
+separate planes ``(L, slots, H, n_pages)``; a page's scale is taken over
+its positions and its head's ``d_head`` lanes of the row, and
+dequantization is a reshape to ``(..., n_pages, page, H, d)`` times the
+broadcast scale.
 
 Budget audit: :meth:`KVCache.audit` runs the analyzer's
 ``hbm-budget`` reservation check (``analysis.memory_passes
@@ -206,8 +213,8 @@ class KVCache:
         self.name = name
         self.ledger = PageLedger(self.max_slots, self.max_seq, self.page)
         self.n_pages = self.max_seq // self.page
-        shape = (self.num_layers, self.max_slots, self.n_heads,
-                 self.max_seq, self.d_head)
+        shape = (self.num_layers, self.max_slots, self.max_seq,
+                 self.n_heads * self.d_head)
         sshape = (self.num_layers, self.max_slots, self.n_heads,
                   self.n_pages)
         self._sharding = self._resolve_sharding(mesh, layout)
@@ -299,10 +306,10 @@ class KVCache:
             return {"budget_bytes": 0, "reserved_bytes": self.hbm_bytes(),
                     "fits": True}
         from ..analysis.memory_passes import check_reservation
-        detail = ("serve KV cache %s: %d layers x %d slots x %d heads x "
-                  "%d seq x %d d_head, %s"
+        detail = ("serve KV cache %s: %d layers x %d slots x %d seq x "
+                  "(%d heads x %d d_head) rows, %s"
                   % (self.name, self.num_layers, self.max_slots,
-                     self.n_heads, self.max_seq,
+                     self.max_seq, self.n_heads,
                      self.d_head, "int8+scales" if self.int8 else "f32"))
         return check_reservation("%s_kv_cache" % self.name,
                                  self.hbm_bytes(), detail=detail)

@@ -1300,6 +1300,10 @@ class GenerativeServer:
             "tokens": _profiler.get_counter(self.name + "_tokens"),
             "decode_steps": _profiler.get_counter(
                 self.name + "_decode_steps"),
+            # of those, the steps whose program read the cache with the
+            # Pallas decode-attention kernel (float32, one device)
+            "decode_attn_kernel_steps": _profiler.get_counter(
+                self.name + "_decode_attn_kernel_steps"),
             "active_sequences": active,
             "waiting": waiting,
             "evicted": _profiler.get_counter(self.name + "_evicted"),

@@ -151,6 +151,25 @@ def test_max_slots_for_inverts_hbm_bytes():
         assert bigger.hbm_bytes() > budget
 
 
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_cache_is_one_row_a_position(int8):
+    """The one layout: K and V are ``(layers, slots, max_seq, heads *
+    d_head)`` — a lane-dense row a position, heads side by side — and
+    the int8 scale planes stay ``(layers, slots, heads, pages)``; the
+    footprint is what the head-major layout reserved."""
+    cache = KVCache(num_layers=3, n_heads=4, d_head=16, max_slots=5,
+                    max_seq=64, page=16, int8=int8, name="rows")
+    assert cache.k.shape == cache.v.shape == (3, 5, 64, 4 * 16)
+    elems = 2 * 3 * 5 * 64 * 4 * 16
+    if int8:
+        assert cache.k.dtype == np.int8
+        assert cache.k_scale.shape == cache.v_scale.shape == (3, 5, 4, 4)
+        assert cache.hbm_bytes() == elems + 2 * 3 * 5 * 4 * 4 * 4
+    else:
+        assert cache.k.dtype == np.float32 and cache.k_scale is None
+        assert cache.hbm_bytes() == elems * 4
+
+
 def test_int8_doubles_resident_sequences():
     """THE int8 acceptance: same budget, quantized KV admits at least
     2x the resident sequences (int8 payload is 4x smaller; the scale

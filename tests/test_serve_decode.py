@@ -158,6 +158,200 @@ def test_int8_kv_matches_f32_within_tolerance(module):
     assert out[False] == out[True]
 
 
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_decode_append_lands_in_its_rows_and_nowhere_else(module, int8):
+    """One decode step over ragged write positions — the first row, the
+    middle of a page, a page's last row, ``max_seq - 1``, and a free
+    slot — changes the cache in row ``[layer, slot, pos[slot], :]`` of
+    each slot and nowhere else (int8: within that row's page, whose
+    scale may grow), and what an active slot's row holds is the token's
+    K and V: what a prefill of the same tokens writes there."""
+    from mxnet_tpu._fused import CompileCache
+    from mxnet_tpu.serve.decode import DecodeEngine, extract_params
+    from mxnet_tpu.serve.kv_cache import KVCache
+    page, slots, free = 4, 5, 4
+    where = {0: 0, 1: 6, 2: 7, 3: SEQ - 1}
+    params = extract_params(module)
+
+    def engine(name):
+        cache = KVCache(LAYERS, HEADS, DMODEL // HEADS, slots, SEQ,
+                        page=page, int8=int8, name=name)
+        return DecodeEngine(params, HEADS, cache, CompileCache(name),
+                            name=name), cache
+
+    eng, cache = engine("append%d" % int8)
+    ref_eng, ref_cache = engine("append_ref%d" % int8)
+    rng = np.random.default_rng(5)
+    tokens = np.zeros((slots,), np.int32)
+    pos = np.zeros((slots,), np.int32)
+    active = np.zeros((slots,), bool)
+    for slot, p in where.items():
+        seq = rng.integers(1, VOCAB, p + 1)
+        if p:
+            eng.prefill(seq[:p], slot)
+        ref_eng.prefill(seq, slot)      # the same tokens, whole
+        tokens[slot], pos[slot], active[slot] = seq[p], p, True
+    before = [np.asarray(a) for a in cache.state()]
+    eng.decode_step(tokens, pos, active)
+    after = [np.asarray(a) for a in cache.state()]
+    ref = [np.asarray(a) for a in ref_cache.state()]
+
+    def rows(state, which, slot, p):
+        """(layers, H, d) f32 of row ``p``, dequantized."""
+        r = state[which][:, slot, p].astype(np.float32).reshape(
+            LAYERS, HEADS, -1)
+        if int8:
+            r = r * state[2 + which][:, slot, :, p // page][..., None]
+        return r
+
+    may_change = np.zeros(before[0].shape[1:3], bool)   # (slots, seq)
+    may_scale = np.zeros((slots, SEQ // page), bool)
+    for slot in range(slots):
+        lo, hi = (pos[slot] // page * page, pos[slot] // page * page + page) \
+            if int8 else (pos[slot], pos[slot] + 1)
+        may_change[slot, lo:hi] = True
+        may_scale[slot, pos[slot] // page] = True
+    for which in (0, 1):
+        changed = (before[which] != after[which]).any(axis=(0, 3))
+        assert not (changed & ~may_change).any(), np.argwhere(
+            changed & ~may_change)
+        if int8:
+            moved = (before[2 + which] != after[2 + which]).any(axis=(0, 2))
+            assert not (moved & ~may_scale).any()
+        for slot, p in where.items():
+            got, want = rows(after, which, slot, p), \
+                rows(ref, which, slot, p)
+            # int8: each side is within half a step of its own scale,
+            # and the keys it attended to were themselves quantized
+            tol = 2.0 * np.abs(want).max() / 127.0 if int8 else 1e-5
+            assert np.abs(got - want).max() <= tol, (which, slot, p)
+    assert not active[free]
+
+
+def _ragged_step(n_slots):
+    """(pos, active) with ragged lengths and free slots first, between
+    and last."""
+    pos = np.array([0, 9, 0, 3, SEQ - 1, 0, 12, 0][:n_slots], np.int32)
+    active = np.array([False, True, False, True, True, False, True,
+                       False][:n_slots])
+    return pos, active
+
+
+@pytest.mark.parametrize("bucket,block_k", [(16, 16), (16, 8), (16, 4),
+                                            (8, 4)])
+def test_decode_attention_kernel_matches_xla_read(bucket, block_k):
+    """The Pallas decode-attention kernel, interpreted, against the
+    bucket read it replaces — keys 0..pos of each active slot, several
+    key blocks a sequence (online softmax across them), free slots
+    before, between and after the active ones. atol 1e-5."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.decode_attention import (decode_attention,
+                                                       fetch_plan)
+    slots, li = 8, 1
+    d_head = DMODEL // HEADS
+    rng = np.random.default_rng(3)
+    k, v = (jnp.asarray(rng.standard_normal((LAYERS, slots, SEQ, DMODEL)),
+                        jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((slots, DMODEL)), jnp.float32)
+    pos, active = _ragged_step(slots)
+    pos = np.minimum(pos, bucket - 1)
+    plan = fetch_plan(jnp.asarray(pos), jnp.asarray(active), block_k)
+    got = np.asarray(jax.jit(lambda *a: decode_attention(
+        *a, n_heads=HEADS, bucket=bucket, block_k=block_k))(
+            q, k, v, li, plan))
+    kb = np.asarray(k)[li, :, :bucket].reshape(slots, bucket, HEADS, d_head)
+    vb = np.asarray(v)[li, :, :bucket].reshape(slots, bucket, HEADS, d_head)
+    s = np.einsum("shd,skhd->shk", np.asarray(q).reshape(
+        slots, HEADS, d_head), kb) / np.sqrt(d_head)
+    s = np.where((np.arange(bucket)[None] <= pos[:, None])[:, None], s,
+                 -1e9)
+    att = np.exp(s - s.max(-1, keepdims=True))
+    att /= att.sum(-1, keepdims=True)
+    want = np.einsum("shk,skhd->shd", att, vb).reshape(slots, DMODEL)
+    assert np.abs(got - want)[active].max() < 1e-5
+    assert not got[~active].any()           # a free slot's row is 0
+
+
+def test_fetch_plan_fetches_live_blocks_only():
+    """Walking the grid with the plan's index map, a block is fetched
+    only when its index changes: the fetches are exactly each active
+    slot's blocks 0..pos // block, none for a free slot, none past a
+    sequence's length."""
+    from mxnet_tpu.ops.pallas.decode_attention import fetch_plan
+    block, n_blocks = 4, SEQ // 4
+    pos, active = _ragged_step(8)
+    slot_of, first, last, live = (np.asarray(a) for a in fetch_plan(
+        np.asarray(pos), np.asarray(active), block))
+    assert (live == np.where(active, pos, -1)).all()
+    walk = [(slot_of[s], min(max(j, first[s]), last[s]))
+            for s in range(8) for j in range(n_blocks)]
+    fetched = [walk[0]] + [b for a, b in zip(walk, walk[1:]) if b != a]
+    assert fetched == [(s, j) for s in range(8) if active[s]
+                       for j in range(pos[s] // block + 1)]
+
+
+def test_decode_program_reads_with_the_kernel_where_it_can(module):
+    """The engine picks the read from what it observes: float32 on one
+    device in TPU-tileable buckets takes the kernel (counted as
+    ``_decode_attn_kernel_steps``), int8 keeps the XLA read; both give
+    the logits of the XLA read on a ragged step with free slots."""
+    from mxnet_tpu._fused import CompileCache
+    from mxnet_tpu.serve.decode import DecodeEngine, extract_params
+    from mxnet_tpu.serve.kv_cache import KVCache
+    params = extract_params(module)
+    pos, active = _ragged_step(8)
+    rng = np.random.default_rng(9)
+    prompts = {s: rng.integers(1, VOCAB, pos[s])
+               for s in np.flatnonzero(active)}
+    tokens = np.where(active, rng.integers(1, VOCAB, 8), 0).astype(np.int32)
+
+    def logits(name, int8=False, xla_read=False):
+        cache = KVCache(LAYERS, HEADS, DMODEL // HEADS, 8, SEQ, page=4,
+                        int8=int8, name=name)
+        eng = DecodeEngine(params, HEADS, cache, CompileCache(name),
+                           name=name)
+        if xla_read:
+            eng._kernel_reads = lambda s_b: False
+        for slot, prompt in prompts.items():
+            eng.prefill(prompt, slot)
+        steps = profiler.get_counter(name + "_decode_attn_kernel_steps")
+        out = eng.decode_step(tokens, pos, active)
+        return out, profiler.get_counter(
+            name + "_decode_attn_kernel_steps") - steps
+
+    want, n = logits("readxla", xla_read=True)
+    assert n == 0
+    got, n = logits("readkernel")
+    assert n == 1
+    assert np.abs(got - want)[active].max() < 1e-5
+    assert (got[~active] == want[~active]).all()    # masked rows
+    _, n = logits("readint8", int8=True)
+    assert n == 0
+
+
+def test_aot_key_carries_the_cache_layout(module):
+    """An executable stored under another cache layout is a miss, not a
+    crash: the AOT key's parts hold the state's shapes and dtypes."""
+    from mxnet_tpu._fused import CompileCache
+    from mxnet_tpu.serve.decode import DecodeEngine, extract_params
+    from mxnet_tpu.serve.kv_cache import KVCache
+    params = extract_params(module)
+
+    def parts(**kw):
+        cache = KVCache(LAYERS, HEADS, DMODEL // HEADS, 2, SEQ, page=4,
+                        name="sig", **kw)
+        eng = DecodeEngine(params, HEADS, cache, CompileCache("sig"),
+                           name="sig")
+        return eng._sig_parts("decode", 8)
+
+    f32, int8 = parts(int8=False), parts(int8=True)
+    assert ((LAYERS, 2, SEQ, DMODEL), "float32") in f32[5]
+    assert ((LAYERS, 2, SEQ, DMODEL), "int8") in int8[5]
+    assert ((LAYERS, 2, HEADS, SEQ // 4), "float32") in int8[5]
+    assert f32 != int8
+
+
 # ------------------------------------------------------- scheduler behavior
 
 def test_streaming_iterator_and_callback(module):

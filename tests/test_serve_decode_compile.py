@@ -1,0 +1,210 @@
+"""The decode program, compiled for the chip it serves on (ISSUE 26).
+
+No chip is attached here: the TPU's compiler compiles for a DESCRIBED
+``v5e:2x2`` device, at the serving cell's widths (2048 wide, 32 heads of
+64, FFN 8192, vocabulary 50272, 16 slots of 2048 positions) with two
+layers for eight. What is asserted is what the compiler decided about
+the KV cache, which no CPU test can see: that the donated cache is
+updated where it lies, and that no step moves a layer's whole slab —
+the program this guards against read 50 GB a step whatever the bucket.
+Nothing here is a time: a compile that passes is not a chip run.
+"""
+import re
+import types
+
+import numpy as np
+import pytest
+
+LAYERS, SLOTS, HEADS, D_HEAD, D_FF, VOCAB, MAX_SEQ = 2, 16, 32, 64, 8192, \
+    50272, 2048
+D_MODEL = HEADS * D_HEAD
+PAGE = 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                      # noqa: BLE001
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # an entry written for a described device cannot be read back
+    # without one: keep these compiles out of the persistent cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _shapes(one_chip):
+    """(params, state) of the cell as shapes on the described chip."""
+    import jax
+    import jax.numpy as jnp
+
+    def sd(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"tok_embed_weight": sd(VOCAB, D_MODEL),
+              "pos_embed_weight": sd(MAX_SEQ, D_MODEL),
+              "lm_head_weight": sd(VOCAB, D_MODEL),
+              "lm_head_bias": sd(VOCAB),
+              "final_ln_gamma": sd(D_MODEL), "final_ln_beta": sd(D_MODEL)}
+    for li in range(LAYERS):
+        pfx = "layer%d_" % li
+        for ln in ("ln1", "ln2"):
+            params[pfx + ln + "_gamma"] = sd(D_MODEL)
+            params[pfx + ln + "_beta"] = sd(D_MODEL)
+        for fc, (n_out, n_in) in (("att_qkv", (3 * D_MODEL, D_MODEL)),
+                                  ("att_proj", (D_MODEL, D_MODEL)),
+                                  ("ff1", (D_FF, D_MODEL)),
+                                  ("ff2", (D_MODEL, D_FF))):
+            params[pfx + fc + "_weight"] = sd(n_out, n_in)
+            params[pfx + fc + "_bias"] = sd(n_out)
+    return params, sd
+
+
+def _state(sd, int8=False):
+    """The cache's state tuple as ``KVCache`` lays it out."""
+    import jax.numpy as jnp
+    slab = (LAYERS, SLOTS, MAX_SEQ, D_MODEL)
+    if not int8:
+        return (sd(*slab), sd(*slab))
+    scales = (LAYERS, SLOTS, HEADS, MAX_SEQ // PAGE)
+    return (sd(*slab, dtype=jnp.int8), sd(*slab, dtype=jnp.int8),
+            sd(*scales), sd(*scales))
+
+
+def _nbytes(tree):
+    import jax
+    return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+@pytest.fixture
+def for_the_chip(monkeypatch):
+    """The default backend here is the CPU, where a Pallas kernel runs
+    interpreted: these compiles take the chip's branch instead."""
+    from mxnet_tpu import rtc
+    monkeypatch.setattr(rtc, "resolve_interpret", lambda arrays: False)
+
+
+def _compile_decode(one_chip, s_b, int8):
+    import jax.numpy as jnp
+    from mxnet_tpu.serve.decode import DecodeEngine
+    params, sd = _shapes(one_chip)
+    state = _state(sd, int8)
+    cache = types.SimpleNamespace(int8=int8, page=PAGE, max_seq=MAX_SEQ,
+                                  max_slots=SLOTS, _sharding=None)
+    eng = DecodeEngine(params, HEADS, cache, None, seq_buckets=[s_b])
+    compiled = eng._build_decode(s_b).lower(
+        params, state, sd(SLOTS, dtype=jnp.int32),
+        sd(SLOTS, dtype=jnp.int32), sd(SLOTS, dtype=jnp.bool_)).compile()
+    return compiled, params, state
+
+
+_INSTR = re.compile(r"^\s+(?:ROOT )?%(\S+) = \(?(\w+)\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\((.*)$", re.M)
+_MAKES_NO_DATA = ("parameter", "get-tuple-element", "tuple", "bitcast")
+
+
+def _slab_makers(hlo, slab_elems):
+    """Instructions of the entry computation whose result holds a whole
+    layer's slab or more and that are not an in-place update fusion:
+    (name, op) pairs."""
+    roots = {}
+    for m in re.finditer(r"^%(\S+) \(.*?\n((?:  .*\n)+?)\}", hlo, re.M):
+        root = re.search(r"^\s+ROOT %\S+ = \S+ ([\w\-]+)\(", m.group(2),
+                         re.M)
+        roots[m.group(1)] = root.group(1) if root else None
+    entry = hlo[hlo.index("\nENTRY"):]
+    out = []
+    for name, _dt, dims, op, rest in _INSTR.findall(entry):
+        elems = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+        if elems < slab_elems or op in _MAKES_NO_DATA:
+            continue
+        if op == "fusion":
+            called = re.search(r"calls=%([\w\.\-]+)", rest)
+            if called and roots.get(called.group(1)) in (
+                    "scatter", "dynamic-update-slice"):
+                continue
+        out.append((name, op))
+    return out
+
+
+@pytest.mark.parametrize("int8,s_b", [(False, 512), (False, 1536),
+                                      (True, 512)],
+                         ids=["f32-512", "f32-1536", "int8-512"])
+def test_decode_step_updates_the_cache_in_place(one_chip, for_the_chip,
+                                                int8, s_b):
+    compiled, params, state = _compile_decode(one_chip, s_b, int8)
+    mem = compiled.memory_analysis()
+    # the donated cache is the output cache: aliased whole
+    assert mem.alias_size_in_bytes == _nbytes(state)
+    assert mem.temp_size_in_bytes < 400e6, mem.temp_size_in_bytes
+    moved = _slab_makers(compiled.as_text(), SLOTS * MAX_SEQ * D_MODEL)
+    assert not moved, "whole-slab instructions: %r" % moved
+    if int8:        # the XLA read moves the bucket dequantized: no bound
+        return
+    # what a step may read: every weight, and the bucket's keys and
+    # values a few times over — never the slabs, whose bytes do not
+    # depend on the bucket
+    read = compiled.cost_analysis()["bytes accessed"]
+    allowed = _nbytes(params) + 5 * _nbytes(state) * s_b // MAX_SEQ
+    assert read < allowed, (read, allowed)
+
+
+def test_prefill_writes_its_rows_in_place(one_chip):
+    """The prefill program at the median prompt's bucket: the cache is
+    aliased whole, each layer's K and V block lands by an in-place
+    update, and the rows reach it without a copy of their own (K and V
+    sliced out of one fused projection were strided copies, and cost
+    three layers' FFN fusions their tiling at this bucket)."""
+    import jax.numpy as jnp
+    from mxnet_tpu.serve.decode import DecodeEngine
+    t_b = 256
+    params, sd = _shapes(one_chip)
+    state = _state(sd)
+    cache = types.SimpleNamespace(int8=False, page=PAGE, max_seq=MAX_SEQ,
+                                  max_slots=SLOTS, _sharding=None)
+    eng = DecodeEngine(params, HEADS, cache, None, seq_buckets=[t_b])
+    compiled = eng._build_prefill(t_b).lower(
+        params, state, sd(t_b, dtype=jnp.int32), sd(dtype=jnp.int32),
+        sd(dtype=jnp.int32)).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes == _nbytes(state)
+    hlo = compiled.as_text()
+    assert not _slab_makers(hlo, SLOTS * MAX_SEQ * D_MODEL)
+    entry = hlo[hlo.index("\nENTRY"):]
+    row_copies = [name for name, _dt, dims, op, _rest in _INSTR.findall(entry)
+                  if op == "copy" and dims == "1,1,%d,%d" % (t_b, D_MODEL)]
+    assert not row_copies, row_copies
+
+
+@pytest.mark.parametrize("s_b", [128, 1536])
+def test_decode_attention_kernel_compiles_at_real_widths(one_chip,
+                                                         for_the_chip, s_b):
+    """The kernel alone, through Mosaic: the whole cache goes in as it
+    lies (no operand copy, next to no temporaries) and the call is
+    there."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.decode_attention import (block_for,
+                                                       decode_attention,
+                                                       fetch_plan)
+    _params, sd = _shapes(one_chip)
+
+    def attend(q, k, v, pos, active):
+        plan = fetch_plan(pos, active, block_for(s_b))
+        return decode_attention(q, k, v, LAYERS - 1, plan, n_heads=HEADS,
+                                bucket=s_b)
+
+    compiled = jax.jit(attend).lower(
+        sd(SLOTS, D_MODEL), *_state(sd), sd(SLOTS, dtype=jnp.int32),
+        sd(SLOTS, dtype=jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
