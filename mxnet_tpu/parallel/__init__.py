@@ -24,14 +24,15 @@ from .mesh import (make_mesh, data_parallel_mesh, batch_sharding,
 from .ring_attention import (ring_attention, ring_self_attention,
                              local_attention_block)
 from .pipeline import pipeline_apply, pipeline_1f1b, stack_stage_params
-from .moe import moe_init, moe_apply
+from .moe import moe_init, moe_apply, moe_share_apply, route_sigmoid
 
 __all__ = ["make_mesh", "data_parallel_mesh", "batch_sharding",
            "replicated_sharding", "shard_batch", "replicate", "P", "Mesh",
            "NamedSharding", "mesh_devices", "ring_attention",
            "ring_self_attention", "local_attention_block",
            "pipeline_apply", "pipeline_1f1b", "stack_stage_params",
-           "moe_init", "moe_apply", "sharding_islands",
+           "moe_init", "moe_apply", "moe_share_apply", "route_sigmoid",
+           "sharding_islands",
            "SpecLayout", "parameter_spec_from_name"]
 
 

@@ -8,12 +8,18 @@ gathered with an einsum, and ``with_sharding_constraint`` pins the expert
 dimension to the ``expert`` mesh axis — XLA/GSPMD then lowers the two
 dispatch einsums to ``all_to_all`` collectives over ICI. No hand-written
 comms; everything stays differentiable and jit-compatible.
+
+:func:`moe_share_apply` is the other form, for a chip that holds a share
+of a wider layer's experts (serving): it is told which experts it holds,
+routes over all of them, sorts the assignments by expert, runs grouped
+products over the experts held and drops nothing.
 """
 from __future__ import annotations
 
 from typing import Optional
 
-__all__ = ["moe_init", "moe_apply", "sharding_island"]
+__all__ = ["moe_init", "moe_apply", "moe_share_apply", "route_sigmoid",
+           "sharding_island"]
 
 
 def sharding_island():
@@ -117,3 +123,68 @@ def moe_apply(params, x, *, top_k: int = 2, capacity_factor: float = 1.25,
     mean_prob = jnp.mean(probs, axis=0)
     aux = E * jnp.sum(frac * mean_prob)
     return out, aux
+
+
+def route_sigmoid(x, router_weight, router_bias, *, top_k: int,
+                  scaling: float):
+    """Sigmoid routing with a selection bias (``noaux_tc``): scores ``s =
+    sigmoid(W_r x)`` in float32; the ``top_k`` experts of largest ``s_e +
+    b_e`` are chosen; their gates are ``scaling * s_e / sum_chosen s`` (the
+    bias chooses, it does not weigh). Returns ``(experts (T, k) int32,
+    gates (T, k) float32)``."""
+    import jax
+    import jax.numpy as jnp
+    s = jax.nn.sigmoid(jnp.einsum(
+        "td,ed->te", x.astype(jnp.float32),
+        router_weight.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(s + router_bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, chosen, axis=1)
+    gates = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), gates
+
+
+def moe_share_apply(x, experts, gates, w_gate, w_up, w_down, *,
+                    first: int):
+    """This chip's share of a routed SiLU-gated expert layer.
+
+    ``x (T, D)``; ``experts``/``gates (T, k)`` as :func:`route_sigmoid`
+    gives them, over ALL the layer's experts; the chip holds the experts
+    ``first .. first + E`` as ``w_gate``/``w_up (E, D, F)`` and ``w_down
+    (E, F, D)``, each expert's matrix ``(in, out)`` as the grouped product
+    reads it. Every assignment to an expert held is computed, however
+    many meet at one expert: the ``T * k`` assignments are sorted by
+    expert (those to absent experts last, in a group no product runs
+    over), the tokens' rows gathered in that order, and three grouped
+    products (``lax.ragged_dot``) run over the groups. What the absent
+    experts would add is left out.
+
+    Returns ``(y (T, D) float32, counts (E,) int32)``: the share of the
+    result, and how many assignments each expert held received.
+    """
+    import jax
+    import jax.numpy as jnp
+    t, k = experts.shape
+    held = w_gate.shape[0]
+    local = experts.reshape(-1) - first
+    here = (local >= 0) & (local < held)
+    group = jnp.where(here, local, held)            # absent experts last
+    order = jnp.argsort(group, stable=True)
+    counts = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    token = order // k
+    rows = x[token]                                 # (T*k, D), by expert
+    from .. import rtc
+
+    def grouped(a, w):
+        # (rows, in) x (E, in, out) -> (rows, out): the rows in the
+        # weights' dtype, float32 accumulation
+        a, w = rtc.product_operands(a, w)
+        return jax.lax.ragged_dot(a, w, counts,
+                                  preferred_element_type=jnp.float32)
+    mid = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+    out = grouped(mid, w_down)
+    weight = jnp.where(here, gates.reshape(-1), 0.0)[order]
+    # rows past the last group hold nothing that counts
+    out = jnp.where(weight[:, None] != 0.0, out * weight[:, None], 0.0)
+    y = jnp.zeros((t, x.shape[1]), jnp.float32).at[token].add(out)
+    return y, counts

@@ -41,6 +41,18 @@ def resolve_interpret(arrays) -> bool:
     return jax.default_backend() != "tpu"
 
 
+def product_operands(a, w):
+    """``(a, w)`` as a product in the weights' dtype takes them: ``a``
+    cast to ``w``'s dtype. Off the TPU (XLA's CPU backend has no bfloat16
+    product) both come back widened to float32: the same products
+    exactly, so a stated dtype can be tested without the chip."""
+    import jax.numpy as jnp
+    a = a.astype(w.dtype)
+    if w.dtype != jnp.float32 and resolve_interpret((a, w)):
+        a, w = a.astype(jnp.float32), w.astype(jnp.float32)
+    return a, w
+
+
 class PallasKernel:
     """A compiled Pallas kernel callable on NDArrays.
 

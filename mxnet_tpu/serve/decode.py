@@ -34,6 +34,18 @@ cache read ``K[layer, :, :S_b]`` through XLA, heads split out of the
 row by a reshape — one layout, and the read chosen by what the engine
 observes of its cache.
 
+The engine has two halves. :class:`DecodeEngine` is the generic one: the
+bucket tables, the dispatch under the CompileCache counters, AOT warm
+starts, the spans and counters. What it serves is a *family*: an object
+that knows one block's parameters, says which planes its cache holds
+(``kv_cache.Plane``) and builds the prefill and decode programs over
+them. :class:`DenseDecoder`, below, is the first family (the zoo
+transformer, everything this docstring has described so far);
+``serve/mla_moe.py`` holds the second (latent attention over a latent
+cache plane, routed experts beside a shared one), whose prompts prefill in
+chunks, each appended to the cache and attending over it. ``docs/architecture/serving_families.md``
+says what a family owes the engine.
+
 The executable set is exactly |prompt buckets| + |decode buckets| (the
 server's CompileCache counters assert it), and each program is
 AOT-warm-startable through :mod:`mxnet_tpu.aot` — a restarted server
@@ -60,8 +72,9 @@ from .. import profiler as _profiler
 from ..base import MXNetError
 from ..obs import compiles as _obs_compiles
 
-__all__ = ["DecodeConfig", "DecodeEngine", "extract_params",
-           "config_from_params", "sample_token"]
+__all__ = ["DecodeConfig", "DecodeEngine", "DenseDecoder", "extract_params",
+           "config_from_params", "sample_token",
+           "family_for"]
 
 _LN_EPS = 1e-5          # ops/nn.py layer_norm default
 
@@ -91,8 +104,11 @@ class DecodeConfig:
                 self.vocab_size, self.max_seq)
 
 
-def extract_params(source) -> Dict[str, Any]:
-    """Normalize the served parameters to ``name -> f32 jnp array``.
+def extract_params(source, dtype: Optional[str] = None) -> Dict[str, Any]:
+    """Normalize the served parameters to ``name -> jnp array``: float32
+    by default, or the ``dtype`` the architecture states (a leaf that is
+    already a device array in that dtype is taken as it is, not carried
+    through the host).
 
     Accepts a bound Module (``get_params()``), an ``(arg, aux)`` tuple,
     or a plain dict of NDArray/numpy arrays — the exact naming the zoo
@@ -110,11 +126,16 @@ def extract_params(source) -> Dict[str, Any]:
         merged.update(source[1] or {})
     else:
         merged = dict(source)
+    want = jnp.dtype(dtype or "float32")
     out = {}
     for name, arr in merged.items():
+        if dtype is not None and isinstance(arr, jnp.ndarray) \
+                and arr.dtype == want:
+            out[name] = arr
+            continue
         if isinstance(arr, nd_mod.NDArray):
             arr = arr.asnumpy()
-        out[name] = jnp.asarray(np.asarray(arr), jnp.float32)
+        out[name] = jnp.asarray(np.asarray(arr), want)
     return out
 
 
@@ -198,58 +219,55 @@ def _quantize_pages(x, page: int, n_heads: int):
     return q.reshape(t, row).astype(jnp.int8), scale.T
 
 
-class DecodeEngine:
-    """The program table: builds, AOT-warm-starts and dispatches the
-    per-bucket prefill/decode executables over one :class:`KVCache`.
+class DenseDecoder:
+    """The first family: the dense pre-LayerNorm decoder of
+    ``models/transformer.py``, found by parameter name. Its cache planes
+    are K and V rows of ``n_heads * d_head`` (int8: and their scales); a
+    prompt prefills in one program at its bucket."""
 
-    NOT thread-safe by design: every method runs on the owning
-    GenerativeServer's scheduler thread (the cache state tuple is
-    donated through each dispatch and re-bound from the result — a
-    second dispatcher would race the donation).
-    """
-
-    def __init__(self, params: Dict[str, Any], n_heads: int, cache,
-                 compile_cache, name: str = "serve",
-                 prompt_buckets: Optional[Sequence[int]] = None,
-                 seq_buckets: Optional[Sequence[int]] = None,
-                 prefill_chunk: int = 512):
+    def __init__(self, params: Dict[str, Any], n_heads: int):
         self.params = params
         self.cfg = config_from_params(params, n_heads)
-        self.cache = cache
-        self.compile_cache = compile_cache
-        self.name = name
-        self.prefill_chunk = int(prefill_chunk)
-        from .bucketing import decode_buckets as _ladder
-        self.seq_buckets: List[int] = list(
-            seq_buckets if seq_buckets is not None
-            else _ladder(cache.max_seq, cache.page))
-        self.prompt_buckets: List[int] = list(
-            prompt_buckets if prompt_buckets is not None
-            else self.seq_buckets)
-        for b in self.prompt_buckets:
-            if b % cache.page:
-                raise ValueError("prompt bucket %d not a multiple of the "
-                                 "kv page %d" % (b, cache.page))
-        # multi-device (sharded cache) programs are AOT-fenced exactly
-        # like the executor forward (aot_skip_multidevice)
-        self._multi_device = cache._sharding is not None
+        self.engine = None
+        self.cache = None
+        self.prefill_chunk = 512
+        self._multi_device = False
+
+    def planes(self, max_seq: int, page: int, int8: bool):
+        from .kv_cache import dense_planes
+        return dense_planes(self.cfg.num_layers, self.cfg.n_heads,
+                            self.cfg.d_head, max_seq, page, int8)
+
+    def bind(self, engine) -> None:
+        """Take from the engine what the programs specialize on."""
+        self.engine = engine
+        self.cache = engine.cache
+        self.prefill_chunk = engine.prefill_chunk
+        self._multi_device = engine._multi_device
+
+    def sig(self) -> Tuple:
+        return self.cfg.sig()
 
     def executable_bound(self) -> int:
-        return len(self.prompt_buckets) + len(self.seq_buckets)
+        return len(self.engine.prompt_buckets) + len(self.engine.seq_buckets)
 
-    def prompt_bucket(self, n: int) -> int:
-        for b in self.prompt_buckets:
-            if n <= b:
-                return b
-        raise MXNetError("prompt of %d tokens exceeds max bucket %d"
-                         % (n, self.prompt_buckets[-1]))
+    def prefill_calls(self, prompt: np.ndarray, slot: int):
+        """One program for the whole prompt, padded to its bucket."""
+        n = int(prompt.shape[0])
+        t_b = self.engine.prompt_bucket(n)
+        tokens = np.zeros((t_b,), np.int32)
+        tokens[:n] = np.asarray(prompt, np.int32)
+        yield t_b, self.build_prefill, \
+            (tokens, np.int32(slot), np.int32(n)), \
+            {"chunk": t_b, "context": t_b}
 
-    def seq_bucket(self, needed: int) -> int:
-        for b in self.seq_buckets:
-            if needed <= b:
-                return b
-        raise MXNetError("sequence needs %d cache positions, max bucket %d"
-                         % (needed, self.seq_buckets[-1]))
+    def step_logits(self, fetched, s_b, pos, active) -> np.ndarray:
+        """What the decode program's fetched output holds for the
+        sampler, counted: here the logits as they came."""
+        if self.kernel_reads(s_b):
+            _profiler.incr_counter(self.engine.name
+                                   + "_decode_attn_kernel_steps")
+        return fetched
 
     # ---------------------------------------------------------- builders
     def _attention_full(self, q, k, v):
@@ -270,7 +288,7 @@ class DecodeEngine:
         att = att / jnp.sum(att, axis=-1, keepdims=True)
         return jnp.einsum("htk,hkd->htd", att, v)
 
-    def _build_prefill(self, t_b: int):
+    def build_prefill(self, t_b: int):
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -344,7 +362,7 @@ class DecodeEngine:
         return (deq(k, state[2][li, :, :, :pb]),
                 deq(v, state[3][li, :, :, :pb]))
 
-    def _kernel_reads(self, s_b: int) -> bool:
+    def kernel_reads(self, s_b: int) -> bool:
         """Whether bucket ``s_b``'s decode program reads the cache with
         the Pallas kernel: the float32 cache on one device, in key
         blocks the TPU can tile. int8 pages are dequantized by the XLA
@@ -354,7 +372,7 @@ class DecodeEngine:
         return (not self.cache.int8 and not self._multi_device
                 and block_for(s_b) % 8 == 0)
 
-    def _build_decode(self, s_b: int):
+    def build_decode(self, s_b: int):
         import jax
         import jax.numpy as jnp
         from ..ops.pallas.decode_attention import (block_for,
@@ -364,7 +382,7 @@ class DecodeEngine:
         int8 = self.cache.int8
         page = self.cache.page
         scale = 1.0 / np.sqrt(cfg.d_head)
-        kernel = self._kernel_reads(s_b)
+        kernel = self.kernel_reads(s_b)
 
         def write_i8(cache, scales, li, new, pos):
             # cache (L, slots, S, H*d) int8, scales (L, slots, H, pages),
@@ -459,6 +477,78 @@ class DecodeEngine:
 
         return jax.jit(fn, donate_argnums=(1,))
 
+
+def family_for(model, n_heads: Optional[int] = None,
+               arch: Optional[Dict[str, Any]] = None):
+    """The family that serves ``model``: the dense decoder when only
+    ``n_heads`` is told, else the one that claims ``arch``."""
+    if arch is None:
+        if n_heads is None:
+            raise ValueError("GenerativeServer needs n_heads (the dense "
+                             "decoder) or arch (a described block)")
+        return DenseDecoder(extract_params(model), n_heads)
+    from . import mla_moe           # it imports this module
+    if mla_moe.serves(arch):
+        return mla_moe.make(model, arch)
+    raise ValueError("GenerativeServer serves no model_type %r"
+                     % (arch.get("model_type"),))
+
+
+class DecodeEngine:
+    """The program table: builds, AOT-warm-starts and dispatches the
+    per-bucket prefill/decode executables of one ``family`` over one
+    :class:`KVCache` that holds the family's planes.
+
+    NOT thread-safe by design: every method runs on the owning
+    GenerativeServer's scheduler thread (the cache state tuple is
+    donated through each dispatch and re-bound from the result — a
+    second dispatcher would race the donation).
+    """
+
+    def __init__(self, family, cache, compile_cache, name: str = "serve",
+                 prompt_buckets: Optional[Sequence[int]] = None,
+                 seq_buckets: Optional[Sequence[int]] = None,
+                 prefill_chunk: int = 512):
+        self.family = family
+        self.params = family.params
+        self.cfg = family.cfg
+        self.cache = cache
+        self.compile_cache = compile_cache
+        self.name = name
+        self.prefill_chunk = int(prefill_chunk)
+        from .bucketing import decode_buckets as _ladder
+        self.seq_buckets: List[int] = list(
+            seq_buckets if seq_buckets is not None
+            else _ladder(cache.max_seq, cache.page))
+        self.prompt_buckets: List[int] = list(
+            prompt_buckets if prompt_buckets is not None
+            else self.seq_buckets)
+        for b in self.prompt_buckets:
+            if b % cache.page:
+                raise ValueError("prompt bucket %d not a multiple of the "
+                                 "kv page %d" % (b, cache.page))
+        # multi-device (sharded cache) programs are AOT-fenced exactly
+        # like the executor forward (aot_skip_multidevice)
+        self._multi_device = cache._sharding is not None
+        self.family.bind(self)
+
+    def executable_bound(self) -> int:
+        return self.family.executable_bound()
+
+    def prompt_bucket(self, n: int) -> int:
+        for b in self.prompt_buckets:
+            if n <= b:
+                return b
+        raise MXNetError("prompt of %d tokens exceeds max bucket %d"
+                         % (n, self.prompt_buckets[-1]))
+
+    def seq_bucket(self, needed: int) -> int:
+        for b in self.seq_buckets:
+            if needed <= b:
+                return b
+        raise MXNetError("sequence needs %d cache positions, max bucket %d"
+                         % (needed, self.seq_buckets[-1]))
+
     # ---------------------------------------------------------- dispatch
     def _sig_parts(self, kind: str, bucket: int) -> Tuple:
         shapes = tuple(sorted((k, tuple(v.shape), str(v.dtype))
@@ -467,7 +557,7 @@ class DecodeEngine:
         # stored for another layout is a miss, not a crash
         state = tuple((tuple(a.shape), str(a.dtype))
                       for a in self.cache.state())
-        return ("serve", kind, bucket, self.cfg.sig(), shapes, state,
+        return ("serve", kind, bucket, self.family.sig(), shapes, state,
                 self.cache.page, self.prefill_chunk)
 
     def _dispatch(self, kind: str, bucket: int, builder, args: Tuple):
@@ -515,18 +605,20 @@ class DecodeEngine:
         return out
 
     def prefill(self, prompt: np.ndarray, slot: int) -> np.ndarray:
-        """Run one prompt through its bucket's prefill program, writing
-        its K/V into ``slot``; returns the last real token's logits as
-        host numpy (the fetch is the device fence)."""
-        n = int(prompt.shape[0])
-        t_b = self.prompt_bucket(n)
-        tokens = np.zeros((t_b,), np.int32)
-        tokens[:n] = np.asarray(prompt, np.int32)
-        logits, new_state = self._dispatch(
-            "prefill", t_b, self._build_prefill,
-            (self.params, self.cache.state(), tokens,
-             np.int32(slot), np.int32(n)))
-        self.cache.set_state(new_state)
+        """Run one prompt through the family's prefill program(s), writing
+        its state into ``slot``: one program at the prompt's bucket, or a
+        chunk after a chunk, each appended to the cache and attending
+        over it. Returns the last real token's logits as host numpy (the
+        fetch is the device fence)."""
+        logits = None
+        for bucket, builder, args, attrs in self.family.prefill_calls(
+                prompt, slot):
+            with _profiler.span("gen_prefill_chunk", "serve", **attrs):
+                logits, new_state = self._dispatch(
+                    "prefill", bucket, builder,
+                    (self.params, self.cache.state()) + tuple(args))
+                self.cache.set_state(new_state)
+            _profiler.incr_counter(self.name + "_prefill_chunks")
         return np.asarray(logits)
 
     def decode_step(self, tokens: np.ndarray, pos: np.ndarray,
@@ -538,14 +630,14 @@ class DecodeEngine:
         s_b = self.seq_bucket(needed)
         with _profiler.span("gen_decode_dispatch", "serve"):
             logits, new_state = self._dispatch(
-                "decode", s_b, self._build_decode,
+                "decode", s_b, self.family.build_decode,
                 (self.params, self.cache.state(),
                  np.asarray(tokens, np.int32), np.asarray(pos, np.int32),
                  np.asarray(active, bool)))
         self.cache.set_state(new_state)
-        if self._kernel_reads(s_b):
-            _profiler.incr_counter(self.name + "_decode_attn_kernel_steps")
         # the fetch is the step's device fence: what the scheduler waits
-        # here is the step's device time and the copy of the logits
+        # here is the step's device time and the copy of the program's
+        # output (a family's own counts may ride in it: no second transfer)
         with _profiler.span("gen_logits_fetch", "serve"):
-            return np.asarray(logits)
+            out = np.asarray(logits)
+        return self.family.step_logits(out, s_b, pos, active)

@@ -31,6 +31,16 @@ its positions and its head's ``d_head`` lanes of the row, and
 dequantization is a reshape to ``(..., n_pages, page, H, d)`` times the
 broadcast scale.
 
+Planes. What the cache holds is described as planes, not as K and V: a
+:class:`Plane` is a name, a number of layers, a row (width and dtype) a
+position, and the cache is one ``(layers, slots, max_seq, width)`` array a
+plane, all planes under one ledger. The dense decoder's planes are ``k``
+and ``v`` (:func:`dense_planes`; int8 adds their two scale planes); a
+latent-attention block keeps one ``latent`` plane, a row ``[c_kv |
+k_rope]`` a position on every layer (``serve/mla_moe.py``).
+``hbm_bytes``, ``audit`` and :func:`max_slots_for` reckon from the
+planes.
+
 Budget audit: :meth:`KVCache.audit` runs the analyzer's
 ``hbm-budget`` reservation check (``analysis.memory_passes
 .check_reservation``) at server start — strict mode rejects an
@@ -48,25 +58,80 @@ from .. import lockcheck as _lockcheck
 from .. import profiler as _profiler
 from ..base import MXNetError
 
-__all__ = ["KVCache", "PageLedger", "CacheFull", "max_slots_for"]
+__all__ = ["KVCache", "PageLedger", "CacheFull", "Plane", "dense_planes",
+           "max_slots_for"]
 
 
 class CacheFull(MXNetError):
     """acquire() with every slot resident (callers queue, not error)."""
 
 
-def max_slots_for(budget_bytes: int, num_layers: int, n_heads: int,
-                  d_head: int, max_seq: int, page: int,
-                  int8: bool = False) -> int:
+class Plane:
+    """One kind of state the cache keeps for every position: ``layers``
+    arrays' worth of rows ``width`` wide in ``dtype``, as ``(layers, slots,
+    max_seq, width)``. ``tail`` overrides the last two axes (the int8
+    scale planes are ``(heads, pages)`` a slot), ``fill`` is the value an
+    untouched cache holds, ``kind`` the layout claim a sharded cache
+    places the plane by."""
+
+    __slots__ = ("name", "layers", "width", "dtype", "tail", "fill", "kind")
+
+    def __init__(self, name: str, layers: int, width: int, dtype: str,
+                 tail: Optional[Tuple[int, int]] = None, fill: float = 0.0,
+                 kind: str = "kv_cache"):
+        self.name = name
+        self.layers = int(layers)
+        self.width = int(width)
+        self.dtype = str(dtype)
+        self.tail = None if tail is None else (int(tail[0]), int(tail[1]))
+        self.fill = fill
+        self.kind = kind
+
+    def shape(self, max_slots: int, max_seq: int) -> Tuple[int, ...]:
+        tail = self.tail if self.tail is not None else (max_seq, self.width)
+        return (self.layers, int(max_slots)) + tuple(tail)
+
+    def bytes_per_slot(self, max_seq: int) -> int:
+        _l, _s, a, b = self.shape(1, max_seq)
+        return self.layers * a * b * _itemsize(self.dtype)
+
+    def describe(self) -> str:
+        if self.tail is not None:
+            return "%s %d layers x %s %s" % (self.name, self.layers,
+                                             "x".join(map(str, self.tail)),
+                                             self.dtype)
+        return "%s %d layers x rows of %d %s" % (self.name, self.layers,
+                                                 self.width, self.dtype)
+
+
+def _itemsize(dtype: str) -> int:
+    return {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}[dtype]
+
+
+def dense_planes(num_layers: int, n_heads: int, d_head: int, max_seq: int,
+                 page: int, int8: bool = False) -> List[Plane]:
+    """The dense decoder's planes: K and V rows of ``n_heads * d_head``,
+    float32, or int8 with one float32 scale per (head, page)."""
+    width = n_heads * d_head
+    if not int8:
+        return [Plane("k", num_layers, width, "float32"),
+                Plane("v", num_layers, width, "float32")]
+    scale = dict(tail=(n_heads, max_seq // page), fill=1.0, kind="kv_scale")
+    # scales start at 1: dequantizing an untouched (zero) page stays zero,
+    # and the requantize-on-write max() never sees 0
+    return [Plane("k", num_layers, width, "int8"),
+            Plane("v", num_layers, width, "int8"),
+            Plane("k_scale", num_layers, 0, "float32", **scale),
+            Plane("v_scale", num_layers, 0, "float32", **scale)]
+
+
+def max_slots_for(budget_bytes: int, planes: List[Plane],
+                  max_seq: int) -> int:
     """Largest ``max_slots`` whose cache reservation fits the budget —
     the capacity-planning inverse of :meth:`KVCache.hbm_bytes` (the two
-    are consistency-tested against each other)."""
-    per_slot = 2 * num_layers * n_heads * max_seq * d_head  # K and V elems
-    if int8:
-        bytes_slot = per_slot * 1 \
-            + 2 * num_layers * n_heads * (max_seq // page) * 4
-    else:
-        bytes_slot = per_slot * 4
+    are consistency-tested against each other), reckoned from the
+    planes (:func:`dense_planes` gives the dense decoder's)."""
+    bytes_slot = sum(p.bytes_per_slot(max_seq) for p in planes)
     return max(0, int(budget_bytes) // bytes_slot)
 
 
@@ -186,51 +251,42 @@ class PageLedger:
 
 
 class KVCache:
-    """The device-resident cache blocks + the ledger + the gauges.
+    """The device-resident cache planes + the ledger + the gauges.
 
-    ``state()``/``set_state()`` expose the arrays as a flat tuple so the
-    jitted prefill/decode programs take and return them as donated
-    operands (double-buffer-free in-place update, the fused-step
-    discipline). f32 state is ``(k, v)``; int8 adds the scale planes:
-    ``(k, v, k_scale, v_scale)``.
+    ``planes`` says what the cache holds (a family's ``planes()``;
+    :func:`dense_planes` for the dense decoder). ``state()``/
+    ``set_state()`` expose the arrays as a flat tuple, in the planes'
+    order, so the jitted prefill/decode programs take and return them as
+    donated operands (double-buffer-free in-place update, the fused-step
+    discipline). The dense decoder's f32 state is ``(k, v)``; int8 adds
+    the scale planes: ``(k, v, k_scale, v_scale)``.
     """
 
-    def __init__(self, num_layers: int, n_heads: int, d_head: int,
-                 max_slots: int, max_seq: int, page: Optional[int] = None,
-                 int8: Optional[bool] = None, name: str = "serve",
+    def __init__(self, planes: List[Plane], max_slots: int, max_seq: int,
+                 page: Optional[int] = None, name: str = "serve",
                  mesh=None, layout=None):
         from .. import config as _config
         import jax.numpy as jnp
         self.page = int(page if page is not None
                         else _config.get("MXNET_TPU_SERVE_KV_PAGE"))
-        self.int8 = bool(_config.get("MXNET_TPU_SERVE_KV_INT8")
-                         if int8 is None else int8)
-        self.num_layers = int(num_layers)
-        self.n_heads = int(n_heads)
-        self.d_head = int(d_head)
         self.max_slots = int(max_slots)
         self.max_seq = int(max_seq)
         self.name = name
         self.ledger = PageLedger(self.max_slots, self.max_seq, self.page)
         self.n_pages = self.max_seq // self.page
-        shape = (self.num_layers, self.max_slots, self.max_seq,
-                 self.n_heads * self.d_head)
-        sshape = (self.num_layers, self.max_slots, self.n_heads,
-                  self.n_pages)
+        self.planes: List[Plane] = list(planes)
+        self.int8 = any(p.dtype == "int8" for p in self.planes)
         self._sharding = self._resolve_sharding(mesh, layout)
-        kv_dtype = jnp.int8 if self.int8 else jnp.float32
-        self.k = self._place(jnp.zeros(shape, kv_dtype), "kv_cache")
-        self.v = self._place(jnp.zeros(shape, kv_dtype), "kv_cache")
-        if self.int8:
-            # scales start at 1: dequantizing an untouched (zero) page
-            # stays zero, and the requantize-on-write max() never sees 0
-            self.k_scale = self._place(jnp.ones(sshape, jnp.float32),
-                                       "kv_scale")
-            self.v_scale = self._place(jnp.ones(sshape, jnp.float32),
-                                       "kv_scale")
-        else:
-            self.k_scale = self.v_scale = None
+        self._arrays: Dict[str, Any] = {
+            p.name: self._place(
+                jnp.full(p.shape(self.max_slots, self.max_seq), p.fill,
+                         jnp.dtype(p.dtype)), p.kind)
+            for p in self.planes}
         self._update_gauges()
+
+    def plane(self, name: str):
+        """The array of the plane ``name``."""
+        return self._arrays[name]
 
     # ---------------------------------------------------------- sharding
     def _resolve_sharding(self, mesh, layout):
@@ -256,20 +312,16 @@ class KVCache:
 
     # ------------------------------------------------------------- state
     def state(self) -> Tuple:
-        if self.int8:
-            return (self.k, self.v, self.k_scale, self.v_scale)
-        return (self.k, self.v)
+        return tuple(self._arrays[p.name] for p in self.planes)
 
     def set_state(self, state: Tuple) -> None:
-        if self.int8:
-            self.k, self.v, self.k_scale, self.v_scale = state
-        else:
-            self.k, self.v = state
+        for p, arr in zip(self.planes, state):
+            self._arrays[p.name] = arr
 
     def hbm_bytes(self) -> int:
-        """The reservation's device footprint (K + V + scale planes)."""
-        n = sum(int(a.size) * a.dtype.itemsize for a in self.state())
-        return n
+        """The reservation's device footprint, every plane's."""
+        return sum(p.bytes_per_slot(self.max_seq) for p in self.planes) \
+            * self.max_slots
 
     # ----------------------------------------------------------- lifecycle
     def acquire(self, length: int) -> Optional[int]:
@@ -306,10 +358,8 @@ class KVCache:
             return {"budget_bytes": 0, "reserved_bytes": self.hbm_bytes(),
                     "fits": True}
         from ..analysis.memory_passes import check_reservation
-        detail = ("serve KV cache %s: %d layers x %d slots x %d seq x "
-                  "(%d heads x %d d_head) rows, %s"
-                  % (self.name, self.num_layers, self.max_slots,
-                     self.max_seq, self.n_heads,
-                     self.d_head, "int8+scales" if self.int8 else "f32"))
+        detail = ("serve KV cache %s: %d slots x %d seq of planes %s"
+                  % (self.name, self.max_slots, self.max_seq,
+                     "; ".join(p.describe() for p in self.planes)))
         return check_reservation("%s_kv_cache" % self.name,
                                  self.hbm_bytes(), detail=detail)

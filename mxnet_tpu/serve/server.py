@@ -884,8 +884,15 @@ class GenerativeServer:
     model : Module | (arg, aux) | dict
         The zoo-transformer parameter source
         (:func:`~mxnet_tpu.serve.decode.extract_params` naming).
-    n_heads : int
-        Attention head count (not shape-derivable).
+    n_heads : int, optional
+        Attention head count of the dense decoder (not shape-derivable).
+    arch : dict, optional
+        The architecture's description, for a block that parameter names
+        and ``n_heads`` do not tell: the published configuration's keys,
+        ``model_type`` among them, with the share this chip holds and
+        the ``dtype`` the weights and the cache are held in. The family
+        whose module claims it serves it (``decode.family_for``);
+        without it the dense decoder is served.
     max_sequences : int, optional
         Resident decode sequences = preallocated KV slots (default
         ``MXNET_TPU_SERVE_MAX_SEQUENCES``).
@@ -909,7 +916,7 @@ class GenerativeServer:
     zero-cost gate asserts ``mxnet_tpu.serve.decode`` stays unimported.
     """
 
-    def __init__(self, model, n_heads: int,
+    def __init__(self, model, n_heads: Optional[int] = None,
                  max_sequences: Optional[int] = None,
                  int8: Optional[bool] = None, page: Optional[int] = None,
                  prefill_tokens: Optional[int] = None,
@@ -918,15 +925,16 @@ class GenerativeServer:
                  prefill_chunk: int = 512,
                  name: str = "serve_gen",
                  metrics_port: Optional[int] = None,
-                 mesh=None, layout=None):
+                 mesh=None, layout=None,
+                 arch: Optional[Dict[str, Any]] = None):
         from .. import config as _config
         from .kv_cache import KVCache                       # lazy: the
-        from .decode import (DecodeEngine, extract_params,  # zero-cost
-                             config_from_params, sample_token)  # gate
+        from .decode import (DecodeEngine, family_for,      # zero-cost
+                             sample_token)                  # gate
         self.name = name
         self._sample_token = sample_token
-        params = extract_params(model)
-        cfg = config_from_params(params, n_heads)
+        family = family_for(model, n_heads=n_heads, arch=arch)
+        cfg = family.cfg
         self.max_sequences = int(
             max_sequences if max_sequences is not None
             else _config.get("MXNET_TPU_SERVE_MAX_SEQUENCES"))
@@ -935,16 +943,19 @@ class GenerativeServer:
             else _config.get("MXNET_TPU_SERVE_PREFILL_TOKENS"))
         self.queue_bound = (queue_bound if queue_bound is not None else
                             _config.get("MXNET_TPU_SERVE_QUEUE_BOUND"))
+        pg = int(page if page is not None
+                 else _config.get("MXNET_TPU_SERVE_KV_PAGE"))
+        i8 = bool(_config.get("MXNET_TPU_SERVE_KV_INT8")
+                  if int8 is None else int8)
         spec = _config.get("MXNET_TPU_SERVE_DECODE_BUCKETS")
         if seq_buckets is None and spec:
             from .bucketing import decode_buckets
-            pg = int(page if page is not None
-                     else _config.get("MXNET_TPU_SERVE_KV_PAGE"))
             seq_buckets = decode_buckets(cfg.max_seq, pg, spec)
-        self.cache = KVCache(cfg.num_layers, cfg.n_heads, cfg.d_head,
-                             self.max_sequences, cfg.max_seq, page=page,
-                             int8=int8, name=name, mesh=mesh,
-                             layout=layout)
+        # the family says which planes its state lives in
+        self.cache = KVCache(family.planes(cfg.max_seq, pg, i8),
+                             max_slots=self.max_sequences,
+                             max_seq=cfg.max_seq, page=pg, name=name,
+                             mesh=mesh, layout=layout)
         # hbm-budget audit of the reservation at server START — strict
         # analyze mode rejects an over-budget cache naming it, before
         # the first request ever lands
@@ -953,7 +964,7 @@ class GenerativeServer:
         self.compile_cache = CompileCache(name,
                                           max_entries=max(grid_bound, 128))
         self.engine = DecodeEngine(
-            params, n_heads, self.cache, self.compile_cache, name=name,
+            family, self.cache, self.compile_cache, name=name,
             seq_buckets=seq_buckets, prefill_chunk=prefill_chunk)
         from .stats import DecodeLatencyStats
         self.latency = DecodeLatencyStats(name=name)

@@ -16,7 +16,17 @@ import mxnet_tpu as mx
 from mxnet_tpu import config as cfg
 from mxnet_tpu import profiler
 from mxnet_tpu.base import MXNetError
-from mxnet_tpu.serve.kv_cache import (KVCache, PageLedger, max_slots_for)
+from mxnet_tpu.serve.kv_cache import (KVCache, PageLedger, dense_planes,
+                                      max_slots_for)
+
+
+def _dense_cache(num_layers, n_heads, d_head, max_slots, max_seq, page,
+                 int8=False, name="serve"):
+    """A cache of the dense decoder's planes."""
+    return KVCache(dense_planes(num_layers, n_heads, d_head, max_seq, page,
+                                int8),
+                   max_slots=max_slots, max_seq=max_seq, page=page,
+                   name=name)
 
 
 # ------------------------------------------------------------ ledger unit
@@ -112,8 +122,8 @@ def test_ledger_property_randomized_interleavings():
 def test_cache_gauges_match_ledger_exactly():
     """The occupancy gauges the server exports ARE the host model —
     asserted equal after every mutation."""
-    cache = KVCache(num_layers=1, n_heads=2, d_head=4, max_slots=3,
-                    max_seq=8, page=4, int8=False, name="gaugetest")
+    cache = _dense_cache(num_layers=1, n_heads=2, d_head=4, max_slots=3,
+                         max_seq=8, page=4, name="gaugetest")
     rng = np.random.RandomState(3)
     live = []
     for _ in range(60):
@@ -143,11 +153,13 @@ def test_max_slots_for_inverts_hbm_bytes():
     for int8 in (False, True):
         geo = dict(num_layers=2, n_heads=2, d_head=8, max_seq=32, page=8)
         budget = 600_000
-        slots = max_slots_for(budget, int8=int8, **geo)
+        slots = max_slots_for(budget, dense_planes(int8=int8, **geo),
+                              geo["max_seq"])
         assert slots >= 1
-        cache = KVCache(max_slots=slots, int8=int8, name="cap", **geo)
+        cache = _dense_cache(max_slots=slots, int8=int8, name="cap", **geo)
         assert cache.hbm_bytes() <= budget
-        bigger = KVCache(max_slots=slots + 1, int8=int8, name="cap2", **geo)
+        bigger = _dense_cache(max_slots=slots + 1, int8=int8, name="cap2",
+                              **geo)
         assert bigger.hbm_bytes() > budget
 
 
@@ -157,16 +169,20 @@ def test_cache_is_one_row_a_position(int8):
     d_head)`` — a lane-dense row a position, heads side by side — and
     the int8 scale planes stay ``(layers, slots, heads, pages)``; the
     footprint is what the head-major layout reserved."""
-    cache = KVCache(num_layers=3, n_heads=4, d_head=16, max_slots=5,
-                    max_seq=64, page=16, int8=int8, name="rows")
-    assert cache.k.shape == cache.v.shape == (3, 5, 64, 4 * 16)
+    cache = _dense_cache(num_layers=3, n_heads=4, d_head=16, max_slots=5,
+                         max_seq=64, page=16, int8=int8, name="rows")
+    k, v = cache.plane("k"), cache.plane("v")
+    assert k.shape == v.shape == (3, 5, 64, 4 * 16)
     elems = 2 * 3 * 5 * 64 * 4 * 16
+    assert cache.int8 is int8
     if int8:
-        assert cache.k.dtype == np.int8
-        assert cache.k_scale.shape == cache.v_scale.shape == (3, 5, 4, 4)
+        assert k.dtype == np.int8
+        assert cache.plane("k_scale").shape \
+            == cache.plane("v_scale").shape == (3, 5, 4, 4)
         assert cache.hbm_bytes() == elems + 2 * 3 * 5 * 4 * 4 * 4
     else:
-        assert cache.k.dtype == np.float32 and cache.k_scale is None
+        assert k.dtype == np.float32
+        assert [p.name for p in cache.planes] == ["k", "v"]
         assert cache.hbm_bytes() == elems * 4
 
 
@@ -176,8 +192,10 @@ def test_int8_doubles_resident_sequences():
     planes claw a little back)."""
     geo = dict(num_layers=2, n_heads=4, d_head=16, max_seq=64, page=16)
     budget = 4 * 1024 * 1024
-    f32_slots = max_slots_for(budget, int8=False, **geo)
-    i8_slots = max_slots_for(budget, int8=True, **geo)
+    f32_slots = max_slots_for(budget, dense_planes(int8=False, **geo),
+                              geo["max_seq"])
+    i8_slots = max_slots_for(budget, dense_planes(int8=True, **geo),
+                             geo["max_seq"])
     assert f32_slots >= 1
     assert i8_slots >= 2 * f32_slots
 
@@ -189,8 +207,8 @@ def test_audit_zero_cost_when_analyze_off(monkeypatch):
     code = (
         "import sys\n"
         "import mxnet_tpu  # noqa: F401\n"
-        "from mxnet_tpu.serve.kv_cache import KVCache\n"
-        "c = KVCache(1, 2, 4, 2, 8, page=4, int8=False, name='zc')\n"
+        "from mxnet_tpu.serve.kv_cache import KVCache, dense_planes\n"
+        "c = KVCache(dense_planes(1, 2, 4, 8, 4), 2, 8, page=4, name='zc')\n"
         "out = c.audit()\n"
         "assert out['fits'] is True\n"
         "assert not any(m.startswith('mxnet_tpu.analysis')\n"
@@ -210,8 +228,8 @@ def test_audit_strict_rejects_naming_reservation(monkeypatch):
     cfg.reset("MXNET_TPU_ANALYZE")
     cfg.reset("MXNET_TPU_ANALYZE_HBM_BUDGET")
     try:
-        cache = KVCache(num_layers=2, n_heads=2, d_head=8, max_slots=4,
-                        max_seq=32, page=8, int8=False, name="rej")
+        cache = _dense_cache(num_layers=2, n_heads=2, d_head=8, max_slots=4,
+                             max_seq=32, page=8, name="rej")
         with pytest.raises(MXNetError) as err:
             cache.audit()
         msg = str(err.value)
@@ -231,8 +249,8 @@ def test_audit_warn_fits_under_big_budget(monkeypatch):
     cfg.reset("MXNET_TPU_ANALYZE")
     cfg.reset("MXNET_TPU_ANALYZE_HBM_BUDGET")
     try:
-        cache = KVCache(num_layers=1, n_heads=2, d_head=4, max_slots=2,
-                        max_seq=8, page=4, int8=False, name="fits")
+        cache = _dense_cache(num_layers=1, n_heads=2, d_head=4, max_slots=2,
+                             max_seq=8, page=4, name="fits")
         out = cache.audit()
         assert out["fits"] is True
         assert out["reserved_bytes"] == cache.hbm_bytes()

@@ -68,19 +68,25 @@ def _server(module, **kw):
     return GenerativeServer(module, n_heads=HEADS, **kw)
 
 
+def _dense_engine(params, slots, name, page=4, int8=False):
+    """The dense decoder's engine over its own planes: (engine, cache)."""
+    from mxnet_tpu._fused import CompileCache
+    from mxnet_tpu.serve.decode import DecodeEngine, DenseDecoder
+    from mxnet_tpu.serve.kv_cache import KVCache
+    family = DenseDecoder(params, HEADS)
+    cache = KVCache(family.planes(SEQ, page, int8), max_slots=slots,
+                    max_seq=SEQ, page=page, name=name)
+    return DecodeEngine(family, cache, CompileCache(name), name=name), cache
+
+
 # ------------------------------------------------------------- correctness
 
 def test_prefill_logits_match_module_forward(module):
     """The decode engine's prefill IS the model: softmax at the last
     real prompt position matches the bucket-padded Module forward."""
-    from mxnet_tpu._fused import CompileCache
-    from mxnet_tpu.serve.decode import DecodeEngine, extract_params
-    from mxnet_tpu.serve.kv_cache import KVCache
+    from mxnet_tpu.serve.decode import extract_params
     params = extract_params(module)
-    cache = KVCache(LAYERS, HEADS, DMODEL // HEADS, 2, SEQ, page=4,
-                    int8=False, name="parity")
-    eng = DecodeEngine(params, HEADS, cache, CompileCache("parity"),
-                       name="parity")
+    eng, cache = _dense_engine(params, 2, "parity")
     for prompt in ([3, 11, 7, 2, 9], [1], [5] * 15):
         slot = cache.acquire(len(prompt))
         logits = eng.prefill(np.array(prompt), slot)
@@ -93,14 +99,9 @@ def test_prefill_logits_match_module_forward(module):
 def test_decode_steps_match_full_forward(module):
     """Incremental KV decode == full re-forward at every step (greedy
     tokens identical, probabilities within f32 tolerance)."""
-    from mxnet_tpu._fused import CompileCache
-    from mxnet_tpu.serve.decode import DecodeEngine, extract_params
-    from mxnet_tpu.serve.kv_cache import KVCache
+    from mxnet_tpu.serve.decode import extract_params
     params = extract_params(module)
-    cache = KVCache(LAYERS, HEADS, DMODEL // HEADS, 2, SEQ, page=4,
-                    int8=False, name="steps")
-    eng = DecodeEngine(params, HEADS, cache, CompileCache("steps"),
-                       name="steps")
+    eng, cache = _dense_engine(params, 2, "steps")
     prompt = [3, 11, 7, 2, 9]
     slot = cache.acquire(len(prompt))
     seq = list(prompt) + [int(np.argmax(eng.prefill(np.array(prompt),
@@ -166,18 +167,13 @@ def test_decode_append_lands_in_its_rows_and_nowhere_else(module, int8):
     each slot and nowhere else (int8: within that row's page, whose
     scale may grow), and what an active slot's row holds is the token's
     K and V: what a prefill of the same tokens writes there."""
-    from mxnet_tpu._fused import CompileCache
-    from mxnet_tpu.serve.decode import DecodeEngine, extract_params
-    from mxnet_tpu.serve.kv_cache import KVCache
+    from mxnet_tpu.serve.decode import extract_params
     page, slots, free = 4, 5, 4
     where = {0: 0, 1: 6, 2: 7, 3: SEQ - 1}
     params = extract_params(module)
 
     def engine(name):
-        cache = KVCache(LAYERS, HEADS, DMODEL // HEADS, slots, SEQ,
-                        page=page, int8=int8, name=name)
-        return DecodeEngine(params, HEADS, cache, CompileCache(name),
-                            name=name), cache
+        return _dense_engine(params, slots, name, page=page, int8=int8)
 
     eng, cache = engine("append%d" % int8)
     ref_eng, ref_cache = engine("append_ref%d" % int8)
@@ -296,9 +292,7 @@ def test_decode_program_reads_with_the_kernel_where_it_can(module):
     device in TPU-tileable buckets takes the kernel (counted as
     ``_decode_attn_kernel_steps``), int8 keeps the XLA read; both give
     the logits of the XLA read on a ragged step with free slots."""
-    from mxnet_tpu._fused import CompileCache
-    from mxnet_tpu.serve.decode import DecodeEngine, extract_params
-    from mxnet_tpu.serve.kv_cache import KVCache
+    from mxnet_tpu.serve.decode import extract_params
     params = extract_params(module)
     pos, active = _ragged_step(8)
     rng = np.random.default_rng(9)
@@ -307,12 +301,9 @@ def test_decode_program_reads_with_the_kernel_where_it_can(module):
     tokens = np.where(active, rng.integers(1, VOCAB, 8), 0).astype(np.int32)
 
     def logits(name, int8=False, xla_read=False):
-        cache = KVCache(LAYERS, HEADS, DMODEL // HEADS, 8, SEQ, page=4,
-                        int8=int8, name=name)
-        eng = DecodeEngine(params, HEADS, cache, CompileCache(name),
-                           name=name)
+        eng, cache = _dense_engine(params, 8, name, int8=int8)
         if xla_read:
-            eng._kernel_reads = lambda s_b: False
+            eng.family.kernel_reads = lambda s_b: False
         for slot, prompt in prompts.items():
             eng.prefill(prompt, slot)
         steps = profiler.get_counter(name + "_decode_attn_kernel_steps")
@@ -333,16 +324,11 @@ def test_decode_program_reads_with_the_kernel_where_it_can(module):
 def test_aot_key_carries_the_cache_layout(module):
     """An executable stored under another cache layout is a miss, not a
     crash: the AOT key's parts hold the state's shapes and dtypes."""
-    from mxnet_tpu._fused import CompileCache
-    from mxnet_tpu.serve.decode import DecodeEngine, extract_params
-    from mxnet_tpu.serve.kv_cache import KVCache
+    from mxnet_tpu.serve.decode import extract_params
     params = extract_params(module)
 
     def parts(**kw):
-        cache = KVCache(LAYERS, HEADS, DMODEL // HEADS, 2, SEQ, page=4,
-                        name="sig", **kw)
-        eng = DecodeEngine(params, HEADS, cache, CompileCache("sig"),
-                           name="sig")
+        eng, _cache = _dense_engine(params, 2, "sig", **kw)
         return eng._sig_parts("decode", 8)
 
     f32, int8 = parts(int8=False), parts(int8=True)

@@ -8,6 +8,14 @@ the KV cache, which no CPU test can see: that the donated cache is
 updated where it lies, and that no step moves a layer's whole slab —
 the program this guards against read 50 GB a step whatever the bucket.
 Nothing here is a time: a compile that passes is not a chip run.
+
+ISSUE 28 parted the engine into a generic half and the families it serves.
+The dense decoder's programs are pinned to the bytes they cost before
+that, so that ``opt13_serve_chat`` cannot drift; and the second family's
+decode step (latent attention over a latent plane, routed experts) is
+compiled at the published widths, where the compiler keeps a 576-wide
+latent plane positions-minor and copies all of it around every append
+(found by PR 27): rows are stored 640 wide.
 """
 import re
 import types
@@ -96,13 +104,14 @@ def for_the_chip(monkeypatch):
 
 def _compile_decode(one_chip, s_b, int8):
     import jax.numpy as jnp
-    from mxnet_tpu.serve.decode import DecodeEngine
+    from mxnet_tpu.serve.decode import DecodeEngine, DenseDecoder
     params, sd = _shapes(one_chip)
     state = _state(sd, int8)
     cache = types.SimpleNamespace(int8=int8, page=PAGE, max_seq=MAX_SEQ,
                                   max_slots=SLOTS, _sharding=None)
-    eng = DecodeEngine(params, HEADS, cache, None, seq_buckets=[s_b])
-    compiled = eng._build_decode(s_b).lower(
+    eng = DecodeEngine(DenseDecoder(params, HEADS), cache, None,
+                       seq_buckets=[s_b])
+    compiled = eng.family.build_decode(s_b).lower(
         params, state, sd(SLOTS, dtype=jnp.int32),
         sd(SLOTS, dtype=jnp.int32), sd(SLOTS, dtype=jnp.bool_)).compile()
     return compiled, params, state
@@ -166,14 +175,15 @@ def test_prefill_writes_its_rows_in_place(one_chip):
     sliced out of one fused projection were strided copies, and cost
     three layers' FFN fusions their tiling at this bucket)."""
     import jax.numpy as jnp
-    from mxnet_tpu.serve.decode import DecodeEngine
+    from mxnet_tpu.serve.decode import DecodeEngine, DenseDecoder
     t_b = 256
     params, sd = _shapes(one_chip)
     state = _state(sd)
     cache = types.SimpleNamespace(int8=False, page=PAGE, max_seq=MAX_SEQ,
                                   max_slots=SLOTS, _sharding=None)
-    eng = DecodeEngine(params, HEADS, cache, None, seq_buckets=[t_b])
-    compiled = eng._build_prefill(t_b).lower(
+    eng = DecodeEngine(DenseDecoder(params, HEADS), cache, None,
+                       seq_buckets=[t_b])
+    compiled = eng.family.build_prefill(t_b).lower(
         params, state, sd(t_b, dtype=jnp.int32), sd(dtype=jnp.int32),
         sd(dtype=jnp.int32)).compile()
     assert compiled.memory_analysis().alias_size_in_bytes == _nbytes(state)
@@ -208,3 +218,111 @@ def test_decode_attention_kernel_compiles_at_real_widths(one_chip,
         sd(SLOTS, dtype=jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+# bytes accessed, as the parent of ISSUE 28 compiled these programs (the
+# engine before it was parted; read there with this file's own helpers)
+_BEFORE_PARTING = {("decode", False, 512): 1676829696,
+                   ("decode", False, 1536): 1676829696,
+                   ("decode", True, 512): 2855140864,
+                   ("prefill", False, 256): 1812728832}
+
+
+@pytest.mark.parametrize("kind,int8,bucket", sorted(_BEFORE_PARTING))
+def test_dense_programs_cost_what_they_cost_before_the_engine_was_parted(
+        one_chip, for_the_chip, kind, int8, bucket):
+    import jax.numpy as jnp
+    from mxnet_tpu.serve.decode import DecodeEngine, DenseDecoder
+    if kind == "decode":
+        compiled, _params, _st = _compile_decode(one_chip, bucket, int8)
+    else:
+        params, sd = _shapes(one_chip)
+        cache = types.SimpleNamespace(int8=int8, page=PAGE, max_seq=MAX_SEQ,
+                                      max_slots=SLOTS, _sharding=None)
+        eng = DecodeEngine(DenseDecoder(params, HEADS), cache, None,
+                           seq_buckets=[bucket])
+        compiled = eng.family.build_prefill(bucket).lower(
+            params, _state(sd, int8), sd(bucket, dtype=jnp.int32),
+            sd(dtype=jnp.int32), sd(dtype=jnp.int32)).compile()
+    read = int(compiled.cost_analysis()["bytes accessed"])
+    assert read == _BEFORE_PARTING[(kind, int8, bucket)]
+
+
+def _mla_moe_arch(max_seq):
+    """Two layers of the second family at the published widths: the
+    leading dense layer and one sparse layer, 16 of 128 experts held."""
+    return {"model_type": "sarvam_mla", "hidden_size": 4096,
+            "num_attention_heads": 64, "kv_lora_rank": 512,
+            "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+            "v_head_dim": 128, "intermediate_size": 16384,
+            "moe_intermediate_size": 2048, "num_shared_experts": 1,
+            "num_experts": 128, "experts_held": [0, 16],
+            "num_experts_per_tok": 8, "routed_scaling_factor": 2.5,
+            "rms_norm_eps": 1e-6, "num_hidden_layers": 2,
+            "first_k_dense_replace": 1, "rope_theta": 10000,
+            "rope_scaling": {"type": "deepseek_yarn", "factor": 40,
+                             "original_max_position_embeddings": 4096,
+                             "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                             "mscale_all_dim": 1},
+            "max_position_embeddings": max_seq, "vocab_size": 32768,
+            "dtype": "bfloat16"}
+
+
+def _mla_moe_family(one_chip, slots, max_seq):
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.models import mla_moe as layer
+    from mxnet_tpu.serve.mla_moe import MlaMoeDecoder
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    arch = layer.Arch(_mla_moe_arch(max_seq))
+    params = {n: sd(*shape) for n, shape in layer.param_shapes(arch).items()}
+    family = MlaMoeDecoder.__new__(MlaMoeDecoder)
+    family.arch = family.cfg = arch
+    state = tuple(sd(*p.shape(slots, max_seq))
+                  for p in family.planes(max_seq, 128, False))
+    return family, params, state, sd
+
+
+def test_latent_cache_is_appended_in_place_at_published_widths(one_chip,
+                                                               for_the_chip):
+    """The second family's decode step over 48 slots of 4096 at bucket
+    4096: the plane aliased whole, next to no temporaries, the grouped
+    products Mosaic kernels, and a step reads the weights and the bucket's
+    rows (for the scores and again for the mix), never a copy of the
+    plane."""
+    import jax.numpy as jnp
+    slots, max_seq, s_b = 48, 4096, 4096
+    family, params, state, sd = _mla_moe_family(one_chip, slots, max_seq)
+    assert state[0].shape == (2, slots, max_seq, 640)
+    compiled = family.build_decode(s_b).lower(
+        params, state, sd(slots, dtype=jnp.int32),
+        sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(state)
+    assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
+    assert "tpu_custom_call" in compiled.as_text()
+    # the cost model reads 6 planes beside the weights (scores and mix
+    # read the bucket's rows, here all of them, and an in-place append is
+    # charged its operand); the positions-minor layout cost ten times the
+    # plane a layer, twenty here
+    read = compiled.cost_analysis()["bytes accessed"]
+    allowed = _nbytes(params) + 8 * _nbytes(state)
+    assert read < allowed, (read, allowed)
+
+
+def test_a_prefill_chunk_appends_in_place_and_fits_beside_the_weights(
+        one_chip, for_the_chip):
+    """A chunk of 1024 over a context of 2048, per head over keys and
+    values expanded from the latent: the plane aliased whole, and the
+    temporaries (a block of queries' scores) under a gigabyte, which is
+    what the 16 GB chip has left beside 9.5 GB of weights and the cache."""
+    import jax.numpy as jnp
+    family, params, state, sd = _mla_moe_family(one_chip, 48, 4096)
+    compiled = family.build_prefill((1024, 2048)).lower(
+        params, state, sd(1024, dtype=jnp.int32), sd(dtype=jnp.int32),
+        sd(dtype=jnp.int32), sd(dtype=jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(state)
+    assert mem.temp_size_in_bytes < 1e9, mem.temp_size_in_bytes

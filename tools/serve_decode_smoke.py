@@ -262,11 +262,11 @@ def check_aot_warm_restart():
     print("PASS aot warm restart: first token with 0 backend compiles "
           "(cold run had %d)" % cold["backend_compiles"])
 
-    from mxnet_tpu.serve.kv_cache import max_slots_for
+    from mxnet_tpu.serve.kv_cache import dense_planes, max_slots_for
     geo = dict(num_layers=4, n_heads=8, d_head=64, max_seq=2048, page=16)
     budget = 8 * 1024 ** 3
-    f32 = max_slots_for(budget, int8=False, **geo)
-    i8 = max_slots_for(budget, int8=True, **geo)
+    f32 = max_slots_for(budget, dense_planes(int8=False, **geo), 2048)
+    i8 = max_slots_for(budget, dense_planes(int8=True, **geo), 2048)
     assert i8 >= 2 * f32, (f32, i8)
     print("PASS int8 capacity: %d -> %d resident sequences under the "
           "same budget" % (f32, i8))
