@@ -324,11 +324,13 @@ def phase_serve(mod):
         logp = np.log(np.asarray(jax.device_get(probs[i, len(p) - 1]),
                                  np.float64) + 1e-30)
         slot = srv.cache.acquire(len(p))
-        logits = np.asarray(srv.engine.prefill(p, slot), np.float64)
+        picked, logits = srv.engine.prefill(p, slot, logits=True)
+        logits = np.asarray(logits, np.float64)
         srv.cache.release(slot)
-        check(int(logits.argmax()) == out[i][0],
+        check(picked == int(logits.argmax()) == out[i][0],
               "prompt of %d tokens: the server answered %d, its prefill "
-              "logits say %d" % (len(p), out[i][0], int(logits.argmax())))
+              "picked %d, its logits say %d"
+              % (len(p), out[i][0], picked, int(logits.argmax())))
         served = logits - logits.max()
         served -= np.log(np.exp(served).sum())
         err = float(np.linalg.norm(served - logp)

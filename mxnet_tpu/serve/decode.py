@@ -15,7 +15,7 @@ decode is a counter-asserted zero-recompile regime:
   ``[layer, slot, 0:T_b, :]``; the state operand is donated, so the
   update is in-place on TPU), and returns only the last real token's
   logits (one ``(D,)`` row through the LM head, not a ``(T_b, V)``
-  matmul).
+  matmul) and, beside them, their argmax.
 * **decode** — ONE jitted step per sequence bucket ``S_b`` over the
   WHOLE slot array: embed the freshest token of every resident
   sequence, append its K/V row at the per-slot write position with one
@@ -23,7 +23,14 @@ decode is a counter-asserted zero-recompile regime:
   (``K.at[layer, arange(slots), pos].set(k_new)``; finished/empty slots
   write into reclaimed space that the next prefill overwrites — a
   masked no-op by construction), attend to rows ``[0:S_b]`` with
-  per-slot length masking, and return ``(slots, V)`` logits.
+  per-slot length masking, and return ``(slots, V)`` logits and their
+  ``(slots,)`` argmax.
+
+Every program returns ``(picked, logits, state)``: the greedy token is
+chosen inside the program, over the float32 logits the host would see,
+and the engine fetches ``picked`` alone. The logits leave the device only
+for a sequence that samples (``temperature > 0``): the scheduler says so
+step by step.
 
 The cache is ``(layers, slots, max_seq, H*d)`` (``kv_cache.py`` says
 why): no program takes a layer's slab out of it or puts one back. The
@@ -73,8 +80,8 @@ from ..base import MXNetError
 from ..obs import compiles as _obs_compiles
 
 __all__ = ["DecodeConfig", "DecodeEngine", "DenseDecoder", "extract_params",
-           "config_from_params", "sample_token",
-           "family_for"]
+           "config_from_params", "sample_token", "PickedRow",
+           "greedy_tokens", "family_for"]
 
 _LN_EPS = 1e-5          # ops/nn.py layer_norm default
 
@@ -162,13 +169,31 @@ def config_from_params(params: Dict[str, Any],
                         int(vocab), int(max_seq))
 
 
-def sample_token(logits: np.ndarray, temperature: float = 0.0,
+class PickedRow:
+    """A row of logits that stayed on the device: the host has its width
+    and its argmax, taken inside the program (:func:`greedy_tokens`).
+    What :func:`sample_token` is given for a greedy sequence."""
+
+    __slots__ = ("token", "width")
+
+    def __init__(self, token, width: int):
+        self.token = int(token)
+        self.width = width
+
+    def __len__(self) -> int:
+        return self.width
+
+
+def sample_token(logits, temperature: float = 0.0,
                  rng: Optional[np.random.Generator] = None) -> int:
-    """Host-side sampling: greedy at ``temperature=0`` (deterministic —
-    the batch-composition-invariance test keys on it), else softmax
-    sampling from the caller's per-request generator."""
+    """The scheduler's sampler, called for every token: greedy at
+    ``temperature=0`` (deterministic — the batch-composition-invariance
+    test keys on it; a :class:`PickedRow` gives the device's choice, an
+    array its argmax), else softmax sampling on the host from the
+    caller's per-request generator."""
     if temperature <= 0.0:
-        return int(np.argmax(logits))
+        return logits.token if isinstance(logits, PickedRow) \
+            else int(np.argmax(logits))
     z = logits.astype(np.float64) / float(temperature)
     z -= z.max()
     p = np.exp(z)
@@ -219,6 +244,13 @@ def _quantize_pages(x, page: int, n_heads: int):
     return q.reshape(t, row).astype(jnp.int8), scale.T
 
 
+def greedy_tokens(logits):
+    """The greedy token of each row of float32 logits, int32: the first
+    index of the largest, as ``np.argmax`` of the same array gives it."""
+    import jax.numpy as jnp
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 class DenseDecoder:
     """The first family: the dense pre-LayerNorm decoder of
     ``models/transformer.py``, found by parameter name. Its cache planes
@@ -261,9 +293,9 @@ class DenseDecoder:
             (tokens, np.int32(slot), np.int32(n)), \
             {"chunk": t_b, "context": t_b}
 
-    def step_logits(self, fetched, s_b, pos, active) -> np.ndarray:
-        """What the decode program's fetched output holds for the
-        sampler, counted: here the logits as they came."""
+    def step_picked(self, fetched, s_b, pos, active) -> np.ndarray:
+        """The slots' tokens out of the decode program's fetched
+        ``picked``, the step counted: here they are all of it."""
         if self.kernel_reads(s_b):
             _profiler.incr_counter(self.engine.name
                                    + "_decode_attn_kernel_steps")
@@ -335,7 +367,7 @@ class DenseDecoder:
             row = _ln(row, params["final_ln_gamma"],
                       params["final_ln_beta"])
             logits = _fc(row, params, "lm_head")[0]         # (V,)
-            return logits, state
+            return greedy_tokens(logits), logits, state
 
         return jax.jit(fn, donate_argnums=(1,))
 
@@ -473,7 +505,7 @@ class DenseDecoder:
             # scheduler bug downstream surfaces as -inf-ish logits, not
             # a plausible token
             logits = jnp.where(active[:, None], logits, -1e30)
-            return logits, state
+            return greedy_tokens(logits), logits, state
 
         return jax.jit(fn, donate_argnums=(1,))
 
@@ -530,6 +562,8 @@ class DecodeEngine:
         # multi-device (sharded cache) programs are AOT-fenced exactly
         # like the executor forward (aot_skip_multidevice)
         self._multi_device = cache._sharding is not None
+        # the logits' width, for rows that are not fetched
+        self.vocab = int(self.params["lm_head_weight"].shape[0])
         self.family.bind(self)
 
     def executable_bound(self) -> int:
@@ -557,8 +591,11 @@ class DecodeEngine:
         # stored for another layout is a miss, not a crash
         state = tuple((tuple(a.shape), str(a.dtype))
                       for a in self.cache.state())
+        # and what a program returns: an executable stored for other
+        # outputs is a miss, not a wrong unpacking
         return ("serve", kind, bucket, self.family.sig(), shapes, state,
-                self.cache.page, self.prefill_chunk)
+                self.cache.page, self.prefill_chunk,
+                ("picked", "logits", "state"))
 
     def _dispatch(self, kind: str, bucket: int, builder, args: Tuple):
         """Bucket-program dispatch under the CompileCache counter
@@ -604,40 +641,51 @@ class DecodeEngine:
         self.compile_cache.note_success(sig)
         return out
 
-    def prefill(self, prompt: np.ndarray, slot: int) -> np.ndarray:
+    def prefill(self, prompt: np.ndarray, slot: int,
+                logits: bool = False) -> Tuple[int, Optional[np.ndarray]]:
         """Run one prompt through the family's prefill program(s), writing
         its state into ``slot``: one program at the prompt's bucket, or a
         chunk after a chunk, each appended to the cache and attending
-        over it. Returns the last real token's logits as host numpy (the
-        fetch is the device fence)."""
-        logits = None
+        over it. Returns the last real token's greedy choice and, where
+        ``logits`` asks for them (the request samples), its ``(V,)``
+        logits as host numpy, else None (the fetch is the device
+        fence)."""
+        picked = out = None
         for bucket, builder, args, attrs in self.family.prefill_calls(
                 prompt, slot):
             with _profiler.span("gen_prefill_chunk", "serve", **attrs):
-                logits, new_state = self._dispatch(
+                picked, out, new_state = self._dispatch(
                     "prefill", bucket, builder,
                     (self.params, self.cache.state()) + tuple(args))
                 self.cache.set_state(new_state)
             _profiler.incr_counter(self.name + "_prefill_chunks")
-        return np.asarray(logits)
+        return int(np.asarray(picked)), \
+            (np.asarray(out) if logits else None)
 
     def decode_step(self, tokens: np.ndarray, pos: np.ndarray,
-                    active: np.ndarray) -> np.ndarray:
-        """One decode step over the whole slot array; returns
-        ``(slots, V)`` logits on host. ``pos[s]`` is the write position
-        (current length) of slot ``s``; inactive slots pass 0/False."""
+                    active: np.ndarray, logits: bool = False
+                    ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+        """One decode step over the whole slot array; returns the
+        ``(slots,)`` greedy tokens on host and, where ``logits`` asks for
+        them (a resident sequence samples), the ``(slots, V)`` logits,
+        else None. ``pos[s]`` is the write position (current length) of
+        slot ``s``; inactive slots pass 0/False."""
         needed = int(pos[active].max()) + 1 if active.any() else 1
         s_b = self.seq_bucket(needed)
         with _profiler.span("gen_decode_dispatch", "serve"):
-            logits, new_state = self._dispatch(
+            picked, out, new_state = self._dispatch(
                 "decode", s_b, self.family.build_decode,
                 (self.params, self.cache.state(),
                  np.asarray(tokens, np.int32), np.asarray(pos, np.int32),
                  np.asarray(active, bool)))
         self.cache.set_state(new_state)
         # the fetch is the step's device fence: what the scheduler waits
-        # here is the step's device time and the copy of the program's
-        # output (a family's own counts may ride in it: no second transfer)
+        # here is the step's device time and the copy of ``picked``, a few
+        # integers (a family's own counts ride in it: no second
+        # transfer). The logits stay on the device unless someone samples.
         with _profiler.span("gen_logits_fetch", "serve"):
-            out = np.asarray(logits)
-        return self.family.step_logits(out, s_b, pos, active)
+            picked = np.asarray(picked)
+            out = np.asarray(out) if logits else None
+        if logits:
+            _profiler.incr_counter(self.name + "_decode_logits_fetched")
+        return self.family.step_picked(picked, s_b, pos, active), out

@@ -11,7 +11,7 @@ architecture states.
 array, as the dense family's: the new token's latent row is appended in
 place at each slot's own position, and every layer runs the absorbed
 attention of all its heads over the slots' first ``S_b`` rows. The step's
-expert counts ride in one more row under the logits.
+expert counts ride in two more entries behind the slots' chosen tokens.
 
 **Prefill in chunks.** A prompt is cut into chunks of ``prefill_chunk``
 tokens (the last padded to a power-of-two share of it); a chunk's program
@@ -32,7 +32,7 @@ import numpy as np
 from .. import profiler as _profiler
 from ..base import MXNetError
 from ..models import mla_moe as _m
-from .decode import extract_params
+from .decode import extract_params, greedy_tokens
 
 __all__ = ["MlaMoeDecoder", "serves", "make"]
 
@@ -96,19 +96,20 @@ class MlaMoeDecoder:
                 (tokens, np.int32(slot), np.int32(start), np.int32(n)), \
                 {"chunk": c_b, "context": ctx_b}
 
-    def step_logits(self, fetched, s_b, pos, active) -> np.ndarray:
-        """The logits out of the decode program's output; its last row
-        holds the step's [assignments, experts hit], counted here."""
+    def step_picked(self, fetched, s_b, pos, active) -> np.ndarray:
+        """The slots' tokens out of the decode program's ``picked``; its
+        last two entries are the step's assignments and experts hit,
+        counted here."""
         name = self.engine.name
-        _profiler.incr_counter(name + "_moe_assignments", int(fetched[-1, 0]))
-        _profiler.incr_counter(name + "_moe_experts_hit", int(fetched[-1, 1]))
+        _profiler.incr_counter(name + "_moe_assignments", int(fetched[-2]))
+        _profiler.incr_counter(name + "_moe_experts_hit", int(fetched[-1]))
         # from the positions and the step's bucket, on the host: every
         # layer reads the bucket's rows of every slot, resident or not
         _profiler.incr_counter(name + "_mla_keys_resident", int(
             pos[active].astype(np.int64).sum() + active.sum()))
         _profiler.incr_counter(name + "_mla_keys_read",
                                int(s_b) * int(pos.shape[0]))
-        return fetched[:-1]
+        return fetched[:-2]
 
     # ------------------------------------------------------------- programs
     def _query_block(self, c_b: int, ctx_b: int) -> int:
@@ -172,7 +173,7 @@ class MlaMoeDecoder:
             last = lax.dynamic_slice(x, (at, 0), (1, a.d))
             logits = _m.dense(_m.rms_norm(last, params["final_ln_gamma"],
                                           a.eps), params["lm_head_weight"])
-            return logits[0], (latent,)
+            return greedy_tokens(logits[0]), logits[0], (latent,)
 
         return jax.jit(fn, donate_argnums=(1,))
 
@@ -216,10 +217,9 @@ class MlaMoeDecoder:
             # finished/empty slots carry garbage rows; mask them so a
             # scheduler bug downstream surfaces as -inf-ish logits
             logits = jnp.where(active[:, None], logits, -1e30)
-            extra = jnp.zeros((1, logits.shape[1]), jnp.float32)
-            extra = extra.at[0, 0].set(assignments.astype(jnp.float32))
-            extra = extra.at[0, 1].set(hit.astype(jnp.float32))
-            return jnp.concatenate([logits, extra], axis=0), (latent,)
+            picked = jnp.concatenate(
+                [greedy_tokens(logits), jnp.stack([assignments, hit])])
+            return picked, logits, (latent,)
 
         return jax.jit(fn, donate_argnums=(1,))
 

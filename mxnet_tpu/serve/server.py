@@ -929,10 +929,11 @@ class GenerativeServer:
                  arch: Optional[Dict[str, Any]] = None):
         from .. import config as _config
         from .kv_cache import KVCache                       # lazy: the
-        from .decode import (DecodeEngine, family_for,      # zero-cost
-                             sample_token)                  # gate
+        from .decode import (DecodeEngine, PickedRow,       # zero-cost
+                             family_for, sample_token)      # gate
         self.name = name
         self._sample_token = sample_token
+        self._picked_row = PickedRow
         family = family_for(model, n_heads=n_heads, arch=arch)
         cfg = family.cfg
         self.max_sequences = int(
@@ -1097,10 +1098,14 @@ class GenerativeServer:
             mask[seq.slot] = True
         bucket = self.engine.seq_bucket(int(pos.max()) + 1) \
             if _profiler.spans_enabled() else 0
+        # the token is chosen on the device; the logits leave it only
+        # if a resident sequence samples
+        sampling = any(seq.temperature > 0.0 for seq in active)
         try:
             with _profiler.span("gen_decode_step", "serve", bucket=bucket,
                                 active=len(active)):
-                logits = self.engine.decode_step(tokens, pos, mask)
+                picked, logits = self.engine.decode_step(
+                    tokens, pos, mask, logits=sampling)
         except Exception as exc:                            # noqa: BLE001
             # a REAL decode failure cannot be attributed to one row —
             # every resident sequence fails legibly and frees its pages
@@ -1112,8 +1117,12 @@ class GenerativeServer:
         finished = []
         with _profiler.span("gen_sample", "serve", active=len(active)):
             for seq in active:
-                tok = self._sample_token(logits[seq.slot], seq.temperature,
-                                         seq.rng)
+                # a greedy sequence's row stayed on the device: the
+                # sampler gets the device's choice standing for it
+                row = logits[seq.slot] if seq.temperature > 0.0 \
+                    else self._picked_row(picked[seq.slot],
+                                          self.engine.vocab)
+                tok = self._sample_token(row, seq.temperature, seq.rng)
                 self.latency.tpot.record(now - seq.t_last)
                 seq.t_last = now
                 seq.handle._put(tok)
@@ -1180,7 +1189,8 @@ class GenerativeServer:
                 with _profiler.span("gen_prefill", "serve", flow=req.flow,
                                     bucket=bucket,
                                     prompt_len=int(req.prompt.size)):
-                    logits = self.engine.prefill(req.prompt, slot)
+                    tok, logits = self.engine.prefill(
+                        req.prompt, slot, logits=req.temperature > 0.0)
             except Exception as exc:                        # noqa: BLE001
                 self.cache.release(slot)
                 req.handle._finish(ServeError(
@@ -1188,7 +1198,9 @@ class GenerativeServer:
                 continue
             rng = np.random.default_rng(req.seed) \
                 if req.seed is not None else None
-            tok = self._sample_token(logits, req.temperature, rng)
+            row = logits if req.temperature > 0.0 \
+                else self._picked_row(tok, self.engine.vocab)
+            tok = self._sample_token(row, req.temperature, rng)
             self.latency.ttft.record(monotonic() - req.t_submit)
             seq = _ActiveSeq(slot, req.handle, int(req.prompt.size),
                              req.max_new_tokens, req.eos_id,
@@ -1315,6 +1327,10 @@ class GenerativeServer:
             # Pallas decode-attention kernel (float32, one device)
             "decode_attn_kernel_steps": _profiler.get_counter(
                 self.name + "_decode_attn_kernel_steps"),
+            # and those that fetched the logits: a resident sequence
+            # sampled (temperature > 0); a greedy step fetches its tokens
+            "decode_logits_fetched": _profiler.get_counter(
+                self.name + "_decode_logits_fetched"),
             "active_sequences": active,
             "waiting": waiting,
             "evicted": _profiler.get_counter(self.name + "_evicted"),

@@ -27,6 +27,8 @@ from mxnet_tpu import io as io_mod
 from mxnet_tpu.serve import (DeadlineExceeded, GenerativeServer, QueueFull,
                              ServeError, ServerClosed)
 
+import _serve_pick
+
 VOCAB, LAYERS, DMODEL, HEADS, SEQ = 128, 2, 32, 2, 16
 
 
@@ -89,7 +91,8 @@ def test_prefill_logits_match_module_forward(module):
     eng, cache = _dense_engine(params, 2, "parity")
     for prompt in ([3, 11, 7, 2, 9], [1], [5] * 15):
         slot = cache.acquire(len(prompt))
-        logits = eng.prefill(np.array(prompt), slot)
+        picked, logits = eng.prefill(np.array(prompt), slot, logits=True)
+        assert picked == int(np.argmax(logits))
         err = np.abs(_ref_probs(module, prompt)
                      - _softmax(logits)).max()
         assert err < 1e-4, "prompt %r: %g" % (prompt, err)
@@ -104,15 +107,16 @@ def test_decode_steps_match_full_forward(module):
     eng, cache = _dense_engine(params, 2, "steps")
     prompt = [3, 11, 7, 2, 9]
     slot = cache.acquire(len(prompt))
-    seq = list(prompt) + [int(np.argmax(eng.prefill(np.array(prompt),
-                                                    slot)))]
+    seq = list(prompt) + [eng.prefill(np.array(prompt), slot)[0]]
     pos = len(prompt)
     for _ in range(6):
         t = np.zeros((2,), np.int32)
         p = np.zeros((2,), np.int32)
         a = np.zeros((2,), bool)
         t[slot], p[slot], a[slot] = seq[-1], pos, True
-        logits = eng.decode_step(t, p, a)[slot]
+        picked, logits = eng.decode_step(t, p, a, logits=True)
+        assert picked[slot] == np.argmax(logits[slot])
+        logits = logits[slot]
         cache.grow(slot)
         pos += 1
         ref = _ref_probs(module, seq)
@@ -307,7 +311,7 @@ def test_decode_program_reads_with_the_kernel_where_it_can(module):
         for slot, prompt in prompts.items():
             eng.prefill(prompt, slot)
         steps = profiler.get_counter(name + "_decode_attn_kernel_steps")
-        out = eng.decode_step(tokens, pos, active)
+        _, out = eng.decode_step(tokens, pos, active, logits=True)
         return out, profiler.get_counter(
             name + "_decode_attn_kernel_steps") - steps
 
@@ -319,6 +323,41 @@ def test_decode_program_reads_with_the_kernel_where_it_can(module):
     assert (got[~active] == want[~active]).all()    # masked rows
     _, n = logits("readint8", int8=True)
     assert n == 0
+
+
+# ------------------------------------- the token is chosen on the device
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_picked_is_the_argmax_of_the_steps_logits(module, int8):
+    from mxnet_tpu.serve.decode import extract_params
+    eng, _cache = _dense_engine(extract_params(module), 5,
+                                "pick%d" % int8, int8=int8)
+    _serve_pick.check_picked_is_the_logits_argmax(
+        eng, VOCAB, {0: [9, 2, 6], 1: [3, 11, 7, 2, 9], 3: [1]})
+
+
+def test_a_greedy_step_fetches_its_tokens_and_no_logits(module,
+                                                        monkeypatch):
+    from mxnet_tpu.serve.decode import extract_params
+    eng, _cache = _dense_engine(extract_params(module), 4, "pickfetch")
+    _serve_pick.check_engine_fetches_logits_when_asked(
+        eng, [3, 11, 7], monkeypatch)
+
+
+def test_a_greedy_server_fetches_tokens_only(module, monkeypatch):
+    _serve_pick.check_greedy_server_fetches_tokens_only(
+        _server(module, name="pickgreedy"),
+        [[3, 1, 4], [1, 5], [9, 2, 6, 5]], monkeypatch)
+
+
+def test_a_sampling_request_among_greedy_ones(module):
+    """The scheduler asks for the logits only while someone samples;
+    the seeded request keeps its stream whoever is resident beside it."""
+    _serve_pick.check_a_sampling_request_among_greedy_ones(
+        lambda name: _server(module, name="pick" + name),
+        [([3, 1, 4], {"max_new_tokens": 9}),
+         ([1, 5], {"max_new_tokens": 5, "temperature": 0.8, "seed": 5}),
+         ([9, 2, 6, 5], {"max_new_tokens": 8})])
 
 
 def test_aot_key_carries_the_cache_layout(module):
@@ -336,6 +375,9 @@ def test_aot_key_carries_the_cache_layout(module):
     assert ((LAYERS, 2, SEQ, DMODEL), "int8") in int8[5]
     assert ((LAYERS, 2, HEADS, SEQ // 4), "float32") in int8[5]
     assert f32 != int8
+    # and what the program returns: an executable stored when a step
+    # returned (logits, state) is a miss, not a wrong unpacking
+    assert f32[-1] == ("picked", "logits", "state")
 
 
 # ------------------------------------------------------- scheduler behavior

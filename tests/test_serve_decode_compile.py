@@ -16,6 +16,11 @@ decode step (latent attention over a latent plane, routed experts) is
 compiled at the published widths, where the compiler keeps a 576-wide
 latent plane positions-minor and copies all of it around every append
 (found by PR 27): rows are stored 640 wide.
+
+ISSUE 29 chose the greedy token inside the programs: each returns the
+int32 argmax beside the float32 logits it was taken over. The pinned
+bytes moved by what the compiler's cost model charges for it, and the
+cache is still aliased whole.
 """
 import re
 import types
@@ -102,18 +107,33 @@ def for_the_chip(monkeypatch):
     monkeypatch.setattr(rtc, "resolve_interpret", lambda arrays: False)
 
 
-def _compile_decode(one_chip, s_b, int8):
-    import jax.numpy as jnp
+def _dense_engine(one_chip, bucket, int8):
+    """The dense decoder's engine over the cell's shapes: (engine,
+    params, state, sd)."""
     from mxnet_tpu.serve.decode import DecodeEngine, DenseDecoder
     params, sd = _shapes(one_chip)
-    state = _state(sd, int8)
     cache = types.SimpleNamespace(int8=int8, page=PAGE, max_seq=MAX_SEQ,
                                   max_slots=SLOTS, _sharding=None)
     eng = DecodeEngine(DenseDecoder(params, HEADS), cache, None,
-                       seq_buckets=[s_b])
+                       seq_buckets=[bucket])
+    return eng, params, _state(sd, int8), sd
+
+
+def _compile_decode(one_chip, s_b, int8):
+    import jax.numpy as jnp
+    eng, params, state, sd = _dense_engine(one_chip, s_b, int8)
     compiled = eng.family.build_decode(s_b).lower(
         params, state, sd(SLOTS, dtype=jnp.int32),
         sd(SLOTS, dtype=jnp.int32), sd(SLOTS, dtype=jnp.bool_)).compile()
+    return compiled, params, state
+
+
+def _compile_prefill(one_chip, t_b, int8=False):
+    import jax.numpy as jnp
+    eng, params, state, sd = _dense_engine(one_chip, t_b, int8)
+    compiled = eng.family.build_prefill(t_b).lower(
+        params, state, sd(t_b, dtype=jnp.int32), sd(dtype=jnp.int32),
+        sd(dtype=jnp.int32)).compile()
     return compiled, params, state
 
 
@@ -174,18 +194,8 @@ def test_prefill_writes_its_rows_in_place(one_chip):
     update, and the rows reach it without a copy of their own (K and V
     sliced out of one fused projection were strided copies, and cost
     three layers' FFN fusions their tiling at this bucket)."""
-    import jax.numpy as jnp
-    from mxnet_tpu.serve.decode import DecodeEngine, DenseDecoder
     t_b = 256
-    params, sd = _shapes(one_chip)
-    state = _state(sd)
-    cache = types.SimpleNamespace(int8=False, page=PAGE, max_seq=MAX_SEQ,
-                                  max_slots=SLOTS, _sharding=None)
-    eng = DecodeEngine(DenseDecoder(params, HEADS), cache, None,
-                       seq_buckets=[t_b])
-    compiled = eng.family.build_prefill(t_b).lower(
-        params, state, sd(t_b, dtype=jnp.int32), sd(dtype=jnp.int32),
-        sd(dtype=jnp.int32)).compile()
+    compiled, _params, state = _compile_prefill(one_chip, t_b)
     assert compiled.memory_analysis().alias_size_in_bytes == _nbytes(state)
     hlo = compiled.as_text()
     assert not _slab_makers(hlo, SLOTS * MAX_SEQ * D_MODEL)
@@ -220,32 +230,43 @@ def test_decode_attention_kernel_compiles_at_real_widths(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
-# bytes accessed, as the parent of ISSUE 28 compiled these programs (the
-# engine before it was parted; read there with this file's own helpers)
-_BEFORE_PARTING = {("decode", False, 512): 1676829696,
-                   ("decode", False, 1536): 1676829696,
-                   ("decode", True, 512): 2855140864,
-                   ("prefill", False, 256): 1812728832}
+# bytes accessed: as the parent of ISSUE 28 compiled these programs (the
+# engine before it was parted; read there with this file's own helpers),
+# and since ISSUE 29 chose the token inside them. The compiler's cost
+# model charges the argmax five times the logits' bytes, not the one read
+# it is: it now makes the logits in fast memory, and counts their copy
+# out to the program's output and the reduce's operands beside the read.
+_BYTES_ACCESSED = {("decode", False, 512): (1676829696, 1692933120),
+                   ("decode", False, 1536): (1676829696, 1692933120),
+                   ("decode", True, 512): (2855140864, 2871244288),
+                   ("prefill", False, 256): (1812728832, 1813758976)}
 
 
-@pytest.mark.parametrize("kind,int8,bucket", sorted(_BEFORE_PARTING))
-def test_dense_programs_cost_what_they_cost_before_the_engine_was_parted(
+@pytest.mark.parametrize("kind,int8,bucket", sorted(_BYTES_ACCESSED))
+def test_dense_programs_cost_what_they_cost_before_and_the_argmax(
         one_chip, for_the_chip, kind, int8, bucket):
-    import jax.numpy as jnp
-    from mxnet_tpu.serve.decode import DecodeEngine, DenseDecoder
-    if kind == "decode":
-        compiled, _params, _st = _compile_decode(one_chip, bucket, int8)
-    else:
-        params, sd = _shapes(one_chip)
-        cache = types.SimpleNamespace(int8=int8, page=PAGE, max_seq=MAX_SEQ,
-                                      max_slots=SLOTS, _sharding=None)
-        eng = DecodeEngine(DenseDecoder(params, HEADS), cache, None,
-                           seq_buckets=[bucket])
-        compiled = eng.family.build_prefill(bucket).lower(
-            params, _state(sd, int8), sd(bucket, dtype=jnp.int32),
-            sd(dtype=jnp.int32), sd(dtype=jnp.int32)).compile()
+    compile_ = _compile_decode if kind == "decode" else _compile_prefill
+    compiled, _params, _st = compile_(one_chip, bucket, int8)
     read = int(compiled.cost_analysis()["bytes accessed"])
-    assert read == _BEFORE_PARTING[(kind, int8, bucket)]
+    before, now = _BYTES_ACCESSED[(kind, int8, bucket)]
+    logits = 4 * VOCAB * (SLOTS if kind == "decode" else 1)
+    assert read == now and before < now <= before + 6 * logits
+
+
+def test_decode_step_returns_the_picked_tokens_beside_the_logits(
+        one_chip, for_the_chip):
+    """One program a step still: its outputs are the slots' int32 argmax,
+    the float32 logits it was taken over, and the cache aliased whole."""
+    import jax
+    compiled, _params, state = _compile_decode(one_chip, 512, False)
+    picked, logits, new_state = compiled.out_info
+    assert (picked.shape, str(picked.dtype)) == ((SLOTS,), "int32")
+    assert (logits.shape, str(logits.dtype)) == ((SLOTS, VOCAB), "float32")
+    assert [(a.shape, a.dtype) for a in jax.tree_util.tree_leaves(
+        new_state)] == [(a.shape, a.dtype) for a in state]
+    assert compiled.memory_analysis().alias_size_in_bytes == _nbytes(state)
+    # the argmax is a reduce of the same program, not a second one
+    assert len(re.findall(r"^ENTRY", compiled.as_text(), re.M)) == 1
 
 
 def _mla_moe_arch(max_seq):
@@ -303,6 +324,10 @@ def test_latent_cache_is_appended_in_place_at_published_widths(one_chip,
     assert mem.alias_size_in_bytes == _nbytes(state)
     assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
     assert "tpu_custom_call" in compiled.as_text()
+    # the slots' tokens, then the step's assignments and experts hit
+    picked, logits, _state_out = compiled.out_info
+    assert (picked.shape, str(picked.dtype)) == ((slots + 2,), "int32")
+    assert (logits.shape, str(logits.dtype)) == ((slots, 32768), "float32")
     # the cost model reads 6 planes beside the weights (scores and mix
     # read the bucket's rows, here all of them, and an in-place append is
     # charged its operand); the positions-minor layout cost ten times the

@@ -12,6 +12,8 @@ import os
 import numpy as np
 import pytest
 
+import _serve_pick
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MAX_SEQ, SLOTS, CHUNK, PAGE = 128, 4, 16, 4
 BUCKETS = [16, 32, 64, 128]
@@ -76,14 +78,16 @@ def test_prefill_in_chunks_then_decode_follows_the_reference(tiny):
     want = [_reference(tiny, s) for s in seqs]
     pos = np.zeros(SLOTS, np.int32)
     for s in range(SLOTS):
-        got = eng.prefill(seqs[s][:prompt[s]], s)
+        picked, got = eng.prefill(seqs[s][:prompt[s]], s, logits=True)
+        assert picked == int(np.argmax(got))
         assert np.abs(got - want[s][prompt[s] - 1]).max() < TOL
         pos[s] = prompt[s]
     active = np.ones(SLOTS, bool)
     for _ in range(20):
         tokens = np.array([seqs[s][pos[s]] for s in range(SLOTS)], np.int32)
-        got = eng.decode_step(tokens, pos, active)
+        picked, got = eng.decode_step(tokens, pos, active, logits=True)
         assert got.shape == (SLOTS, cfg["vocab_held"])
+        assert (picked == np.argmax(got, axis=-1)).all()
         for s in range(SLOTS):
             assert np.abs(got[s] - want[s][pos[s]]).max() < TOL, (s, pos)
         pos += 1
@@ -98,8 +102,9 @@ def test_chunked_prefill_equals_one_shot(tiny):
     prompt = np.random.default_rng(1).integers(0, cfg["vocab_held"], 45)
     chunked, whole = _engine(tiny, "mmchunk"), _engine(tiny, "mmwhole",
                                                        chunk=64)
-    a, b = chunked.prefill(prompt, 1), whole.prefill(prompt, 1)
-    assert np.abs(a - b).max() < TOL
+    (ta, a), (tb, b) = (e.prefill(prompt, 1, logits=True)
+                        for e in (chunked, whole))
+    assert np.abs(a - b).max() < TOL and ta == tb
     (pa,), (pb,) = chunked.cache.state(), whole.cache.state()
     rows_a = np.asarray(pa)[:, 1, :45]
     assert np.abs(rows_a).max() > 0
@@ -119,9 +124,11 @@ def test_an_empty_slot_is_routed_nowhere_and_masked(tiny):
     pos = np.array([0, 0, 9, 0], np.int32)
     active = np.array([False, False, True, False])
     before = mx.profiler.get_counter("mmempty_moe_assignments")
-    out = eng.decode_step(np.array([0, 0, 3, 0], np.int32), pos, active)
+    picked, out = eng.decode_step(np.array([0, 0, 3, 0], np.int32), pos,
+                                  active, logits=True)
     sent = mx.profiler.get_counter("mmempty_moe_assignments") - before
     assert (out[~active] < -1e29).all() and out.shape == (SLOTS, 256)
+    assert picked.shape == (SLOTS,) and picked[2] == np.argmax(out[2])
     # one token, two sparse layers, at most experts-per-token each
     assert 0 <= sent <= 2 * cfg["num_experts_per_tok"]
     assert mx.profiler.get_counter("mmempty_moe_experts_hit") <= sent
@@ -131,15 +138,21 @@ def test_an_empty_slot_is_routed_nowhere_and_masked(tiny):
     assert mx.profiler.get_counter("mmempty_prefill_chunks") == 1
 
 
+def _server(tiny, name):
+    import mxnet_tpu as mx
+    _cfg, arch, params = tiny
+    return mx.serve.GenerativeServer(
+        params, arch=arch, max_sequences=SLOTS, seq_buckets=BUCKETS,
+        prefill_chunk=CHUNK, page=PAGE, name=name)
+
+
 def test_server_serves_it_and_compiles_nothing_after_warm_up(tiny):
     """Through ``GenerativeServer.submit_generate``; greedy tokens are the
     reference's best at every step, and a second round of the same shapes
     builds no program."""
     import mxnet_tpu as mx
-    cfg, arch, params = tiny
-    srv = mx.serve.GenerativeServer(
-        params, arch=arch, max_sequences=SLOTS, seq_buckets=BUCKETS,
-        prefill_chunk=CHUNK, page=PAGE, name="mmsrv")
+    cfg = tiny[0]
+    srv = _server(tiny, "mmsrv")
     try:
         rng = np.random.default_rng(3)
         lengths = (5, 40, 23, 70)
@@ -166,6 +179,66 @@ def test_server_serves_it_and_compiles_nothing_after_warm_up(tiny):
             1 + 3 + 2 + 5)
     finally:
         srv.close()
+
+
+# ------------------------------------- the token is chosen on the device
+
+def test_picked_is_the_argmax_of_the_steps_logits(tiny):
+    cfg = tiny[0]
+    rng = np.random.default_rng(4)
+    _serve_pick.check_picked_is_the_logits_argmax(
+        _engine(tiny, "mmpick"), cfg["vocab_held"],
+        {s: rng.integers(0, cfg["vocab_held"], n)
+         for s, n in ((0, 21), (1, 5), (3, 40))})
+
+
+def test_a_greedy_step_fetches_its_tokens_and_no_logits(tiny, monkeypatch):
+    prompt = np.random.default_rng(5).integers(0, tiny[0]["vocab_held"], 21)
+    _serve_pick.check_engine_fetches_logits_when_asked(
+        _engine(tiny, "mmpickfetch"), prompt, monkeypatch)
+
+
+def test_a_greedy_server_fetches_tokens_only(tiny, monkeypatch):
+    rng = np.random.default_rng(6)
+    _serve_pick.check_greedy_server_fetches_tokens_only(
+        _server(tiny, "mmpickgreedy"),
+        [rng.integers(0, tiny[0]["vocab_held"], n) for n in (5, 40, 23)],
+        monkeypatch)
+
+
+def test_a_sampling_request_among_greedy_ones(tiny):
+    rng = np.random.default_rng(7)
+    p = [rng.integers(0, tiny[0]["vocab_held"], n) for n in (5, 40, 23)]
+    _serve_pick.check_a_sampling_request_among_greedy_ones(
+        lambda name: _server(tiny, "mmpick" + name),
+        [(p[0], {"max_new_tokens": 9}),
+         (p[1], {"max_new_tokens": 5, "temperature": 0.8, "seed": 5}),
+         (p[2], {"max_new_tokens": 8})])
+
+
+def test_expert_counters_read_what_they_read_in_the_logits_row(tiny):
+    """``moe_assignments`` / ``moe_experts_hit`` ride behind the picked
+    tokens as int32 now. Three of four slots resident, six teacher-forced
+    steps, the counters read after each: the figures are the ones the
+    program gave when they rode in a float32 row under the logits (read
+    at the parent of PR 29 on this scenario)."""
+    import mxnet_tpu as mx
+    cfg = tiny[0]
+    eng = _engine(tiny, "mmcounts")
+    rng = np.random.default_rng(8)
+    pos = np.array([21, 0, 5, 40], np.int32)
+    active = pos > 0
+    for s in np.flatnonzero(active):
+        eng.prefill(rng.integers(0, cfg["vocab_held"], pos[s]), int(s))
+    read = []
+    for _ in range(6):
+        tokens = np.where(active, rng.integers(0, cfg["vocab_held"], SLOTS),
+                          0).astype(np.int32)
+        eng.decode_step(tokens, pos, active)
+        pos[active] += 1
+        read.append((mx.profiler.get_counter("mmcounts_moe_assignments"),
+                     mx.profiler.get_counter("mmcounts_moe_experts_hit")))
+    assert read == [(7, 4), (13, 9), (18, 13), (26, 18), (29, 20), (38, 25)]
 
 
 def test_server_refuses_what_it_cannot_serve(tiny):
@@ -448,11 +521,11 @@ def test_bfloat16_as_stated_passes_a_tolerance_that_float8_fails(tiny, seed):
                   "mmbf16_%d" % seed, slots=2, dtype="bfloat16")
     assert {str(a.dtype) for a in eng.cache.state()} == {"bfloat16"}
     seq = np.random.default_rng(seed).integers(0, cfg["vocab_held"], 70)
-    got = [eng.prefill(seq[:40], 0)]
+    got = [eng.prefill(seq[:40], 0, logits=True)[1]]
     pos, active = np.array([40, 0], np.int32), np.array([True, False])
     for j in range(29):
         got.append(eng.decode_step(np.array([seq[40 + j], 0], np.int32),
-                                   pos, active)[0])
+                                   pos, active, logits=True)[1][0])
         pos[0] += 1
     held = {k: v.astype(jnp.float32) for k, v in params.items()}
     want = np.asarray(ref.forward(cfg, held, jnp.asarray(seq[:69])))[39:]
