@@ -118,8 +118,7 @@ def _tune_provenance():
     return {
         "tuned": bool(mx.profiler.counters().get("tune_applied")),
         "tune_knobs": {k: mx.config.get(k) for k in (
-            "MXNET_TPU_REMAT", "MXNET_TPU_SCAN_LAYERS",
-            "MXNET_TPU_GROUP_UPDATE", "MXNET_TPU_ASYNC_WINDOW")},
+            "MXNET_TPU_REMAT", "MXNET_TPU_ASYNC_WINDOW")},
     }
 
 

@@ -150,10 +150,7 @@ def _audit_zoo_net(name: str, fail_at) -> int:
     if name.startswith("zoo:"):
         name = name[4:]           # accept the graph subcommand's spelling
     sym, shapes = _zoo_symbol(name)
-    # audits always calibrate the remat prediction (one block forward +
-    # vjp on zeros) — this is the offline path where that cost belongs
-    report = analyze_symbol(sym, input_shapes=shapes, context=name,
-                            calibrate_remat=True)
+    report = analyze_symbol(sym, input_shapes=shapes, context=name)
     cost = report.extras.get("cost", {})
     remat = report.extras.get("remat", {})
     print("== %s: %.3g GFLOP, est peak %.3g MB (%.3g MB activations)"

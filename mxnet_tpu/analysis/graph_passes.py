@@ -574,26 +574,19 @@ GRAPH_PASSES.append(("cost-model", cost_model))
 
 def analyze_symbol(sym, input_shapes=None, input_dtypes=None,
                    passes=None, context: str = "graph",
-                   calibrate_remat=None, grad_accum=None,
-                   batch_inputs=None) -> Report:
+                   grad_accum=None, batch_inputs=None) -> Report:
     """Run the graph passes over ``sym``; returns a :class:`Report`.
 
     ``input_shapes``/``input_dtypes`` play the role of bind-time shapes
     (name -> shape/dtype); omitted names fall back to ``__shape__`` attrs
     and structural parameter derivation. ``passes`` optionally restricts
-    to a subset of pass codes. ``calibrate_remat`` forces (True) or
-    suppresses (False) the remat pass's concrete block-residual
-    calibration; None (default) runs it only when an applied-remat knob
-    is active — a plain warn/strict bind analysis must stay
-    execution-free (memory_passes._predict_block_savings).
-    ``grad_accum=N`` with ``batch_inputs`` (the data/label variable
+    to a subset of pass codes. ``grad_accum=N`` with ``batch_inputs`` (the data/label variable
     names) makes the cost model price the microbatch scan peak instead
     of the full batch — see :func:`cost_model`.
     """
     report = Report(context=context)
     ctx = GraphContext(sym, input_shapes, input_dtypes,
                        grad_accum=grad_accum, batch_inputs=batch_inputs)
-    ctx.calibrate_remat = calibrate_remat
     for code, fn in GRAPH_PASSES:
         if passes is not None and code not in passes:
             continue

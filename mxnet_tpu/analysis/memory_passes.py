@@ -167,15 +167,6 @@ def remat_pass(ctx: GraphContext, report: Report) -> None:
                 % policy,
         "est_bytes_saved": int(total_bytes),
     }
-    # calibrated peak prediction: when the graph has a verified repeated
-    # chain (the scan-over-layers detector), measure ONE block's actual
-    # vjp residuals with and without the policy and scale by depth —
-    # the number MXNET_TPU_REMAT=auto is held to (round-trip test:
-    # applied remat must move analyze_program_memory's high-water by
-    # this amount ±25%)
-    est_peak = _predict_block_savings(ctx, policy)
-    if est_peak is not None:
-        suggestion["est_peak_saving"] = int(est_peak)
     report.extras["remat"] = {"candidates": candidates,
                               "suggestion": suggestion}
     for c in top:
@@ -187,87 +178,6 @@ def remat_pass(ctx: GraphContext, report: Report) -> None:
             % (c["op"], "x".join(map(str, c["shape"][0])), c["bytes"] / 1e6,
                n_nodes - c["live_span"], c["flops_per_byte"], policy),
             node=c["node"], op=c["op"], detail=c)
-
-
-def _predict_block_savings(ctx: GraphContext, policy_name: str):
-    """Predicted activation-high-water drop of applying ``policy_name``
-    per repeated block: detect the chain (scan-over-layers machinery),
-    build ONE block as a callable over zeros of the bound shapes, and
-    compare the byte size of its actual ``jax.vjp`` residuals plain vs
-    checkpointed — scaled by the layer count. Values don't matter
-    (residual SIZES are shape-determined), so zeros suffice; one block's
-    forward+vjp trace is comparable to the shape pass's cost. Returns
-    None when no verified chain exists or anything fails.
-
-    Gated: a plain ``warn``/``strict`` bind analysis must not execute
-    compute (the bind contract is static-only), so the calibration runs
-    only when an applied-remat knob is active — the consumer of the
-    number — or when the caller forces it (the audit CLI, the
-    round-trip test) via ``analyze_symbol(calibrate_remat=True)``."""
-    want = getattr(ctx, "calibrate_remat", None)
-    if want is None:
-        from .. import config as _config
-        want = _config.get("MXNET_TPU_REMAT") != "off" or \
-            bool(_config.get("MXNET_EXEC_ENABLE_REMAT"))
-    if not want:
-        return None
-    try:
-        from ..symbol.scan import build_scan_plan
-        plan = build_scan_plan(ctx.sym, min_repeat=2)
-        if plan is None:
-            return None
-        import jax
-        import jax.numpy as jnp
-        from ..executor import _run_node
-
-        def zeros(key):
-            aval = ctx.shapes.get(key)
-            if aval is None:
-                raise KeyError(key)
-            shape, dt = aval
-            return jnp.zeros(shape, dt)
-
-        stream_key = (id(plan.stream_in[0]), plan.stream_in[1])
-        x0 = zeros(stream_key)
-        pvals = {tid: zeros((tid, 0)) for tid in plan.var_lists}
-        out_key = (plan.layer_table[0][plan._out_pos()], plan.out_idx)
-        rng = jax.random.PRNGKey(0)
-        shared_cache: Dict[Tuple[int, int], Any] = {}
-
-        def block_fn(x, pv):
-            seg: Dict[Tuple[int, int], Any] = {}
-
-            def entry_val(ent):
-                node, ei = ent
-                k = (id(node), ei)
-                if k == stream_key:
-                    return x
-                if k in seg:
-                    return seg[k]
-                if id(node) in pv:
-                    return pv[id(node)]
-                if k not in shared_cache:
-                    shared_cache[k] = zeros(k)
-                return shared_cache[k]
-
-            for node in plan.template:
-                ins = [entry_val(e) for e in node.inputs]
-                outs = _run_node(node, ins, rng, 0, True)
-                for i, o in enumerate(outs):
-                    seg[(id(node), i)] = o
-            return seg[out_key]
-
-        def residual_bytes(fn):
-            _, f_vjp = jax.vjp(fn, x0, pvals)
-            return sum(getattr(leaf, "nbytes", 0)
-                       for leaf in jax.tree_util.tree_leaves(f_vjp))
-
-        policy = getattr(jax.checkpoint_policies, policy_name)
-        plain = residual_bytes(block_fn)
-        kept = residual_bytes(jax.checkpoint(block_fn, policy=policy))
-        return plan.n_layers * max(0, plain - kept)
-    except Exception:                                       # noqa: BLE001
-        return None
 
 
 # ------------------------------------------------------------- HBM budget

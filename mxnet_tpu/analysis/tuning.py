@@ -7,10 +7,10 @@ reject without parsing messages. This module is that adapter layer: pure
 functions over the existing cost/remat/comm models, no new estimators.
 
 * :func:`cost_report` — one analyzer run per (symbol, shapes,
-  grad_accum) with the cost + memory passes, remat calibration on.
+  grad_accum) with the cost + memory passes.
 * :func:`peak_bytes` / :func:`remat_candidates` — the pruner's inputs:
   the static HBM high-water and the ordered remat policy ladder with
-  calibrated ``est_peak_saving``.
+  ``est_bytes_saved``.
 * :func:`rank_layouts` — every ``data x fsdp x tp`` factorization of the
   device count, ranked by analytic per-device collective bytes
   (:func:`~.sharding_passes.comm_link_bytes` ring counts — the same
@@ -37,14 +37,13 @@ _PARAM_STATE_MULT = 4
 def cost_report(sym, input_shapes, input_dtypes=None, grad_accum=1,
                 batch_inputs=None) -> Report:
     """One static analysis of ``sym`` at the given microbatching factor:
-    cost model (microbatch-aware liveness), remat opportunity with
-    calibration forced on (the tuner needs ``est_peak_saving`` to order
-    remat candidates even when no remat knob is set), and hbm-budget."""
+    cost model (microbatch-aware liveness), remat opportunity and
+    hbm-budget."""
     return analyze_symbol(
         sym, input_shapes=input_shapes, input_dtypes=input_dtypes,
         passes=("shape-error", "cost-model", "remat-opportunity",
                 "hbm-budget"),
-        context="tune", calibrate_remat=True, grad_accum=grad_accum,
+        context="tune", grad_accum=grad_accum,
         batch_inputs=batch_inputs)
 
 
@@ -60,19 +59,17 @@ def peak_bytes(report: Report) -> Optional[int]:
 
 def remat_candidates(report: Report) -> List[Dict[str, Any]]:
     """The remat policy ladder for this graph, strongest saving first:
-    ``[{"policy", "est_peak_saving", "est_bytes_saved", "wrap"}, ...]``
+    ``[{"policy", "est_bytes_saved", "wrap"}, ...]``
     plus the implicit ``{"policy": "off"}`` entry (always first — remat
     costs recompute FLOPs, so "off" is the default until memory forces a
     rung down the ladder)."""
     out: List[Dict[str, Any]] = [
-        {"policy": "off", "est_peak_saving": 0, "est_bytes_saved": 0,
-         "wrap": None}]
+        {"policy": "off", "est_bytes_saved": 0, "wrap": None}]
     remat = report.extras.get("remat") or {}
     sug = remat.get("suggestion")
     if sug and sug.get("policy"):
         out.append({
             "policy": str(sug["policy"]),
-            "est_peak_saving": int(sug.get("est_peak_saving") or 0),
             "est_bytes_saved": int(sug.get("est_bytes_saved") or 0),
             "wrap": sug.get("wrap"),
         })

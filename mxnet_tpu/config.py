@@ -161,7 +161,7 @@ register("MXNET_TPU_FLEET_MAX_RESPAWNS", int, 16,
          "reuses MXNET_TPU_ELASTIC_BACKOFF/_MAX between attempts)")
 register("MXNET_TPU_FLEET_SPAWN_TIMEOUT", float, 240.0,
          "fleet: seconds a freshly spawned replica may take to answer "
-         "its first PING (model build + bind + AOT warm start); past "
+         "its first PING (model build + bind + compile); past "
          "it the spawn is scored failed and retried under the respawn "
          "budget (PhaseGuard discipline — no unbounded waits)")
 def _parse_analyze_mode(v) -> str:
@@ -344,34 +344,6 @@ register("MXNET_TPU_OBS_STRAGGLER_RATIO", float, 2.0,
          "leader aggregates into report()'s 'pod' block, the "
          "obs_straggler counter and per-rank /metrics gauges). "
          "0 = disabled (the straggler module is never imported)")
-def _parse_scan_layers(v) -> str:
-    s = str(v).strip().lower()
-    if s in ("", "0", "off", "false", "no", "none"):
-        return "off"
-    if s in ("auto", "on", "true", "yes", "1"):
-        return "auto"
-    if s.isdigit() and int(s) >= 2:
-        return s
-    raise ValueError(
-        "MXNET_TPU_SCAN_LAYERS must be off|auto|<min-repeat >= 2>, "
-        "got %r" % (v,))
-
-
-register("MXNET_TPU_SCAN_LAYERS", _parse_scan_layers, "off",
-         "scan-over-layers: lower repeated homogeneous blocks "
-         "(transformer layers) through jax.lax.scan so trace/compile "
-         "time stops growing with depth; auto = chains of >= 4 verified-"
-         "isomorphic blocks, an integer overrides that minimum, off = "
-         "always unroll (the scan module is never imported). Off by "
-         "default: the scanned step holds a stacked copy of the "
-         "per-layer parameters and of their gradients, and the 0.67B "
-         "LM's does not fit a 16 GB chip (PERF.md, PR 21)")
-register("MXNET_TPU_GROUP_UPDATE", _parse_bool, True,
-         "with a scan plan bound, trace the fused optimizer update as "
-         "ONE vmapped body per per-layer parameter family (stacked "
-         "(L, ...) arrays) instead of L per-param copies — kills the "
-         "remaining O(L) update eqns of deep scanned models; 0 = the "
-         "per-param trace (bisection fallback, bit-identical result)")
 
 
 def _parse_nancheck(v) -> str:
@@ -437,15 +409,6 @@ register("MXNET_TPU_REMAT", _parse_remat, "off",
          "(Report.extras['remat']), any other value = a "
          "jax.checkpoint_policies name applied as-is (e.g. "
          "nothing_saveable, dots_with_no_batch_dims_saveable)")
-register("MXNET_TPU_COMPILE_CACHE", str, "",
-         "AOT warm starts: directory for serialized fused-step "
-         "executables keyed on the program signature (symbol + shapes + "
-         "dtypes + optimizer statics + compile knobs + jax/device "
-         "fingerprint) so a restarted process skips trace AND compile. "
-         "SINGLE-DEVICE executables only (deserialized multi-device "
-         "executables mis-execute on this jax version — the fence is "
-         "capability-probed, see docs/architecture/program_model.md). "
-         "Empty = off")
 def _parse_tune(v) -> str:
     s = str(v).strip().lower()
     if s in ("", "0", "off", "false", "no", "none"):
@@ -477,10 +440,8 @@ register("MXNET_TPU_TUNE_MAX_PROBES", int, 4,
          "candidates probed per search (the default config is always "
          "probed in addition); 0 = static-only ranking")
 register("MXNET_TPU_TUNE_STORE", str, "",
-         "tune: TunedConfig store directory; empty = co-locate with "
-         "MXNET_TPU_COMPILE_CACHE (the aot executable cache), so a "
-         "restart finds the tuned knobs next to the executables they "
-         "compile into. Both empty = no persistence")
+         "tune: TunedConfig store directory, where a restart finds "
+         "the tuned knobs. Empty = no persistence")
 register("MXNET_TPU_LAYERNORM_TWO_PASS", _parse_bool, False,
          "LayerNorm: two-pass E[(x-mean)^2] variance instead of the fused "
          "one-pass E[x^2]-E[x]^2 form — restores precision for "

@@ -58,8 +58,7 @@ def _implicit_attrs(op) -> frozenset:
     return cached
 
 
-def graph_function(symbol, node_device=None, scan_plan=None,
-                   batch_rows=None):
+def graph_function(symbol, node_device=None, batch_rows=None):
     """Compile a Symbol into a pure function
     ``fn(args_dict, aux_dict, rng_key, is_train) -> (outputs, new_aux_dict)``.
 
@@ -76,13 +75,6 @@ def graph_function(symbol, node_device=None, scan_plan=None,
     (exactly the reference's one-engine-op-per-node schedule) and must not
     be wrapped in an outer jit.
 
-    ``scan_plan`` (optional, incompatible with ``node_device``): a
-    verified :class:`~mxnet_tpu.symbol.scan.ScanPlan` — the repeated
-    homogeneous chain it describes executes as ONE ``jax.lax.scan`` over
-    stacked per-layer parameters instead of unrolled per-layer tracing,
-    so trace time and HLO size stop growing with depth
-    (docs/architecture/program_model.md, compile-time control).
-
     ``batch_rows`` (optional): ``(mesh, axes)`` — the mesh the graph's
     values are placed on and the axes the batch dimension is sharded over
     (``()`` when the batch is replicated). GSPMD partitions ordinary ops
@@ -95,9 +87,6 @@ def graph_function(symbol, node_device=None, scan_plan=None,
     nodes = _topo_order(symbol._entries)
     node_index = {id(n): i for i, n in enumerate(nodes)}
     entries = list(symbol._entries)
-    if scan_plan is not None and node_device is not None:
-        raise MXNetError("scan-over-layers cannot combine with group2ctx "
-                         "op-by-op placement")
 
     def fn(args: Dict[str, Any], aux: Dict[str, Any], key, is_train: bool):
         vals: Dict[Any, Any] = {}
@@ -126,18 +115,8 @@ def graph_function(symbol, node_device=None, scan_plan=None,
                     if src.is_variable:
                         new_aux[src.name] = val
 
-        if scan_plan is not None:
-            for node in scan_plan.pre_nodes:
-                exec_node(node)
-            scan_plan.execute(vals, args, key, is_train,
-                              lambda node, ins, k, idx, it:
-                              _run_node(node, ins, k, idx, it, None,
-                                        batch_rows))
-            for node in scan_plan.post_nodes:
-                exec_node(node)
-        else:
-            for node in nodes:
-                exec_node(node)
+        for node in nodes:
+            exec_node(node)
         outputs = [vals[(id(n), i)] for n, i in entries]
         return outputs, new_aux
 
@@ -246,11 +225,8 @@ class Executor:
         # observability.md)
         self._obs_label = "graph:%s" % (
             self._output_names[0] if self._output_names else "?")
-        self._remat_name = "off"
-        self._scan_plan = self._build_scan_plan(_config)
         self._batch_rows = batch_rows
         self._fn = graph_function(symbol, self._node_device_fn(),
-                                  scan_plan=self._scan_plan,
                                   batch_rows=batch_rows)
         # programs embedding host-callback custom ops must run
         # synchronously with the frontend: async execution + concurrent
@@ -286,37 +262,27 @@ class Executor:
             self._jit_fwd = self._fn          # staged eager execution
         else:
             self._jit_fwd = jax.jit(self._fn, static_argnums=(3,))
-        # AOT warm starts for the forward path (serve restarts): one
-        # serialized executable per is_train variant, resolved lazily at
-        # first dispatch (aot.py; single-device programs only)
-        self._aot_fwd: Dict[bool, Any] = {}
 
-        # ---- applied remat on the NON-FUSED training path (the other
-        # PR 9 close-out flag): forward_backward + update drivers
-        # (kvstore binds, custom updaters, monitor mode) trace fwd_bwd
-        # below, which never went through Module._build_fused_step's
-        # wrap. With a scan plan the body_wrapper already checkpointed
-        # each repeated block (and that wrap lives inside self._fn, so
-        # fwd_bwd inherits it); only the plan-less whole-forward form is
-        # applied here. Kept on a SEPARATE attribute from _remat_name:
-        # the fused step keys its own wrap off _remat_name, and this
-        # wrap does not reach the fused step's loss_fn.
+        # ---- applied remat on the NON-FUSED training path:
+        # forward_backward + update drivers (kvstore binds, custom
+        # updaters, monitor mode) trace fwd_bwd below, which never goes
+        # through Module._build_fused_step's wrap. The fused step reuses
+        # the policy resolved here (one analysis run per bind); the wrap
+        # itself does not reach the fused step's loss_fn.
         self._fwd_bwd_remat = None
-        if self._wrt and self._remat_name == "off" and \
-                self._scan_plan is None and (
-                    _config.get("MXNET_TPU_REMAT") != "off"
-                    or _config.get("MXNET_EXEC_ENABLE_REMAT")):
+        if self._wrt and (
+                _config.get("MXNET_TPU_REMAT") != "off"
+                or _config.get("MXNET_EXEC_ENABLE_REMAT")):
             from . import remat as _remat
             shapes = {n: tuple(a.shape) for n, a in self.arg_dict.items()}
             shapes.update({n: tuple(a.shape)
                            for n, a in self.aux_dict.items()})
             dts = {n: a.dtype for n, a in self.arg_dict.items()}
             dts.update({n: a.dtype for n, a in self.aux_dict.items()})
-            policy, name = _remat.resolve_policy(
+            policy, _ = _remat.resolve_policy(
                 self._symbol, input_shapes=shapes, input_dtypes=dts)
             if policy is not None:
                 self._fwd_bwd_remat = policy
-                self._fwd_bwd_remat_name = name
                 from . import profiler as _profiler
                 _profiler.incr_counter("remat_applied")
 
@@ -342,87 +308,6 @@ class Executor:
         self._jit_fwd_bwd = fwd_bwd if in_shardings is not None \
             else jax.jit(fwd_bwd)
 
-    # ------------------------------------------------------------- forward AOT
-    def _dispatch_fwd(self, arg_vals, aux_vals, key, is_train):
-        """Forward dispatch with optional AOT warm start
-        (MXNET_TPU_COMPILE_CACHE): the first call per ``is_train``
-        variant loads — or compiles and serializes — a concrete
-        executable; later calls (and later *processes*) run it without
-        trace or compile. Multi-device bindings (mesh-sharded values,
-        group2ctx) always take the plain path: deserialized multi-device
-        executables mis-execute on this jax version (aot.py)."""
-        is_train = bool(is_train)
-        if self._group2ctx:
-            return self._jit_fwd(arg_vals, aux_vals, key, is_train)
-        from . import config as _config
-        if _config.get("MXNET_TPU_COMPILE_CACHE"):
-            # per-shape runners: serve's bucket padding re-enters this
-            # executor with different batch geometries, each its own
-            # executable (exactly like the jit cache it replaces)
-            vkey = (is_train, tuple(v.shape for v in arg_vals.values()))
-            runner = self._aot_fwd.get(vkey)
-            if runner is None:
-                runner = self._aot_fwd_setup(arg_vals, aux_vals, key,
-                                             is_train, vkey)
-            if runner is not False:
-                try:
-                    return runner(arg_vals, aux_vals, key)
-                except Exception as exc:                    # noqa: BLE001
-                    from . import profiler as _profiler
-                    _profiler.incr_counter("aot_error")
-                    import logging
-                    logging.getLogger(__name__).warning(
-                        "aot: forward executable failed (%s); falling "
-                        "back to jit", exc)
-                    self._aot_fwd[vkey] = False
-        return self._jit_fwd(arg_vals, aux_vals, key, is_train)
-
-    def _aot_fwd_setup(self, arg_vals, aux_vals, key, is_train, vkey):
-        """Resolve the AOT runner for one is_train variant (False =
-        permanently use the jit path for this binding)."""
-        from . import aot as _aot
-        from . import profiler as _profiler
-
-        def _multi(v):
-            sh = getattr(v, "sharding", None)
-            devs = getattr(sh, "device_set", None)
-            return devs is not None and len(devs) > 1
-
-        runner = False
-        vals = list(arg_vals.values()) + list(aux_vals.values())
-        if any(_multi(v) for v in vals):
-            _profiler.incr_counter("aot_skip_multidevice")
-        elif _aot.supported():
-            try:
-                from . import amp as _amp
-                sig = (
-                    "graph_fwd", self._symbol.tojson(),
-                    sorted((n, tuple(v.shape), str(v.dtype))
-                           for n, v in arg_vals.items()),
-                    sorted((n, tuple(v.shape), str(v.dtype))
-                           for n, v in aux_vals.items()),
-                    is_train,
-                    self._scan_plan.n_layers
-                    if self._scan_plan is not None else 0,
-                    (_amp.active(),
-                     str(_amp.compute_dtype()) if _amp.active() else ""),
-                )
-                digest = _aot.digest(sig)
-                runner = _aot.load("graph_fwd", digest)
-                if runner is None:
-                    compiled = self._jit_fwd.lower(
-                        arg_vals, aux_vals, key, is_train).compile()
-                    _aot.store("graph_fwd", digest, compiled)
-                    runner = compiled
-            except Exception:                               # noqa: BLE001
-                import logging
-                logging.getLogger(__name__).warning(
-                    "aot: forward warm-start setup failed; using jit",
-                    exc_info=True)
-                runner = False
-        self._aot_fwd[vkey] = runner
-        return runner
-
     @property
     def requires_sync_loop(self) -> bool:
         """True when programs from this executor must execute synchronously
@@ -441,48 +326,6 @@ class Executor:
         from . import profiler as _profiler
         _profiler.incr_counter("loop_forced_sync")
         jax.block_until_ready(values)
-
-    # ------------------------------------------------------------ scan
-    def _build_scan_plan(self, _config):
-        """Scan-over-layers (MXNET_TPU_SCAN_LAYERS, default off): detect
-        a repeated homogeneous chain and lower it through one
-        ``jax.lax.scan`` so bind time stops growing with depth. Detection
-        that does not verify falls back to the unrolled path silently;
-        ``scan_applied``/``scan_layers`` report what happened."""
-        if self._group2ctx:
-            return None
-        mode = _config.get("MXNET_TPU_SCAN_LAYERS")
-        if mode == "off":
-            return None
-        from .symbol.scan import DEFAULT_MIN_REPEAT, build_scan_plan
-        min_repeat = DEFAULT_MIN_REPEAT if mode == "auto" else int(mode)
-        shapes = {n: tuple(a.shape) for n, a in self.arg_dict.items()}
-        shapes.update({n: tuple(a.shape)
-                       for n, a in self.aux_dict.items()})
-        dtypes = {n: a.dtype for n, a in self.arg_dict.items()}
-        dtypes.update({n: a.dtype for n, a in self.aux_dict.items()})
-        plan = build_scan_plan(self._symbol, min_repeat=min_repeat,
-                               shapes=shapes, dtypes=dtypes)
-        from . import profiler as _profiler
-        if plan is not None:
-            _profiler.incr_counter("scan_applied")
-            _profiler.set_gauge("scan_layers", plan.n_layers)
-            # applied remat at block granularity: wrapping the scan body
-            # in jax.checkpoint IS the "wrap each repeated block" form
-            # the analysis remat-opportunity suggestion prescribes
-            if _config.get("MXNET_TPU_REMAT") != "off" or \
-                    _config.get("MXNET_EXEC_ENABLE_REMAT"):
-                from . import remat as _remat
-                policy, name = _remat.resolve_policy(
-                    self._symbol, input_shapes=shapes,
-                    input_dtypes=dtypes)
-                if policy is not None:
-                    import jax as _jax
-                    plan.body_wrapper = (
-                        lambda body: _jax.checkpoint(body, policy=policy))
-                    self._remat_name = name
-                    _profiler.incr_counter("remat_applied")
-        return plan
 
     # ------------------------------------------------------------ placement
     def _node_device_fn(self):
@@ -556,8 +399,8 @@ class Executor:
             self._outputs = None
         else:
             with _obs_compiles.scope(self._obs_label):
-                outs, new_aux = self._dispatch_fwd(arg_vals, aux_vals,
-                                                   key, is_train)
+                outs, new_aux = self._jit_fwd(arg_vals, aux_vals, key,
+                                              bool(is_train))
             if self._sync_host_callbacks:
                 self._forced_sync(outs)
             self._commit(outs, new_aux)
@@ -630,8 +473,7 @@ class Executor:
         if self._outputs is None and self._pending is not None:
             arg_vals, aux_vals, key = self._pending
             with _obs_compiles.scope(self._obs_label):
-                outs, new_aux = self._dispatch_fwd(arg_vals, aux_vals,
-                                                   key, True)
+                outs, new_aux = self._jit_fwd(arg_vals, aux_vals, key, True)
             if self._sync_host_callbacks:
                 self._forced_sync(outs)
             self._commit(outs, new_aux)

@@ -7,8 +7,8 @@ processes over a stdlib line-protocol wire (``fleet.wire``, the
 
 * supervision — per-replica bounded-backoff respawn (the elastic
   discipline), PING liveness with the ProbeRing refused-vs-timeout
-  rule, warm restarts through the AOT executable cache (zero backend
-  compiles on respawn);
+  rule; a respawn reads its compiled programs from JAX's persistent
+  compilation cache;
 * routing + admission — sequences are sticky to the replica holding
   their KV pages; new requests go least-loaded (occupancy + queue
   depth from the heartbeat snapshots); the gateway sheds beyond its

@@ -8,9 +8,8 @@ One gateway process fronts N replica processes (each a
   spec, waits for its first PING, then watches the process. Death means
   bounded-backoff respawn (:func:`mxnet_tpu.elastic.backoff_delay`, the
   training supervisor's exact formula) under the
-  ``MXNET_TPU_FLEET_MAX_RESPAWNS`` budget. ``MXNET_TPU_COMPILE_CACHE``
-  passes through, so a respawn warm-starts off the AOT executable cache
-  and reaches first token with zero backend compiles.
+  ``MXNET_TPU_FLEET_MAX_RESPAWNS`` budget. A respawn reads its compiled
+  programs from JAX's persistent compilation cache.
 
 * **Routing + admission** — a sequence is STICKY to the replica that
   prefilled it by construction: one GEN stream drives the whole

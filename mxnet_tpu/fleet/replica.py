@@ -4,10 +4,8 @@ A replica process is a plain :class:`~mxnet_tpu.serve.server.
 GenerativeServer` (built from a JSON model spec, deterministic seeded
 init so every replica serves bit-identical weights — the fail-over
 re-prefill contract requires it) fronted by :class:`~mxnet_tpu.fleet.
-wire.ServeWire`. Respawns reach first token with zero backend compiles
-through the PR 16 AOT path: the supervisor passes
-``MXNET_TPU_COMPILE_CACHE`` through, so a warm restart deserializes
-every serve executable instead of recompiling.
+wire.ServeWire`. A respawn builds its programs again and reads their
+compiled code from JAX's persistent compilation cache.
 
 Also here: :class:`ScriptedDecodeServer`, a stdlib continuous-batching
 *simulator* with the same ``submit_generate()/stats()/close()`` surface.
@@ -240,7 +238,7 @@ class ReplicaFront(object):
 
     def _backend_compiles(self) -> int:
         """Backend compiles attributed to this server's scope (the PR 16
-        obs compile accounting) — 0 on an AOT-warm respawn."""
+        obs compile accounting)."""
         try:
             from .. import obs as _obs
             rep = _obs.report()
@@ -293,8 +291,7 @@ def build_from_spec(spec: Dict[str, Any]):
     m.bind(data_shapes=[("data", (1, s))],
            label_shapes=[("softmax_label", (1, s))])
     # initializers draw from global np.random: seeding it makes params
-    # bit-identical across replica processes (serve_decode_smoke's AOT
-    # drill relies on the same property)
+    # bit-identical across replica processes
     np.random.seed(int(spec.get("seed", 11)))
     m.init_params(_init.Uniform(0.05))
     return GenerativeServer(

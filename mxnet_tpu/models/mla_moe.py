@@ -82,7 +82,6 @@ class Arch:
         missing = [k for k in need if k not in doc]
         if missing:
             raise ValueError("architecture description lacks %s" % missing)
-        self.doc = dict(doc)
         self.d = int(doc["hidden_size"])
         self.heads = int(doc["num_attention_heads"])
         self.kv_rank = int(doc["kv_lora_rank"])
@@ -115,9 +114,6 @@ class Arch:
         # row. A row of 576 is not, and the TPU compiler then keeps the
         # plane positions-minor and copies all of it around every append
         self.row_stored = -(-self.row // 128) * 128
-
-    def sig(self) -> Tuple:
-        return tuple(sorted((k, repr(v)) for k, v in self.doc.items()))
 
 
 def param_shapes(arch: Arch) -> Dict[str, Tuple[int, ...]]:

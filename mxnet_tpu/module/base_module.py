@@ -316,15 +316,14 @@ class BaseModule(object):
 
         ``tune="auto"`` (docs/architecture/tune.md): before binding,
         load or search the tuned configuration for this program
-        (``mxnet_tpu.tune``) and apply it — remat / scan / group-update
-        / async-window via fit-scoped config overrides (restored when
+        (``mxnet_tpu.tune``) and apply it — remat / async-window via
+        fit-scoped config overrides (restored when
         fit returns — tuning one fit never reconfigures a later one),
         ``grad_accum`` and ``layout`` through these same arguments when
         the caller left them None (explicit arguments win). ``"static"``
         skips probe subprocesses (model-only pick); default follows the
-        ``MXNET_TPU_TUNE`` knob. With a stored config and a warm AOT
-        compile cache a restarted fit reaches its first step pre-tuned
-        with zero search cost and zero backend compiles.
+        ``MXNET_TPU_TUNE`` knob. With a stored config a restarted fit
+        reaches its first step pre-tuned with zero search cost.
         """
         assert num_epoch is not None, "please specify number of epochs"
         from ..initializer import Uniform
@@ -379,8 +378,8 @@ class BaseModule(object):
         # winners flow through mx.config overrides; grad_accum/layout go
         # through fit's own arguments — but ONLY when the caller left
         # them None (explicit user arguments always win). With a stored
-        # config and a warm AOT cache this path costs one JSON read:
-        # pre-tuned AND pre-compiled (docs/architecture/tune.md).
+        # config this path costs one JSON read
+        # (docs/architecture/tune.md).
         tune_mode = tune if tune is not None \
             else _config.get("MXNET_TPU_TUNE")
         if tune_mode in (True, 1, "on", "1", "yes", "true"):

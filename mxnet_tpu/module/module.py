@@ -967,30 +967,20 @@ class Module(BaseModule):
                     is_leaf=lambda x: isinstance(x, nd.NDArray) or x is None)
             return states
 
-        from .. import config as _config
         # ---- applied rematerialization (MXNET_TPU_REMAT; legacy alias
-        # MXNET_EXEC_ENABLE_REMAT). With a scan plan bound, the executor
-        # already wrapped each repeated block — exactly the granularity
-        # the remat-opportunity suggestion prescribes — so only the
-        # plan-less flat trace is wrapped here (whole-forward form).
+        # MXNET_EXEC_ENABLE_REMAT), whole-forward form: loss_fn below is
+        # wrapped in jax.checkpoint under the resolved save policy.
         # Historical caveat (tools/perf/doc_evidence.py, note_memory.md):
-        # on dense-attention transformers the flat save-policy form cuts
-        # little (the T^2 score tensors must exist during the backward
-        # recompute anyway); the per-block form over a scan plan is the
-        # one that recovers residual-stream activations.
+        # on dense-attention transformers this form cuts little (the T^2
+        # score tensors must exist during the backward recompute anyway).
         remat_policy = None
-        remat_name = getattr(self._exec, "_remat_name", "off")
-        if remat_name == "off" and (
-                _config.get("MXNET_TPU_REMAT") != "off"
-                or _config.get("MXNET_EXEC_ENABLE_REMAT")):
+        if _config.get("MXNET_TPU_REMAT") != "off" \
+                or _config.get("MXNET_EXEC_ENABLE_REMAT"):
             # the executor resolved the same whole-forward policy for
             # its non-fused fwd_bwd path already — reuse it (one
             # analysis run per bind, one remat_applied count)
             remat_policy = getattr(self._exec, "_fwd_bwd_remat", None)
-            if remat_policy is not None:
-                remat_name = getattr(self._exec, "_fwd_bwd_remat_name",
-                                     "auto")
-            else:
+            if remat_policy is None:
                 from .. import remat as _remat
                 shapes = {n: tuple(a.shape)
                           for n, a in self._exec.arg_dict.items()}
@@ -1001,11 +991,10 @@ class Module(BaseModule):
                 # their real width in the remat ranking (the PR 8 rule)
                 dts.update({n: a.dtype
                             for n, a in self._exec.aux_dict.items()})
-                remat_policy, remat_name = _remat.resolve_policy(
+                remat_policy, _ = _remat.resolve_policy(
                     self._symbol, input_shapes=shapes, input_dtypes=dts)
                 if remat_policy is not None:
                     _profiler.incr_counter("remat_applied")
-        self._remat_name = remat_name
 
         # ---- microbatch gradient accumulation (fit(grad_accum=N) /
         # set_grad_accum): the bound batch is split into N equal
@@ -1025,40 +1014,6 @@ class Module(BaseModule):
                         "dimension %d" % (accum, d.name, d.shape[0]))
             accum_scale = _accum_loss_scale(self._symbol, accum)
             _profiler.set_gauge("grad_accum", accum)
-
-        # ---- grouped optimizer update over scan var-lists (the PR 9
-        # close-out lever): with a scan plan bound, the forward already
-        # traces ONE block whatever the depth — but the optimizer update
-        # still traced L per-layer copies of itself (the residual O(L)
-        # program eqns). Each verified per-layer parameter family
-        # (scan_plan.var_lists) updates as ONE vmapped raw_update over
-        # the stacked (L, ...) arrays instead: the update body traces
-        # once per family. Families whose members resolve different
-        # lr/wd multipliers fall back to the per-param path (the vmapped
-        # body resolves mults once, at the template's index).
-        update_groups: List[List[str]] = []
-        grouped_names = set()
-        scan_plan = getattr(self._exec, "_scan_plan", None)
-        if scan_plan is not None and _config.get("MXNET_TPU_GROUP_UPDATE"):
-            pset = set(param_names)
-            for names in scan_plan.var_lists.values():
-                if len(names) < 2 or any(n not in pset for n in names):
-                    continue        # fixed/frozen member: eager per-param
-                mults = {
-                    (optimizer._resolve_mult(optimizer.lr_mult,
-                                             name2idx[n]),
-                     optimizer._resolve_mult(optimizer.wd_mult,
-                                             name2idx[n]))
-                    for n in names}
-                if len(mults) != 1:
-                    continue
-                update_groups.append(list(names))
-                grouped_names.update(names)
-            if update_groups:
-                _profiler.incr_counter("fused_update_grouped")
-                _profiler.set_gauge("fused_update_groups",
-                                    len(update_groups))
-        single_names = [n for n in param_names if n not in grouped_names]
 
         def step(params, states, aux, inputs, frozen_vals, key, lr, t):
             def forward(p_in, aux_in, inp, k):
@@ -1106,26 +1061,11 @@ class Module(BaseModule):
             else:
                 outs, new_aux, grads = forward(params, aux, inputs, key)
             new_params, new_states = {}, {}
-            for n in single_names:
+            for n in param_names:
                 w, s = optimizer.raw_update(
                     name2idx[n], params[n], grads[n], states[n], lr=lr, t=t)
                 new_params[n] = w
                 new_states[n] = s
-            for names in update_groups:
-                idx0 = name2idx[names[0]]
-                w_stk = jnp.stack([params[n] for n in names])
-                g_stk = jnp.stack([grads[n] for n in names])
-                s_stk = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs), *[states[n] for n in names])
-
-                def _one(w, g, s, _i=idx0):
-                    return optimizer.raw_update(_i, w, g, s, lr=lr, t=t)
-
-                nw, ns = jax.vmap(_one)(w_stk, g_stk, s_stk)
-                for i, n in enumerate(names):
-                    new_params[n] = nw[i]
-                    new_states[n] = jax.tree_util.tree_map(
-                        lambda x, _i=i: x[_i], ns)
             return outs, new_params, new_states, new_aux
 
         self._fused_num_update = self._optimizer.num_update
@@ -1166,17 +1106,10 @@ class Module(BaseModule):
                          jnp.asarray(lr, jnp.float32),
                          jnp.asarray(t, jnp.int32))
             with _obs_compiles.scope("fused_step", self._obs_sig):
-                if self._fused_call is not None:
-                    # AOT path: a deserialized (or explicitly compiled)
-                    # executable — no jit dispatch, no trace, no compile
-                    outs, new_params, new_states, new_aux = \
-                        self._fused_call(*call_args)
-                elif self._fused_aot_key is not None:
-                    outs, new_params, new_states, new_aux = \
-                        self._fused_aot_first(call_args)
-                else:
-                    outs, new_params, new_states, new_aux = \
-                        self._fused_jit(*call_args)
+                # looked up on the instance at every step: what replaces
+                # _fused_jit after the build runs from the next step on
+                outs, new_params, new_states, new_aux = \
+                    self._fused_jit(*call_args)
             if self._nancheck_mode != "off":
                 self._nancheck_accumulate(outs)
             if accum > 1:
@@ -1230,48 +1163,6 @@ class Module(BaseModule):
                 set(self._fused_states) != set(param_names):
             self._fused_states = make_states()
 
-        # ---- AOT warm start (MXNET_TPU_COMPILE_CACHE): key the fused
-        # step's executable on everything its trace bakes in, so a
-        # restarted process deserializes instead of compiling. Fenced to
-        # single-device programs (aot.py: deserialized multi-device
-        # executables mis-execute on this jax version).
-        self._fused_call = None
-        self._fused_aot_key = None
-        if _config.get("MXNET_TPU_COMPILE_CACHE"):
-            from .. import aot as _aot
-            if self._mesh is not None:
-                _profiler.incr_counter("aot_skip_multidevice")
-            elif _aot.supported():
-                try:
-                    from .. import amp as _amp
-                    opt = self._optimizer
-                    sig_parts = (
-                        "fused_step", self._symbol.tojson(),
-                        sorted((n, tuple(a.shape), str(a.dtype))
-                               for n, a in self._exec.arg_dict.items()),
-                        sorted((n, tuple(a.shape), str(a.dtype))
-                               for n, a in self._exec.aux_dict.items()),
-                        tuple(param_names), tuple(frozen),
-                        sorted(self._grad_req.items()),
-                        opt._fused_static_key(),
-                        # statics the module step bakes (FusedUpdater
-                        # passes these dynamically; this trace does not)
-                        opt.wd, opt.rescale_grad, opt.clip_gradient,
-                        sorted(opt.lr_mult.items()),
-                        sorted(opt.wd_mult.items()),
-                        sorted(opt.idx2name.items()),
-                        accum, accum_scale, remat_name,
-                        self._exec._scan_plan.n_layers
-                        if self._exec._scan_plan is not None else 0,
-                        (_amp.active(),
-                         str(_amp.compute_dtype()) if _amp.active()
-                         else ""),
-                    )
-                    self._fused_aot_key = _aot.digest(sig_parts)
-                except Exception:                           # noqa: BLE001
-                    # unkeyable configuration (unhashable optimizer
-                    # statics): no warm start, plain jit dispatch
-                    self._fused_aot_key = None
         if self._mesh is not None:
             # pin updated params to their declared shardings — otherwise
             # GSPMD may pick a different output layout after the first
@@ -1291,47 +1182,6 @@ class Module(BaseModule):
         else:
             self._fused_jit = jax.jit(step, donate_argnums=(0, 1, 2))
         self._fused = run
-
-    def _fused_aot_first(self, call_args):
-        """First fused dispatch under MXNET_TPU_COMPILE_CACHE: load the
-        serialized executable for this signature, or AOT-compile
-        (``jit.lower().compile()``) and serialize it for the next
-        process. Either way subsequent steps call a concrete executable
-        — zero jit dispatch overhead, zero recompiles by construction."""
-        from .. import aot as _aot
-        name, key = "fused_step", self._fused_aot_key
-        runner = _aot.load(name, key)
-        if runner is not None:
-            # first call through a deserialized executable runs on
-            # COPIES of the donated trees: if the entry is unusable the
-            # live buffers stay valid for the fresh-compile fallback.
-            # The tiny per-shape copy jits get their own compile scope —
-            # they must not show up as "the fused step compiled" in the
-            # warm-start accounting (the CI gate asserts zero there)
-            with _obs_compiles.scope("aot_first_copy"):
-                safe = jax.tree_util.tree_map(jnp.copy, call_args[:3])
-            try:
-                out = runner(*safe, *call_args[3:])
-            except Exception as exc:                        # noqa: BLE001
-                _profiler.incr_counter("aot_error")
-                self.logger.warning(
-                    "aot: cached fused-step executable failed (%s); "
-                    "recompiling", exc)
-                runner = None
-            else:
-                self._fused_call = runner
-                self._fused_aot_key = None
-                return out
-        try:
-            compiled = self._fused_jit.lower(*call_args).compile()
-        except Exception:                                   # noqa: BLE001
-            # lowering path failed (never expected); keep plain dispatch
-            self._fused_aot_key = None
-            return self._fused_jit(*call_args)
-        _aot.store(name, key, compiled)
-        self._fused_call = compiled
-        self._fused_aot_key = None
-        return compiled(*call_args)
 
     def _check_accum_needs_fused(self) -> None:
         if getattr(self, "_grad_accum", 1) > 1:

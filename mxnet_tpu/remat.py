@@ -23,11 +23,9 @@ The legacy bool ``MXNET_EXEC_ENABLE_REMAT=1`` is kept as an alias for
 ``dots_with_no_batch_dims_saveable`` (its documented historical
 behavior) and loses to an explicit ``MXNET_TPU_REMAT``.
 
-Application point (``Module._build_fused_step``): with a scan plan
-bound, each ``lax.scan`` body iteration — one repeated block — is
-wrapped, which is precisely the "wrap each repeated block" form the
-suggestion prescribes; without one, the whole forward is wrapped under
-the policy. ``remat_applied`` counts every build that actually wrapped,
+Application point (``Module._build_fused_step`` and the executor's
+``fwd_bwd``): the whole forward is wrapped under the policy.
+``remat_applied`` counts every build that actually wrapped,
 and the chosen policy is surfaced via the ``remat_policy`` extra in
 ``mx.obs.report()``'s counters companion gauges.
 """
@@ -75,12 +73,10 @@ def resolve_policy(symbol=None, input_shapes=None, input_dtypes=None
     if symbol is None:
         return None, "off"
     from .analysis import analyze_symbol
-    # only the policy NAME is consumed here; skip the pass's concrete
-    # block-residual calibration (the audit CLI / round-trip test ask
-    # for it explicitly)
+    # only the policy NAME is consumed here
     report = analyze_symbol(symbol, input_shapes=input_shapes,
                             input_dtypes=input_dtypes,
-                            context="remat-auto", calibrate_remat=False)
+                            context="remat-auto")
     remat = report.extras.get("remat") or {}
     suggestion = remat.get("suggestion") or {}
     name = suggestion.get("policy")

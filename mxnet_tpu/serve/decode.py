@@ -42,8 +42,8 @@ row by a reshape — one layout, and the read chosen by what the engine
 observes of its cache.
 
 The engine has two halves. :class:`DecodeEngine` is the generic one: the
-bucket tables, the dispatch under the CompileCache counters, AOT warm
-starts, the spans and counters. What it serves is a *family*: an object
+bucket tables, the dispatch under the CompileCache counters, the spans
+and counters. What it serves is a *family*: an object
 that knows one block's parameters, says which planes its cache holds
 (``kv_cache.Plane``) and builds the prefill and decode programs over
 them. :class:`DenseDecoder`, below, is the first family (the zoo
@@ -54,10 +54,9 @@ chunks, each appended to the cache and attending over it. ``docs/architecture/se
 says what a family owes the engine.
 
 The executable set is exactly |prompt buckets| + |decode buckets| (the
-server's CompileCache counters assert it), and each program is
-AOT-warm-startable through :mod:`mxnet_tpu.aot` — a restarted server
-reaches its first token with zero backend compiles (the CI drill
-asserts the obs compile accounting stays empty).
+server's CompileCache counters assert it). A restarted server builds
+each program again and reads its compiled code from JAX's persistent
+compilation cache (``config._apply_import_knobs``), which is always on.
 
 The decode forward is a pure-jax reimplementation of the Symbol graph,
 consuming the SAME parameter dict ``Module.get_params()`` returns —
@@ -70,7 +69,6 @@ broadcast multiply per read — tolerance documented in the same test.
 """
 from __future__ import annotations
 
-from contextlib import nullcontext as _nullcontext
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -105,10 +103,6 @@ class DecodeConfig:
         self.d_ff = int(d_ff)
         self.vocab_size = int(vocab_size)
         self.max_seq = int(max_seq)
-
-    def sig(self) -> Tuple:
-        return (self.num_layers, self.d_model, self.n_heads, self.d_ff,
-                self.vocab_size, self.max_seq)
 
 
 def extract_params(source, dtype: Optional[str] = None) -> Dict[str, Any]:
@@ -276,9 +270,6 @@ class DenseDecoder:
         self.cache = engine.cache
         self.prefill_chunk = engine.prefill_chunk
         self._multi_device = engine._multi_device
-
-    def sig(self) -> Tuple:
-        return self.cfg.sig()
 
     def executable_bound(self) -> int:
         return len(self.engine.prompt_buckets) + len(self.engine.seq_buckets)
@@ -527,7 +518,7 @@ def family_for(model, n_heads: Optional[int] = None,
 
 
 class DecodeEngine:
-    """The program table: builds, AOT-warm-starts and dispatches the
+    """The program table: builds and dispatches the
     per-bucket prefill/decode executables of one ``family`` over one
     :class:`KVCache` that holds the family's planes.
 
@@ -559,8 +550,7 @@ class DecodeEngine:
             if b % cache.page:
                 raise ValueError("prompt bucket %d not a multiple of the "
                                  "kv page %d" % (b, cache.page))
-        # multi-device (sharded cache) programs are AOT-fenced exactly
-        # like the executor forward (aot_skip_multidevice)
+        # a sharded cache is read through XLA, not by the Pallas kernel
         self._multi_device = cache._sharding is not None
         # the logits' width, for rows that are not fetched
         self.vocab = int(self.params["lm_head_weight"].shape[0])
@@ -584,61 +574,23 @@ class DecodeEngine:
                          % (needed, self.seq_buckets[-1]))
 
     # ---------------------------------------------------------- dispatch
-    def _sig_parts(self, kind: str, bucket: int) -> Tuple:
-        shapes = tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                              for k, v in self.params.items()))
-        # the state's shapes carry the cache layout: an executable
-        # stored for another layout is a miss, not a crash
-        state = tuple((tuple(a.shape), str(a.dtype))
-                      for a in self.cache.state())
-        # and what a program returns: an executable stored for other
-        # outputs is a miss, not a wrong unpacking
-        return ("serve", kind, bucket, self.family.sig(), shapes, state,
-                self.cache.page, self.prefill_chunk,
-                ("picked", "logits", "state"))
-
     def _dispatch(self, kind: str, bucket: int, builder, args: Tuple):
         """Bucket-program dispatch under the CompileCache counter
         discipline: first arrival builds (``<name>_compile``), every
         later arrival is ``<name>_cache_hit`` — zero steady-state
         recompiles is an assertable counter delta, exactly like
         InferenceServer's stateless path."""
-        from .. import aot
         sig = ("gen_" + kind, bucket)
         prog = self.compile_cache.get(sig)
         fresh = prog is None
         if fresh:
-            jitted = builder(bucket)
-            use_aot = (not self._multi_device and aot.enabled() is not None
-                       and aot.supported())
-            hit = False
-            if use_aot:
-                key = aot.digest(self._sig_parts(kind, bucket))
-                with _obs_compiles.scope(self.name, sig):
-                    prog, hit = aot.load_or_compile(
-                        "serve_%s" % kind, key, jitted, *args)
-                if hit:
-                    # first call of a LOADED executable runs on copies
-                    # of the donated cache state: a bad entry must not
-                    # invalidate the live buffers (the _fused
-                    # discipline). The copy happens OUTSIDE the obs
-                    # scope — its incidental jit(copy) must not show up
-                    # as a serve-attributed backend compile in the
-                    # warm-restart drill.
-                    import jax.numpy as jnp
-                    args = (args[0],
-                            tuple(jnp.array(a) for a in args[1])) \
-                        + args[2:]
-            else:
-                prog = jitted
-            with _obs_compiles.scope(self.name, sig) if not hit \
-                    else _nullcontext():
-                out = prog(*args)
-            self.compile_cache.put(sig, prog)
-            return out
+            prog = builder(bucket)
         with _obs_compiles.scope(self.name, sig):
             out = prog(*args)
-        self.compile_cache.note_success(sig)
+        if fresh:
+            self.compile_cache.put(sig, prog)
+        else:
+            self.compile_cache.note_success(sig)
         return out
 
     def prefill(self, prompt: np.ndarray, slot: int,

@@ -75,9 +75,6 @@ class MlaMoeDecoder:
         self.chunk_buckets: List[int] = sorted(
             {max(1, self.chunk >> s) for s in (3, 2, 1, 0)})
 
-    def sig(self) -> Tuple:
-        return ("mla_moe",) + self.arch.sig()
-
     def executable_bound(self) -> int:
         return (len(self.chunk_buckets) + 1) * len(self.engine.seq_buckets)
 

@@ -908,8 +908,7 @@ class GenerativeServer:
         or pow2 up to the model's max sequence).
     mesh, layout : optional
         Shard the cache's head axis over the layout's ``tp`` axis
-        (``island_specs("serve")``); AOT warm starts are skipped for
-        sharded caches (the multi-device fence).
+        (``island_specs("serve")``).
 
     The decode path (kv_cache/decode modules) imports lazily here: a
     process that only uses InferenceServer never pays for it — the CI

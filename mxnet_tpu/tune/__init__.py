@@ -2,8 +2,7 @@
 
 Given a module, an optimizer, a batch source and an HBM/wall-clock
 budget, :func:`search` finds the training configuration — remat policy
-x ``grad_accum`` x scan-over-layers x grouped update x async window x
-``SpecLayout`` — in three phases:
+x ``grad_accum`` x async window x ``SpecLayout`` — in three phases:
 
 1. **enumerate** the knob space (:mod:`.space`);
 2. **prune statically** with the analysis cost/memory/comm models
@@ -14,10 +13,9 @@ x ``grad_accum`` x scan-over-layers x grouped update x async window x
    ``obs_mfu`` / steps-per-sec (pod throughput on a pod) with
    ``loop_recompile == 0`` required.
 
-The winner persists next to the AOT executable cache (:mod:`.store`,
-keyed by the ``aot`` fingerprint scheme), so ``fit(tune="auto")`` on a
-restart is pre-tuned AND pre-compiled: zero search cost, zero backend
-compiles.
+The winner persists under ``MXNET_TPU_TUNE_STORE`` (:mod:`.store`), so
+``fit(tune="auto")`` on a restart is pre-tuned at zero search cost; its
+step is read from JAX's persistent compilation cache like any other.
 
 This package is LAZY (PEP 562 in ``mxnet_tpu/__init__``) and imported
 only when the tuner is armed — ``MXNET_TPU_TUNE`` unset means it never

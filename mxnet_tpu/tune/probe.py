@@ -15,9 +15,9 @@ Process isolation is the point, not a convenience: a probe compiles
 executables, mutates config knobs and bumps counters — none of which
 may leak into the searching process (subprocess-asserted by the probe
 isolation test, same discipline as the zero-cost gates). The child
-inherits ``MXNET_TPU_COMPILE_CACHE``, so the winning probe's fused-step
-executable seeds the AOT cache under the exact signature the tuned
-``fit`` computes later — the zero-compile warm restart.
+shares JAX's persistent compilation cache with the searching process, so
+the step the winning probe compiled is read from there by the tuned
+``fit``.
 """
 from __future__ import annotations
 
@@ -140,14 +140,13 @@ def run_probe(spec: Dict[str, Any],
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env = dict(os.environ)
-    # the probe inherits the platform and (critically) the AOT compile
-    # cache — including runtime config.set overrides, which subprocesses
-    # would otherwise not see; it must not inherit an armed tuner
+    # the probe inherits the platform and the store directory —
+    # including a runtime config.set override, which a subprocess would
+    # otherwise not see; it must not inherit an armed tuner
     from .. import config as _config
-    for knob in ("MXNET_TPU_COMPILE_CACHE", "MXNET_TPU_TUNE_STORE"):
-        val = _config.get(knob)
-        if val:
-            env[knob] = str(val)
+    store = _config.get("MXNET_TPU_TUNE_STORE")
+    if store:
+        env["MXNET_TPU_TUNE_STORE"] = str(store)
     env["MXNET_TPU_TUNE"] = ""
     env["PYTHONPATH"] = root + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")

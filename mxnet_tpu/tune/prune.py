@@ -5,15 +5,15 @@ Three rejections/orderings, all on the PR 8 cost/memory model via the
 ``analysis.tuning`` candidate hooks:
 
 * **hbm-budget**: a candidate whose static peak (microbatch-aware
-  liveness at its ``grad_accum``, minus its remat policy's calibrated
-  ``est_peak_saving``, over its layout's per-device sharding) exceeds
+  liveness at its ``grad_accum``, minus its remat policy's
+  ``est_bytes_saved``, over its layout's per-device sharding) exceeds
   the budget cannot bind — rejected, counted ``tune_pruned``.
 * **comm ranking**: layout candidates inherit their
   ``analysis.tuning.rank_layouts`` collective-bytes rank.
 * **overhead ordering**: among survivors, prefer the cheaper mechanism
   — no remat over remat (recompute FLOPs), small ``grad_accum`` over
-  large (scan overhead), scan+group+async defaults over their off
-  arms — so the probe budget is spent on the plausible frontier.
+  large (scan overhead), the async window over its off arm — so the
+  probe budget is spent on the plausible frontier.
 """
 from __future__ import annotations
 
@@ -30,8 +30,7 @@ def _remat_saving(report, policy: str) -> int:
     for cand in _tuning.remat_candidates(report):
         if cand["policy"] == policy or (
                 policy == "auto" and cand["policy"] != "off"):
-            return int(cand.get("est_peak_saving")
-                       or cand.get("est_bytes_saved") or 0)
+            return int(cand.get("est_bytes_saved") or 0)
     return 0
 
 
@@ -76,9 +75,7 @@ def static_rank(sym, input_shapes: Dict[str, tuple],
         saving = _remat_saving(rep, cand.remat) if cand.remat != "off" \
             else 0
         # floor at the bound buffers: remat recomputes activations but
-        # can never erase params/inputs (the calibrated saving is
-        # measured on the bigger fwd+bwd program and may exceed this
-        # static graph's whole activation term)
+        # can never erase params/inputs
         bound = int((rep.extras.get("cost") or {})
                     .get("bound_bytes") or 0)
         est_peak = None if peak is None else max(bound, peak - saving)
@@ -115,8 +112,6 @@ def static_rank(sym, input_shapes: Dict[str, tuple],
         score = (comm_rank,
                  0 if cand.remat == "off" else 1,
                  cand.grad_accum,
-                 0 if cand.scan_layers == "off" else 1,
-                 0 if cand.group_update else 1,
                  0 if cand.async_window else 1,
                  cand.order_key())
         scored.append((score, cand))

@@ -5,8 +5,7 @@
 
 1. computes the :func:`~.store.program_key` and returns a stored
    :class:`~.store.TunedConfig` immediately when one exists (a restart
-   pays ZERO search cost — and because the winning probe compiled under
-   the same AOT cache, zero backend compiles too);
+   pays ZERO search cost);
 2. enumerates the knob space (:func:`~.space.enumerate_space`),
    statically prunes and ranks it against the HBM budget and the comm
    model (:func:`~.prune.static_rank` over ``analysis.tuning``) — no
@@ -15,7 +14,7 @@
    under per-probe and total deadlines (:mod:`~.probe`), scoring by
    ``obs_mfu`` (pod throughput when a pod is live, steps/s as the
    denominator-free fallback) with ``loop_recompile == 0`` required;
-4. persists the winner next to the AOT executables and returns it.
+4. persists the winner (``MXNET_TPU_TUNE_STORE``) and returns it.
 
 Determinism: with probing disabled (``mode="static"`` or
 ``max_probes=0``) the result is a pure function of (program, budget,

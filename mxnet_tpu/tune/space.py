@@ -1,9 +1,8 @@
 """The tuner's knob space: one frozen :class:`Candidate` per point.
 
 A candidate is exactly the set of PR 9/14 performance levers a restart
-can re-apply from a stored record: remat policy x grad_accum x
-scan-over-layers x grouped update x async window x ``SpecLayout``
-factorization. :func:`enumerate_space` yields the cross product in a
+can re-apply from a stored record: remat policy x grad_accum x async
+window x ``SpecLayout`` factorization. :func:`enumerate_space` yields the cross product in a
 deterministic order with the DEFAULT configuration first — the search
 always probes the default, so the winner is >= default by construction.
 """
@@ -27,8 +26,6 @@ class Candidate:
     raises TypeError, exactly when candidates tie on a score prefix."""
     remat: str = "off"            # off | auto | a checkpoint-policy name
     grad_accum: int = 1
-    scan_layers: str = "off"      # off | auto
-    group_update: bool = True
     async_window: int = 2
     layout: Optional[Tuple[int, int, int]] = None   # (data, fsdp, tp)
 
@@ -36,8 +33,7 @@ class Candidate:
         """Total-orderable deterministic sort tail: field order, the
         default arm of each knob first, ``layout=None`` below any
         factorization (None -> ``()``)."""
-        return (self.remat, self.grad_accum, self.scan_layers,
-                not self.group_update, self.async_window,
+        return (self.remat, self.grad_accum, self.async_window,
                 self.layout or ())
 
     def knobs(self) -> Dict[str, Any]:
@@ -46,27 +42,23 @@ class Candidate:
         the environment)."""
         return {
             "MXNET_TPU_REMAT": self.remat,
-            "MXNET_TPU_SCAN_LAYERS": self.scan_layers,
-            "MXNET_TPU_GROUP_UPDATE": self.group_update,
             "MXNET_TPU_ASYNC_WINDOW": self.async_window,
         }
 
     def to_dict(self) -> Dict[str, Any]:
         return {
             "remat": self.remat, "grad_accum": self.grad_accum,
-            "scan_layers": self.scan_layers,
-            "group_update": self.group_update,
             "async_window": self.async_window,
             "layout": list(self.layout) if self.layout else None,
         }
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "Candidate":
+        """Fields this class does not have (an older record's) are
+        ignored."""
         lay = d.get("layout")
         return cls(remat=str(d.get("remat", "off")),
                    grad_accum=int(d.get("grad_accum", 1)),
-                   scan_layers=str(d.get("scan_layers", "off")),
-                   group_update=bool(d.get("group_update", True)),
                    async_window=int(d.get("async_window", 2)),
                    layout=tuple(int(x) for x in lay) if lay else None)
 
@@ -92,14 +84,10 @@ def enumerate_space(batch_size: int, n_devices: int = 1,
     for lay in lays:
         for remat in remat_policies:
             for accum in accums:
-                for scan in ("off", "auto"):
-                    for group in (True, False):
-                        for window in (2, 0):
-                            c = Candidate(
-                                remat=remat, grad_accum=accum,
-                                scan_layers=scan, group_update=group,
-                                async_window=window, layout=lay)
-                            if c not in seen:
-                                seen.add(c)
-                                out.append(c)
+                for window in (2, 0):
+                    c = Candidate(remat=remat, grad_accum=accum,
+                                  async_window=window, layout=lay)
+                    if c not in seen:
+                        seen.add(c)
+                        out.append(c)
     return out

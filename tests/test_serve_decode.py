@@ -360,26 +360,6 @@ def test_a_sampling_request_among_greedy_ones(module):
          ([9, 2, 6, 5], {"max_new_tokens": 8})])
 
 
-def test_aot_key_carries_the_cache_layout(module):
-    """An executable stored under another cache layout is a miss, not a
-    crash: the AOT key's parts hold the state's shapes and dtypes."""
-    from mxnet_tpu.serve.decode import extract_params
-    params = extract_params(module)
-
-    def parts(**kw):
-        eng, _cache = _dense_engine(params, 2, "sig", **kw)
-        return eng._sig_parts("decode", 8)
-
-    f32, int8 = parts(int8=False), parts(int8=True)
-    assert ((LAYERS, 2, SEQ, DMODEL), "float32") in f32[5]
-    assert ((LAYERS, 2, SEQ, DMODEL), "int8") in int8[5]
-    assert ((LAYERS, 2, HEADS, SEQ // 4), "float32") in int8[5]
-    assert f32 != int8
-    # and what the program returns: an executable stored when a step
-    # returned (logits, state) is a miss, not a wrong unpacking
-    assert f32[-1] == ("picked", "logits", "state")
-
-
 # ------------------------------------------------------- scheduler behavior
 
 def test_streaming_iterator_and_callback(module):

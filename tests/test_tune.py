@@ -278,11 +278,32 @@ class TestStore:
             json.dump({"version": 99, "candidate": {}}, f)
         assert load_config("v" * 64) is None
 
+    def test_older_record_loads_and_its_extra_fields_are_ignored(
+            self, tmp_path, monkeypatch):
+        """A record stored when the space still had scan-over-layers and
+        the grouped update is read; the two fields are dropped."""
+        from mxnet_tpu.tune.space import Candidate
+        from mxnet_tpu.tune.store import load_config
+        monkeypatch.setenv("MXNET_TPU_TUNE_STORE", str(tmp_path))
+        with open(os.path.join(str(tmp_path),
+                               "tune-%s.json" % ("o" * 64)), "w") as f:
+            json.dump({"version": 1, "key": "o" * 64, "source": "probe",
+                       "candidate": {"remat": "auto", "grad_accum": 2,
+                                     "scan_layers": "auto",
+                                     "group_update": False,
+                                     "async_window": 0, "layout": None}},
+                      f)
+        got = load_config("o" * 64)
+        assert got.candidate == Candidate(remat="auto", grad_accum=2,
+                                          async_window=0)
+        assert set(got.candidate.knobs()) == {"MXNET_TPU_REMAT",
+                                              "MXNET_TPU_ASYNC_WINDOW"}
+        assert set(got.candidate.knobs()) <= set(mx.config.KNOBS)
+
     def test_no_store_dir_is_none(self, monkeypatch):
         from mxnet_tpu.tune.space import Candidate
         from mxnet_tpu.tune.store import TunedConfig, store_config
         monkeypatch.delenv("MXNET_TPU_TUNE_STORE", raising=False)
-        monkeypatch.delenv("MXNET_TPU_COMPILE_CACHE", raising=False)
         assert store_config(TunedConfig(candidate=Candidate(),
                                         key="x" * 64)) is None
 
@@ -311,7 +332,7 @@ class TestProbeIsolation:
         # always probed in addition (the MAX_PROBES help-text contract)
         assert cfg.n_probed == 2
         after = profiler.counters()
-        # the probe's own loop/aot/obs counters must NOT appear here;
+        # the probe's own loop/obs counters must NOT appear here;
         # only the tuner's bookkeeping may move
         moved = {k for k in after
                  if after[k] != before_counters.get(k, 0)}
@@ -362,8 +383,7 @@ class TestFitTune:
         # with tune off must not inherit them, and a pre-existing user
         # override must survive the tuned fit untouched
         from mxnet_tpu import config as _cfg
-        knobs = ("MXNET_TPU_REMAT", "MXNET_TPU_SCAN_LAYERS",
-                 "MXNET_TPU_GROUP_UPDATE", "MXNET_TPU_ASYNC_WINDOW")
+        knobs = ("MXNET_TPU_REMAT", "MXNET_TPU_ASYNC_WINDOW")
         _cfg.set("MXNET_TPU_REMAT", "off")
         try:
             before = _cfg.snapshot_overrides(knobs)

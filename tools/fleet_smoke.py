@@ -6,7 +6,7 @@ every subprocess wait under a hard timeout (the PhaseGuard discipline —
 a wedged drill must fail the job, not hang it):
 
 1. **Fleet drill** — a gateway supervises THREE replica processes
-   serving bit-identical weights off a shared executable cache.
+   serving bit-identical weights.
    ``MXNET_TPU_FLEET_FAULT_REPLICA=1:replica.die@6:hostkill`` arms rank
    1 (first spawn only) to SIGKILL itself after its 6th emitted token
    frame. Under a concurrent request wave:
@@ -17,9 +17,8 @@ a wedged drill must fail the job, not hang it):
      duplicated, none lost, ``fleet_dup_dropped == 0``);
    - survivors are undisturbed (their streams are part of the same
      bit-equality check);
-   - the supervisor respawns rank 1, which rejoins with ZERO backend
-     compiles (AOT warm restart through the shared cache) and serves
-     real traffic in the next wave;
+   - the supervisor respawns rank 1, which rejoins and serves real
+     traffic in the next wave;
    - the federated ``/metrics`` text parses strictly and carries
      ``replica="0|1|2"`` labeled samples.
 
@@ -34,7 +33,6 @@ import json
 import os
 import subprocess
 import sys
-import tempfile
 import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -50,9 +48,7 @@ NEW_TOKENS = 12
 
 def _reference_streams():
     """Single-server ground truth: same spec, same seeded init — what
-    every fleet stream must equal bit-for-bit. Building it first also
-    warms the shared executable cache, so replica spawns (and the
-    respawn under test) start AOT-warm."""
+    every fleet stream must equal bit-for-bit."""
     from mxnet_tpu.fleet.replica import build_from_spec
     srv = build_from_spec(dict(SPEC, name="fleetref"))
     try:
@@ -77,8 +73,6 @@ def check_fleet_drill():
     from mxnet_tpu import config as _config
     from mxnet_tpu.obs.prometheus import parse_prometheus
 
-    cache_dir = tempfile.mkdtemp(prefix="fleet_smoke_aot_")
-    os.environ["MXNET_TPU_COMPILE_CACHE"] = cache_dir
     # rank 1, FIRST spawn only, dies after its 6th emitted token frame;
     # hostkill (with the coordinated-parent marker stripped by the
     # supervisor) SIGKILLs exactly the replica process — no cleanup,
@@ -88,8 +82,7 @@ def check_fleet_drill():
     _config.set("MXNET_TPU_ELASTIC_BACKOFF", 0.2)
 
     ref = _reference_streams()
-    print("reference streams computed (%d prompts), cache warm"
-          % len(ref))
+    print("reference streams computed (%d prompts)" % len(ref))
 
     from mxnet_tpu.fleet import Gateway
     gw = Gateway(spec=SPEC, replicas=3, port=None, stats_period=0.2,
@@ -113,7 +106,7 @@ def check_fleet_drill():
               "rank-1 death (failover=%d, dup_dropped=0) in %.1fs"
               % (len(PROMPTS), st["failover"], time.monotonic() - t0))
 
-        # ---- respawn: rank 1 rejoins, AOT-warm (zero backend compiles)
+        # ---- respawn: rank 1 rejoins
         t0 = time.monotonic()
         deadline = time.monotonic() + 600.0
         while time.monotonic() < deadline:
@@ -127,18 +120,6 @@ def check_fleet_drill():
             raise AssertionError("rank 1 never rejoined: %s" % st)
         print("rank 1 respawned and live in %.1fs (restarts=%d)"
               % (time.monotonic() - t0, st["replicas"][1]["restarts"]))
-        # heartbeat carries the respawned process's compile accounting
-        deadline = time.monotonic() + 60.0
-        while time.monotonic() < deadline:
-            bc = gw.stats()["replicas"][1]["stats"].get("backend_compiles")
-            if bc is not None:
-                break
-            time.sleep(0.2)
-        assert bc == 0, \
-            "respawned replica compiled %s serve programs (want 0: " \
-            "AOT warm restart)" % bc
-        print("PASS warm respawn: rank 1 rejoined with 0 backend compiles")
-
         # ---- wave 2: the healed world serves, rank 1 takes traffic
         _wave(gw, ref)
         r1_tokens = gw.stats()["replicas"][1]["stats"].get("tokens", 0)

@@ -251,18 +251,14 @@ print(json.dumps({"ttft_s": ttft, "backend_compiles": backend,
 """
 
 
-def _cold_start_ttft(cache_dir=None):
-    """Fresh process -> first generated token, with/without the
-    executable cache. Returns the subprocess's own measurement."""
+def _cold_start_ttft():
+    """Fresh process -> first generated token. Returns the subprocess's
+    own measurement."""
     import subprocess
     root = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "..", "..")
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
-    if cache_dir is None:
-        env.pop("MXNET_TPU_COMPILE_CACHE", None)
-    else:
-        env["MXNET_TPU_COMPILE_CACHE"] = cache_dir
     code = _COLD_START_SCRIPT % {"root": os.path.abspath(root),
                                  "geo": _DECODE_GEO}
     out = subprocess.run([sys.executable, "-c", code], env=env,
@@ -275,8 +271,7 @@ def _cold_start_ttft(cache_dir=None):
 def _bench_decode(quick=False, reps=1):
     """The ISSUE 16 acceptance table: aggregate tok/s continuous vs
     sequential batch-1, TTFT/TPOT percentiles, zero steady-state
-    recompiles, cold-start-to-first-token with and without the
-    executable cache."""
+    recompiles, cold-start-to-first-token."""
     mod = _build_decode_module()
     new_tokens = 8 if quick else 16
     client_loads = [8] if quick else [8, 32]
@@ -324,22 +319,9 @@ def _bench_decode(quick=False, reps=1):
                  best["recompiles"]))
 
     if not quick:
-        import tempfile
-        cold = _cold_start_ttft(cache_dir=None)
-        cache_dir = tempfile.mkdtemp(prefix="serve_bench_aot_")
-        _cold_start_ttft(cache_dir=cache_dir)       # populate
-        warm = _cold_start_ttft(cache_dir=cache_dir)
-        assert warm["backend_compiles"] == 0, (
-            "AOT warm restart still compiled %d serve programs"
-            % warm["backend_compiles"])
-        out["cold_start"] = {
-            "no_cache_ttft_s": round(cold["ttft_s"], 3),
-            "compile_cache_ttft_s": round(warm["ttft_s"], 3),
-            "compile_cache_backend_compiles": warm["backend_compiles"],
-        }
-        print("decode cold-start ttft: %.3fs uncached -> %.3fs with "
-              "MXNET_TPU_COMPILE_CACHE (0 backend compiles)"
-              % (cold["ttft_s"], warm["ttft_s"]))
+        cold = _cold_start_ttft()
+        out["cold_start"] = {"ttft_s": round(cold["ttft_s"], 3)}
+        print("decode cold-start ttft: %.3fs" % cold["ttft_s"])
     return out
 
 
@@ -448,14 +430,11 @@ def _fleet_kill_under_load():
     bit-equal to a single-server reference)."""
     import os as _os
     import signal as _signal
-    import tempfile as _tempfile
     from mxnet_tpu.fleet import Gateway
     from mxnet_tpu.fleet.replica import build_from_spec
     geo = dict(_DECODE_GEO, seq_len=32)
     spec = {"kind": "transformer", "geo": geo, "seed": 11, "slots": 4,
             "page": 8, "name": "benchkill"}
-    _os.environ["MXNET_TPU_COMPILE_CACHE"] = _tempfile.mkdtemp(
-        prefix="fleet_bench_aot_")
     ref_srv = build_from_spec(dict(spec, name="benchkillref"))
     prompts = [[3, 1, 4], [1, 5, 9], [2, 6], [5, 3, 5],
                [8, 9, 7], [3, 2], [7, 7, 1], [9, 4]]

@@ -4,7 +4,7 @@
 For each zoo net the search runs with the probe budget of a real
 ``fit(tune="auto")`` cold start. The DEFAULT candidate — exactly the
 hand-tuned configuration ``bench.py`` runs (repo knob defaults: remat
-off, scan auto, group update on, async window 2) — is always probed
+off, async window 2) — is always probed
 first, so every record carries the honest head-to-head: the tuner's
 winner and the hand-tuned baseline scored by the SAME obs probe
 harness on the same machine. Recorded per net:
