@@ -205,7 +205,7 @@ def _lowered_fused_step(mod, inputs):
 
 def _check_mosaic_kernels(mod, inputs):
     text = _lowered_fused_step(mod, inputs).as_text()
-    for kernel in ("_fa_kernel", "_fa_bwd_dq_kernel", "_fa_bwd_dkv_kernel"):
+    for kernel in ("_fa_kernel", "_fa_bwd_kernel"):
         check("tpu_custom_call" in text
               and 'kernel_name = "%s"' % kernel in text,
               "no Mosaic custom call for %s in the lowered fused step "
@@ -218,7 +218,7 @@ def _flash_rows_per_chip(mod, inputs):
     hlo = _lowered_fused_step(mod, inputs).compile().as_text()
     calls = [line for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    check(len(calls) >= 3, "no Mosaic custom calls in the compiled step")
+    check(len(calls) >= 2, "no Mosaic custom calls in the compiled step")
     pattern = r"bf16\[(\d+),%d,%d\]" % (SEQ, D_MODEL // HEADS)
     return {int(n) for line in calls for n in re.findall(pattern, line)}
 

@@ -351,3 +351,45 @@ def test_a_prefill_chunk_appends_in_place_and_fits_beside_the_weights(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == _nbytes(state)
     assert mem.temp_size_in_bytes < 1e9, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_kernels_compile_at_the_training_cells_widths(one_chip,
+                                                            causal):
+    """ISSUE 31: the forward and the one-pass backward at ``opt13_fit``'s
+    shapes (64 heads of 2 rows, 2048 positions, d_head 64, bf16) through
+    Mosaic. The row statistics cross HBM as lane-dense rows (the old
+    ``f32[64,2048,8]`` was laid out 128 wide: 67 MB for half a megabyte),
+    and the walk steps over no tile it does no arithmetic on (the old grid
+    read 30 visited of 48 a head under ``causal``)."""
+    import importlib
+    import jax
+    import jax.numpy as jnp
+    import mxnet_tpu as mx
+    fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+    head = jax.ShapeDtypeStruct((64, 2048, D_HEAD), jnp.bfloat16,
+                                sharding=one_chip)
+    block_q, block_k = fa._blocks(2048, 2048, 512, 512)
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda *a: fa._fa(*a, D_HEAD ** -0.5, causal, block_q, block_k,
+                              False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    fa._fa_forward.clear_cache()    # built, and counted, in here
+    fa._fa_backward.clear_cache()
+    with mx.profiler.counter_delta() as tiles:
+        compiled = jax.jit(grads).trace(head, head, head).lower(
+            lowering_platforms=("tpu",)).compile()
+    visited = tiles.get("flash_attn_tiles_visited")
+    assert visited and visited == tiles.get("flash_attn_tiles_grid")
+    whole = 2 * (2048 // block_q) * (2048 // block_k)  # two kernels
+    assert visited == whole if not causal else visited < 0.7 * whole
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2, calls
+    for line in calls:
+        assert not re.search(r"f32\[[\d,]*,8\]", line), line
+        # lse and delta: a row a Q block
+        assert "f32[64,%d,%d]" % (2048 // block_q, block_q) in line, line
