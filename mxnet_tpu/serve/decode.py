@@ -50,8 +50,11 @@ them. :class:`DenseDecoder`, below, is the first family (the zoo
 transformer, everything this docstring has described so far);
 ``serve/mla_moe.py`` holds the second (latent attention over a latent
 cache plane, routed experts beside a shared one), whose prompts prefill in
-chunks, each appended to the cache and attending over it. ``docs/architecture/serving_families.md``
-says what a family owes the engine.
+chunks, each appended to the cache and attending over it;
+``serve/sparse_linear.py`` the third (block-sparse attention over selected
+key blocks beside lightning layers whose recurrent state is a plane a
+slot). ``docs/architecture/serving_families.md`` says what a family owes
+the engine.
 
 The executable set is exactly |prompt buckets| + |decode buckets| (the
 server's CompileCache counters assert it). A restarted server builds
@@ -501,6 +504,69 @@ class DenseDecoder:
         return jax.jit(fn, donate_argnums=(1,))
 
 
+# ------------------------------------------- families that prefill in chunks
+
+
+def chunk_buckets(chunk: int, multiple: int = 1) -> List[int]:
+    """The sizes a prompt's last chunk is padded to: power-of-two shares
+    of ``chunk`` down to an eighth, those that are whole ``multiple``s."""
+    return sorted({c for c in (max(1, chunk >> s) for s in (3, 2, 1, 0))
+                   if c % multiple == 0})
+
+
+def check_chunked(engine, chunk: int, who: str) -> None:
+    """What a family that prefills in chunks asks of its engine: one
+    device, slots of whole chunks, buckets that reach a slot's end."""
+    cache = engine.cache
+    if engine._multi_device:
+        raise MXNetError("serve %s: a sharded cache is not supported" % who)
+    if cache.max_seq % chunk:
+        raise ValueError("max_seq %d not a multiple of the prefill "
+                         "chunk %d" % (cache.max_seq, chunk))
+    if engine.seq_buckets[-1] < cache.max_seq:
+        raise ValueError("the sequence buckets end at %d, a slot at %d"
+                         % (engine.seq_buckets[-1], cache.max_seq))
+
+
+def chunked_prefill_calls(engine, chunk: int, buckets: List[int], builder,
+                          prompt: np.ndarray, slot: int):
+    """A prompt chunk after chunk, as a family's ``prefill_calls`` yields
+    them: ``((chunk, context), builder, (tokens, slot, start, length),
+    span attributes)``, the last chunk padded to its bucket, the context
+    the sequence bucket that holds the chunk's end."""
+    n = int(prompt.shape[0])
+    for start in range(0, n, chunk):
+        left = min(chunk, n - start)
+        c_b = next(c for c in buckets if left <= c)
+        ctx_b = engine.seq_bucket(start + c_b)
+        tokens = np.zeros((c_b,), np.int32)
+        tokens[:left] = np.asarray(prompt[start:start + left], np.int32)
+        yield (c_b, ctx_b), builder, \
+            (tokens, np.int32(slot), np.int32(start), np.int32(n)), \
+            {"chunk": c_b, "context": ctx_b}
+
+
+def query_block(heads: int, c_b: int, ctx_b: int, budget: int) -> int:
+    """Queries a block, so that a block's float32 scores over the context
+    stay under ``budget`` bytes: a power of two that divides the chunk."""
+    blk = max(1, budget // (heads * ctx_b * 4))
+    blk = 1 << (blk.bit_length() - 1)
+    while c_b % blk:
+        blk >>= 1
+    return min(blk, c_b)
+
+
+def over_query_blocks(f, c_b: int, blk: int, *xs):
+    """``f`` over blocks of ``blk`` of the chunk's ``c_b`` queries (the
+    leading axis of every ``xs``), rows put together."""
+    from jax import lax
+    if blk == c_b:
+        return f(*xs)
+    cut = [x.reshape((c_b // blk, blk) + x.shape[1:]) for x in xs]
+    out = lax.map(lambda b: f(*b), tuple(cut))
+    return out.reshape((c_b,) + out.shape[2:])
+
+
 def family_for(model, n_heads: Optional[int] = None,
                arch: Optional[Dict[str, Any]] = None):
     """The family that serves ``model``: the dense decoder when only
@@ -510,9 +576,10 @@ def family_for(model, n_heads: Optional[int] = None,
             raise ValueError("GenerativeServer needs n_heads (the dense "
                              "decoder) or arch (a described block)")
         return DenseDecoder(extract_params(model), n_heads)
-    from . import mla_moe           # it imports this module
-    if mla_moe.serves(arch):
-        return mla_moe.make(model, arch)
+    from . import mla_moe, sparse_linear    # they import this module
+    for family in (mla_moe, sparse_linear):
+        if family.serves(arch):
+            return family.make(model, arch)
     raise ValueError("GenerativeServer serves no model_type %r"
                      % (arch.get("model_type"),))
 
@@ -632,9 +699,10 @@ class DecodeEngine:
                  np.asarray(active, bool)))
         self.cache.set_state(new_state)
         # the fetch is the step's device fence: what the scheduler waits
-        # here is the step's device time and the copy of ``picked``, a few
-        # integers (a family's own counts ride in it: no second
-        # transfer). The logits stay on the device unless someone samples.
+        # here is the step's device time and the copy of ``picked``, the
+        # slots' integers (what a family puts behind them, counts or what
+        # a step selected, rides in it: no second transfer). The logits
+        # stay on the device unless someone samples.
         with _profiler.span("gen_logits_fetch", "serve"):
             picked = np.asarray(picked)
             out = np.asarray(out) if logits else None

@@ -38,8 +38,18 @@ plane, all planes under one ledger. The dense decoder's planes are ``k``
 and ``v`` (:func:`dense_planes`; int8 adds their two scale planes); a
 latent-attention block keeps one ``latent`` plane, a row ``[c_kv |
 k_rope]`` a position on every layer (``serve/mla_moe.py``).
-``hbm_bytes``, ``audit`` and :func:`max_slots_for` reckon from the
-planes.
+A plane need not hold a row a position: ``tail`` gives a slot's share
+any other shape. The third family (``serve/sparse_linear.py``) keeps two
+such beside its ``k`` and ``v``: ``kc``, its indexer's compressed keys, a
+row a ``stride`` positions (``tail=(max_seq // stride, width)``), and
+``state``, its lightning layers' recurrent state, ``(heads * d_head,
+d_head)`` float32 a slot whatever ``max_seq`` (kind ``slot_state``). The
+ledger pages positions, so it pages the positional planes only; a per-slot
+plane costs its whole share for as long as the slot is held, and no
+position mask hides what an earlier tenant left in it: the family's
+prefill starts a prompt's first chunk from zeros (nothing is zeroed at
+``acquire``). ``hbm_bytes``, ``audit`` and :func:`max_slots_for` reckon
+from the planes, of every kind.
 
 Budget audit: :meth:`KVCache.audit` runs the analyzer's
 ``hbm-budget`` reservation check (``analysis.memory_passes
@@ -72,7 +82,8 @@ class Plane:
     max_seq, width)``. ``tail`` overrides the last two axes (the int8
     scale planes are ``(heads, pages)`` a slot), ``fill`` is the value an
     untouched cache holds, ``kind`` the layout claim a sharded cache
-    places the plane by."""
+    places the plane by (``slot_state``: a state a slot that does not grow
+    with ``max_seq``, and that no sharded layout places yet)."""
 
     __slots__ = ("name", "layers", "width", "dtype", "tail", "fill", "kind")
 
@@ -97,9 +108,9 @@ class Plane:
 
     def describe(self) -> str:
         if self.tail is not None:
-            return "%s %d layers x %s %s" % (self.name, self.layers,
-                                             "x".join(map(str, self.tail)),
-                                             self.dtype)
+            return "%s %d layers x %s %s%s" % (
+                self.name, self.layers, "x".join(map(str, self.tail)),
+                self.dtype, " a slot" if self.kind == "slot_state" else "")
         return "%s %d layers x rows of %d %s" % (self.name, self.layers,
                                                  self.width, self.dtype)
 
@@ -308,6 +319,9 @@ class KVCache:
         if self._sharding is None:
             return arr
         import jax
+        if kind not in self._sharding:
+            raise MXNetError("kv cache %s: no sharded layout places a "
+                             "plane of kind %r" % (self.name, kind))
         return jax.device_put(arr, self._sharding[kind])
 
     # ------------------------------------------------------------- state

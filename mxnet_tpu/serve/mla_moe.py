@@ -32,7 +32,9 @@ import numpy as np
 from .. import profiler as _profiler
 from ..base import MXNetError
 from ..models import mla_moe as _m
-from .decode import extract_params, greedy_tokens
+from .decode import (check_chunked, chunk_buckets, chunked_prefill_calls,
+                     extract_params, greedy_tokens, over_query_blocks,
+                     query_block)
 
 __all__ = ["MlaMoeDecoder", "serves", "make"]
 
@@ -63,17 +65,8 @@ class MlaMoeDecoder:
         self.engine = engine
         self.cache = engine.cache
         self.chunk = int(engine.prefill_chunk)
-        if engine._multi_device:
-            raise MXNetError("serve mla_moe: a sharded cache is not "
-                             "supported")
-        if self.cache.max_seq % self.chunk:
-            raise ValueError("max_seq %d not a multiple of the prefill "
-                             "chunk %d" % (self.cache.max_seq, self.chunk))
-        if engine.seq_buckets[-1] < self.cache.max_seq:
-            raise ValueError("the sequence buckets end at %d, a slot at %d"
-                             % (engine.seq_buckets[-1], self.cache.max_seq))
-        self.chunk_buckets: List[int] = sorted(
-            {max(1, self.chunk >> s) for s in (3, 2, 1, 0)})
+        check_chunked(engine, self.chunk, "mla_moe")
+        self.chunk_buckets: List[int] = chunk_buckets(self.chunk)
 
     def executable_bound(self) -> int:
         return (len(self.chunk_buckets) + 1) * len(self.engine.seq_buckets)
@@ -82,16 +75,9 @@ class MlaMoeDecoder:
     def prefill_calls(self, prompt: np.ndarray, slot: int):
         """Chunk after chunk: ``((chunk, context), builder, args, span
         attributes)``."""
-        n = int(prompt.shape[0])
-        for start in range(0, n, self.chunk):
-            left = min(self.chunk, n - start)
-            c_b = next(c for c in self.chunk_buckets if left <= c)
-            ctx_b = self.engine.seq_bucket(start + c_b)
-            tokens = np.zeros((c_b,), np.int32)
-            tokens[:left] = np.asarray(prompt[start:start + left], np.int32)
-            yield (c_b, ctx_b), self.build_prefill, \
-                (tokens, np.int32(slot), np.int32(start), np.int32(n)), \
-                {"chunk": c_b, "context": ctx_b}
+        return chunked_prefill_calls(self.engine, self.chunk,
+                                     self.chunk_buckets, self.build_prefill,
+                                     prompt, slot)
 
     def step_picked(self, fetched, s_b, pos, active) -> np.ndarray:
         """The slots' tokens out of the decode program's ``picked``; its
@@ -109,30 +95,17 @@ class MlaMoeDecoder:
         return fetched[:-2]
 
     # ------------------------------------------------------------- programs
-    def _query_block(self, c_b: int, ctx_b: int) -> int:
-        per_query = self.arch.heads * ctx_b * 4
-        blk = max(1, _SCORE_BLOCK_BYTES // per_query)
-        blk = 1 << (blk.bit_length() - 1)
-        while c_b % blk:
-            blk >>= 1
-        return min(blk, c_b)
-
     def build_prefill(self, bucket: Tuple[int, int]):
         import jax
         import jax.numpy as jnp
         from jax import lax
         a = self.arch
         c_b, ctx_b = bucket
-        blk = self._query_block(c_b, ctx_b)
+        blk = query_block(a.heads, c_b, ctx_b, _SCORE_BLOCK_BYTES)
         dt = jnp.dtype(a.dtype)
 
         def blocked(f, *xs):
-            """``f`` over blocks of ``blk`` queries, rows put together."""
-            if blk == c_b:
-                return f(*xs)
-            cut = [x.reshape((c_b // blk, blk) + x.shape[1:]) for x in xs]
-            out = lax.map(lambda b: f(*b), tuple(cut))
-            return out.reshape((c_b,) + out.shape[2:])
+            return over_query_blocks(f, c_b, blk, *xs)
 
         def fn(params, state, tokens, slot, start, true_len):
             # tokens (c_b,) int32; slot, start, true_len scalar int32

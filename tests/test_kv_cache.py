@@ -259,3 +259,41 @@ def test_audit_warn_fits_under_big_budget(monkeypatch):
         monkeypatch.delenv("MXNET_TPU_ANALYZE_HBM_BUDGET")
         cfg.reset("MXNET_TPU_ANALYZE")
         cfg.reset("MXNET_TPU_ANALYZE_HBM_BUDGET")
+
+
+def test_a_plane_a_slot_is_counted_and_does_not_grow_with_max_seq():
+    """ISSUE 33: a recurrent state is a plane whose share of a slot has
+    its own shape (``tail``) whatever ``max_seq``; ``hbm_bytes``, ``audit``
+    and ``max_slots_for`` count it, the ledger pages positions only."""
+    from mxnet_tpu.serve.kv_cache import KVCache, Plane, max_slots_for
+    planes = [Plane("k", 2, 8, "bfloat16"),
+              Plane("kc", 2, 8, "bfloat16", tail=(64 // 4, 8)),
+              Plane("state", 3, 0, "float32", tail=(16, 4),
+                    kind="slot_state")]
+    cache = KVCache(planes, max_slots=5, max_seq=64, page=16, name="kvslot")
+    assert [tuple(a.shape) for a in cache.state()] == [
+        (2, 5, 64, 8), (2, 5, 16, 8), (3, 5, 16, 4)]
+    per_slot = 2 * 64 * 8 * 2 + 2 * 16 * 8 * 2 + 3 * 16 * 4 * 4
+    assert cache.hbm_bytes() == 5 * per_slot
+    assert cache.audit()["reserved_bytes"] == 5 * per_slot
+    assert max_slots_for(5 * per_slot + per_slot - 1, planes, 64) == 5
+    assert planes[2].bytes_per_slot(64) == planes[2].bytes_per_slot(4096)
+    assert planes[2].describe() == "state 3 layers x 16x4 float32 a slot"
+    assert planes[1].describe() == "kc 2 layers x 16x8 bfloat16"
+    # the ledger pages positions: a slot's pages follow its length alone
+    slot = cache.acquire(17)
+    assert cache.ledger.pages_in_use == 2
+    cache.release(slot)
+    assert float(np.abs(np.asarray(cache.plane("state"))).max()) == 0.0
+
+
+def test_a_sharded_cache_refuses_a_plane_it_cannot_place():
+    import jax
+    from jax.sharding import Mesh
+    from mxnet_tpu.base import MXNetError
+    from mxnet_tpu.serve.kv_cache import KVCache, Plane
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    with pytest.raises(MXNetError, match="no sharded layout places"):
+        KVCache([Plane("state", 1, 0, "float32", tail=(8, 4),
+                       kind="slot_state")], max_slots=2, max_seq=16, page=4,
+                name="kvnoplace", mesh=mesh)

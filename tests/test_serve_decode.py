@@ -148,19 +148,32 @@ def test_greedy_generation_composition_invariant(module):
 
 
 def test_int8_kv_matches_f32_within_tolerance(module):
-    """int8 KV documented tolerance: greedy tokens identical on this
-    model, decode softmax within 5e-2 of f32 (int8 round-trip is exact
-    while a page's scale is unchanged; requantization adds bounded
-    noise)."""
-    out = {}
-    for int8 in (False, True):
-        srv = _server(module, int8=int8, name="q%d" % int8)
-        try:
-            out[int8] = srv.submit_generate([3, 11, 7], max_new_tokens=8)\
-                .result(timeout=120)
-        finally:
-            srv.close()
-    assert out[False] == out[True]
+    """int8 KV documented tolerance: the decode softmax within 5e-2 of
+    f32 at every step of the same (teacher-forced) sequence, and the same
+    greedy token wherever f32's two best lie further apart than that
+    (int8 round-trip is exact while a page's scale is unchanged;
+    requantization adds bounded noise). Equal greedy tokens over a free
+    run are NOT the tolerance: where two logits tie to within the noise
+    either may win, and which one did followed the machine's load."""
+    from mxnet_tpu.serve.decode import extract_params
+    params = extract_params(module)
+    prompt, steps, tol = np.array([3, 11, 7]), 8, 5e-2
+    engines = [_dense_engine(params, 2, "q%d" % int8, int8=int8)[0]
+               for int8 in (False, True)]
+    rows = [eng.prefill(prompt, 1, logits=True)[1] for eng in engines]
+    pos, active = np.array([0, len(prompt)], np.int32), \
+        np.array([False, True])
+    for _ in range(steps + 1):
+        f32, i8 = (_softmax(r) for r in rows)
+        assert np.abs(f32 - i8).max() < tol
+        best = np.sort(f32)[-2:]
+        if best[1] - best[0] > 2 * tol:
+            assert int(np.argmax(rows[0])) == int(np.argmax(rows[1]))
+        # both follow f32's greedy token
+        tokens = np.array([0, int(np.argmax(rows[0]))], np.int32)
+        rows = [eng.decode_step(tokens, pos, active, logits=True)[1][1]
+                for eng in engines]
+        pos[1] += 1
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])

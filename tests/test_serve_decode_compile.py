@@ -393,3 +393,106 @@ def test_flash_kernels_compile_at_the_training_cells_widths(one_chip,
         assert not re.search(r"f32\[[\d,]*,8\]", line), line
         # lse and delta: a row a Q block
         assert "f32[64,%d,%d]" % (2048 // block_q, block_q) in line, line
+
+
+# ------------------------------------------------- ISSUE 33: the third family
+
+
+def _sparse_linear_family(one_chip, slots, max_seq):
+    """Two layers of the third family at the published widths, one of each
+    kind: 32 query heads for 2 key/value heads of 128, 32 lightning heads,
+    the whole vocabulary, bfloat16."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.models import sparse_linear as layer
+    from mxnet_tpu.serve.sparse_linear import SparseLinearDecoder
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    arch = layer.Arch({
+        "model_type": "minicpm_sala", "hidden_size": 4096,
+        "num_attention_heads": 32, "num_key_value_heads": 2, "head_dim": 128,
+        "intermediate_size": 16384, "lightning_nh": 32, "lightning_nkv": 32,
+        "lightning_head_dim": 128, "rms_norm_eps": 1e-6, "rope_theta": 10000,
+        "scale_emb": 12, "scale_depth": 1.4, "dim_model_base": 256,
+        "mixer_types": ["minicpm4", "lightning-attn"],
+        "depth_scale_layers": 32, "max_position_embeddings": max_seq,
+        "vocab_size": 73448, "dtype": "bfloat16"})
+    params = {n: sd(*shape) for n, shape in layer.param_shapes(arch).items()}
+    family = SparseLinearDecoder.__new__(SparseLinearDecoder)
+    family.arch = family.cfg = arch
+    family.chunk = 1024
+    state = tuple(sd(*p.shape(slots, max_seq), dtype=jnp.dtype(p.dtype))
+                  for p in family.planes(max_seq, 128, False))
+    return family, params, state, sd
+
+
+def _kv_block_bytes(text):
+    """Bytes of K and V a compiled decode program's kernel calls fetch a
+    grid step: the block shapes of the cache operands, from the Mosaic
+    call's own operand list."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return len(calls), sorted(set(re.findall(r"bf16\[[\d,]+\]", " ".join(
+        calls))))
+
+
+def test_the_third_familys_decode_reads_the_same_kv_at_two_buckets(
+        one_chip, for_the_chip):
+    """24 slots of 36 864 positions: the decode programs of buckets 16 384
+    and 36 864 alias all four planes, hold next to no temporaries, read the
+    selected blocks through the Mosaic kernel, and differ in the bytes they
+    access by the compressed keys' rows alone (the indexer scores the
+    bucket's ``kc`` rows; K and V are 64 blocks a slot whatever the
+    bucket)."""
+    import jax.numpy as jnp
+    slots, max_seq = 24, 36864
+    family, params, state, sd = _sparse_linear_family(one_chip, slots,
+                                                      max_seq)
+    assert [s.shape for s in state] == [
+        (1, slots, max_seq, 256), (1, slots, max_seq, 256),
+        (1, slots, max_seq // 16, 256), (1, slots, 4096, 128)]
+    assert family.kernel_reads()
+    read, texts = {}, {}
+    for s_b in (16384, 36864):
+        compiled = family.build_decode(s_b).lower(
+            params, state, sd(slots, dtype=jnp.int32),
+            sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.bool_)).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == _nbytes(state)
+        assert mem.temp_size_in_bytes < 50e6, mem.temp_size_in_bytes
+        picked, logits, _state_out = compiled.out_info
+        # the tokens, then the sparse layer's 64 block numbers a slot and
+        # key/value head and a mean a head of what it read
+        assert (picked.shape, str(picked.dtype)) == (
+            (slots * (1 + 2 * 64 + 32),), "int32")
+        assert (logits.shape, str(logits.dtype)) == ((slots, 73448),
+                                                     "float32")
+        read[s_b] = compiled.cost_analysis()["bytes accessed"]
+        texts[s_b] = compiled.as_text()
+        assert "tpu_custom_call" in texts[s_b]
+        # the K and V planes reach the kernel whole: no slice of a bucket,
+        # no copy of a plane
+        assert not re.search(r"bf16\[\d+,%d,256\]\S* (copy|slice)\(" % s_b,
+                             texts[s_b])
+    kc_rows = slots * (36864 - 16384) // 16 * 256 * 2
+    assert 0 <= read[36864] - read[16384] <= 1.05 * kc_rows, read
+    assert _kv_block_bytes(texts[16384]) == _kv_block_bytes(texts[36864])
+    # the weights once, the state read and written, a charge for each
+    # in-place append's operand: nowhere near a second pass over a plane
+    assert read[36864] < _nbytes(params) + 3 * _nbytes(state), read
+
+
+def test_the_third_familys_prefill_chunk_fits_beside_the_weights(
+        one_chip, for_the_chip):
+    """A chunk of 1024 over the longest context, the selection as a
+    block-level mask in blocks of queries: all four planes aliased, the
+    temporaries under half a gigabyte."""
+    import jax.numpy as jnp
+    family, params, state, sd = _sparse_linear_family(one_chip, 24, 36864)
+    compiled = family.build_prefill((1024, 36864)).lower(
+        params, state, sd(1024, dtype=jnp.int32), sd(dtype=jnp.int32),
+        sd(dtype=jnp.int32), sd(dtype=jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(state)
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
