@@ -1,11 +1,12 @@
 """mx.io — data iterators (reference: python/mxnet/io.py + src/io/)."""
-from .io import (DataDesc, DataBatch, DataIter, NDArrayIter, ResizeIter,
-                 PrefetchingIter, CSVIter, MNISTIter)
+from .io import (DataDesc, DataBatch, DeferredImages, DataIter, NDArrayIter,
+                 ResizeIter, PrefetchingIter, CSVIter, MNISTIter)
 from .image_record import ImageRecordIter, ImageRecordUInt8Iter
 
-__all__ = ["DataDesc", "DataBatch", "DataIter", "NDArrayIter", "ResizeIter",
-           "PrefetchingIter", "CSVIter", "MNISTIter", "ImageRecordIter",
-           "ImageRecordUInt8Iter", "ImageDetRecordIter"]
+__all__ = ["DataDesc", "DataBatch", "DeferredImages", "DataIter",
+           "NDArrayIter", "ResizeIter", "PrefetchingIter", "CSVIter",
+           "MNISTIter", "ImageRecordIter", "ImageRecordUInt8Iter",
+           "ImageDetRecordIter"]
 
 
 def __getattr__(name):

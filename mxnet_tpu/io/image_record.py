@@ -19,7 +19,7 @@ from .. import lockcheck as _lockcheck
 from .. import ndarray as nd
 from .. import profiler as _profiler
 from ..recordio import MXRecordIO, MXIndexedRecordIO, unpack
-from .io import DataBatch, DataDesc, DataIter
+from .io import DataBatch, DataDesc, DataIter, DeferredImages
 
 __all__ = ["ImageRecordIter", "ImageRecordUInt8Iter", "imdecode", "imread"]
 
@@ -84,6 +84,12 @@ class ImageRecordIter(DataIter):
             mean=np.array([mean_r, mean_g, mean_b], np.float32),
             std=np.array([std_r, std_g, std_b], np.float32),
             scale=scale)
+        # what the native path's uint8 batch is owed, a channel: (x -
+        # mean) * inv, all in float32
+        std = self._params["std"]
+        inv = np.float32(scale) / np.where(std == 0, np.float32(1), std)
+        c = self.data_shape[0]
+        self._owed = (self._params["mean"][:c], inv[:c])
         if mean_img is not None:
             try:
                 self._params["mean_arr"] = nd.load(mean_img)["mean_img"].asnumpy()
@@ -105,9 +111,11 @@ class ImageRecordIter(DataIter):
 
         # Native C++ pipeline (mxnet_tpu/native: RecordIO mmap reader +
         # libjpeg/libpng decode + threaded augment/batch workers) handles
-        # the standard crop/mirror/mean-std path entirely off the Python
-        # thread; custom Augmenter pipelines and mean_img files fall back
-        # to the Python/cv2 path below.
+        # the standard crop/mirror path entirely off the Python thread and
+        # hands over uint8 pixels with the mean/std/scale finish still
+        # owed (DeferredImages); custom Augmenter pipelines, mean_img
+        # files and random scales fall back to the Python/cv2 path below,
+        # which hands over finished float32.
         self._native = None
         if (aug_list is None and self._params.get("mean_arr") is None
                 and max_random_scale == 1.0 and min_random_scale == 1.0
@@ -312,9 +320,17 @@ class ImageRecordIter(DataIter):
                     raise StopIteration
         with _profiler.span("io_batch_place", "io",
                             bytes=imgs.nbytes + labels.nbytes):
-            return DataBatch(data=[nd.array(imgs.astype(self._dtype,
-                                                        copy=False),
-                                            dtype=self._dtype)],
+            data = deferred = None
+            if self._native is not None:
+                # the decoders' uint8 and the finish it is owed: made on
+                # the chip by Module._place_value, or on the host by
+                # whoever reads batch.data first
+                deferred = [DeferredImages(imgs, *self._owed,
+                                           dtype=self._dtype)]
+            else:
+                data = [nd.array(imgs.astype(self._dtype, copy=False),
+                                 dtype=self._dtype)]
+            return DataBatch(data=data, deferred=deferred,
                              label=[nd.array(labels)], pad=pad,
                              provide_data=self.provide_data,
                              provide_label=self.provide_label)
@@ -368,9 +384,6 @@ class _NativePipe:
         cfg.resize = int(p["resize"])
         cfg.rand_crop = int(bool(p["rand_crop"]))
         cfg.rand_mirror = int(bool(p["rand_mirror"]))
-        cfg.mean[:] = [float(x) for x in p["mean"]]
-        cfg.std_[:] = [float(x) for x in p["std"]]
-        cfg.scale = float(p["scale"])
         cfg.seed = seed
         cfg.num_threads = it._n_threads
         cfg.queue_depth = it._prefetch
@@ -394,11 +407,11 @@ class _NativePipe:
     def next(self):
         import numpy as _np
         ct = self._ct
-        data = _np.empty(self._shape, _np.float32)
+        data = _np.empty(self._shape, _np.uint8)
         label = _np.empty(self._label_shape, _np.float32)
         pad = ct.c_int()
         rc = self._lib.mxpipe_next(
-            self.handle, data.ctypes.data_as(ct.POINTER(ct.c_float)),
+            self.handle, data.ctypes.data_as(ct.POINTER(ct.c_uint8)),
             label.ctypes.data_as(ct.POINTER(ct.c_float)), ct.byref(pad))
         if rc == 1:
             raise StopIteration

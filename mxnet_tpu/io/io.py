@@ -10,6 +10,7 @@ thread + bounded queue in :class:`PrefetchingIter`.
 """
 from __future__ import annotations
 
+import functools
 import gzip
 import os
 import queue
@@ -19,6 +20,7 @@ import time
 from collections import namedtuple
 from typing import Dict, List, Optional, Sequence, Union
 
+import jax
 import numpy as np
 
 from .. import lockcheck as _lockcheck
@@ -27,8 +29,9 @@ from ..ndarray import NDArray
 from ..base import MXNetError
 from .. import profiler as _profiler
 
-__all__ = ["DataDesc", "DataBatch", "DataIter", "NDArrayIter", "ResizeIter",
-           "PrefetchingIter", "CSVIter", "MNISTIter"]
+__all__ = ["DataDesc", "DataBatch", "DeferredImages", "DataIter",
+           "NDArrayIter", "ResizeIter", "PrefetchingIter", "CSVIter",
+           "MNISTIter"]
 
 
 class DataDesc(namedtuple("DataDesc", ["name", "shape", "dtype", "layout"])):
@@ -51,16 +54,82 @@ class DataDesc(namedtuple("DataDesc", ["name", "shape", "dtype", "layout"])):
         return [DataDesc(x[0], x[1]) for x in shapes]
 
 
+class DeferredImages(object):
+    """An image batch as the decoders left it, and the finish it is owed.
+
+    ``pixels`` is the cropped, mirrored uint8 NCHW numpy array; the
+    finish is the per-channel affine map ``(x - mean) * inv`` in float32
+    (``inv`` is ``scale / std``), then a cast to ``dtype``. Whoever takes
+    the batch runs it: ``Module._place_value`` on the chip, after the
+    pixels crossed at one byte a value (``finish_placed``), or
+    ``finish()`` on the host for a consumer that reads ``batch.data``.
+    ``mean`` and ``inv`` are None when nothing but the cast is owed
+    (``ImageRecordUInt8Iter``)."""
+
+    __slots__ = ("pixels", "mean", "inv", "dtype")
+
+    def __init__(self, pixels, mean=None, inv=None, dtype=np.float32):
+        self.pixels = pixels
+        self.dtype = np.dtype(dtype)
+        self.mean = self.inv = None
+        if mean is not None and (np.any(mean) or np.any(inv != 1.0)):
+            self.mean = np.asarray(mean, np.float32).reshape(1, -1, 1, 1)
+            self.inv = np.asarray(inv, np.float32).reshape(1, -1, 1, 1)
+
+    @property
+    def shape(self):
+        return self.pixels.shape
+
+    def finish(self):
+        """The finished batch on the host, a numpy array of ``dtype``."""
+        if self.mean is None:
+            return self.pixels.astype(self.dtype, copy=False)
+        # in place: one float32 buffer, not three (a batch of 256 is
+        # 154 MB, and fresh pages are what a host pays most for)
+        x = self.pixels.astype(np.float32)
+        x -= self.mean
+        x *= self.inv
+        return x.astype(self.dtype, copy=False)
+
+    def finish_placed(self, placed, dtype):
+        """The finished batch where ``placed`` (the pixels, already on
+        their device or mesh) lies, cast on to ``dtype``: one small
+        program, compiled once per (shape, dtype, placement)."""
+        if self.mean is None and placed.dtype == self.dtype == dtype:
+            return placed
+        from ..obs import compiles as _compiles
+        with _compiles.scope("io_finish", (placed.shape, str(dtype))):
+            return _finish_placed(placed, self.mean, self.inv, self.dtype,
+                                  np.dtype(dtype))
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _finish_placed(pixels, mean, inv, via, dtype):
+    # mean and inv are None together (nothing owed but the casts): an
+    # empty pytree to jit, told apart while tracing
+    x = pixels
+    if mean is not None:
+        x = (x.astype(np.float32) - mean) * inv
+    return x.astype(via).astype(dtype)
+
+
 class DataBatch(object):
-    """(reference: io.py DataBatch)."""
+    """(reference: io.py DataBatch).
+
+    ``deferred``, when given, holds one :class:`DeferredImages` per data
+    input in place of ``data``: ``batch.data`` then finishes them on the
+    host at its first read, and a consumer that places the batch on a
+    device without reading it never pays for that."""
 
     def __init__(self, data, label=None, pad=None, index=None,
-                 bucket_key=None, provide_data=None, provide_label=None):
+                 bucket_key=None, provide_data=None, provide_label=None,
+                 deferred=None):
         if data is not None and not isinstance(data, (list, tuple)):
             data = [data]
         if label is not None and not isinstance(label, (list, tuple)):
             label = [label]
         self.data = data
+        self.deferred = deferred
         self.label = label
         self.pad = pad
         self.index = index
@@ -68,8 +137,22 @@ class DataBatch(object):
         self.provide_data = provide_data
         self.provide_label = provide_label
 
+    @property
+    def data(self):
+        if self._data is None and self.deferred is not None:
+            self._data = [nd.array(d.finish(), dtype=d.dtype)
+                          for d in self.deferred]
+        return self._data
+
+    @data.setter
+    def data(self, value):
+        # whoever assigns the data owns it from here: nothing is owed
+        self._data = value
+        self.deferred = None
+
     def __str__(self):
-        data_shapes = [d.shape for d in self.data] if self.data else None
+        data = self._data if self._data is not None else self.deferred
+        data_shapes = [d.shape for d in data] if data else None
         label_shapes = [l.shape for l in self.label] if self.label else None
         return "{}: data shapes: {} label shapes: {}".format(
             self.__class__.__name__, data_shapes, label_shapes)
@@ -424,9 +507,16 @@ class PrefetchingIter(DataIter):
             if kind == "stop":
                 return cur, None
             batches.append(batch)
-        data = sum([b.data for b in batches], [])
+        # batches still owed their finish stay so (reading .data here
+        # would finish them on this host thread)
+        data = deferred = None
+        if all(getattr(b, "deferred", None) for b in batches):
+            deferred = sum([b.deferred for b in batches], [])
+        else:
+            data = sum([b.data for b in batches], [])
         label = sum([(b.label or []) for b in batches], [])
-        return cur, DataBatch(data=data, label=label or None,
+        return cur, DataBatch(data=data, deferred=deferred,
+                              label=label or None,
                               pad=batches[0].pad, index=batches[0].index,
                               provide_data=self.provide_data,
                               provide_label=self.provide_label)

@@ -37,7 +37,7 @@ from .. import profiler as _profiler
 from ..obs import compiles as _obs_compiles
 from ..obs import mfu as _obs_mfu
 from .base_module import BaseModule, _check_input_names
-from ..io.io import DataDesc
+from ..io.io import DataDesc, DeferredImages
 
 __all__ = ["Module"]
 
@@ -1260,38 +1260,53 @@ class Module(BaseModule):
         return None
 
     # ------------------------------------------------------------- compute
+    def _input_sharding(self, ndim):
+        """Where a bound input of rank ``ndim`` lives: the one device, or
+        on a mesh batch-sharded / replicated as the layout says."""
+        if self._mesh is None:
+            return self._context[0].jax_device
+        from ..parallel.mesh import batch_sharding, replicated_sharding
+        if ndim == 0:
+            # rank-0 inputs have no batch dim to shard (bind-time
+            # validation skips them the same way) — replicate
+            return replicated_sharding(self._mesh)
+        if self._batch_sharding is not None:
+            # unified layout: the batch shards over BOTH data-parallel
+            # axes (data, fsdp) — validated at bind
+            return self._batch_sharding
+        if "data" in self._mesh.axis_names:
+            return batch_sharding(self._mesh)
+        # pure tensor-parallel mesh: the batch is replicated
+        return replicated_sharding(self._mesh)
+
     def _place_value(self, name, arr):
         """One input's device placement: dtype cast + shard/replicate per
         the bound mesh (or plain device_put). Shared by the critical-path
         ``_load_batch`` and the background device-prefetch stage, so a
-        prefetched batch lands exactly where a synchronous one would."""
-        val = arr.data if isinstance(arr, nd.NDArray) else \
-            jnp.asarray(np.asarray(arr))
+        prefetched batch lands exactly where a synchronous one would.
+        An image batch still owed its finish (``io.DeferredImages``)
+        crosses as the decoder's uint8 numpy, once, and is finished where
+        it lands."""
         tgt = self._exec.arg_dict.get(name)
         if tgt is None:
             return None
+        if isinstance(arr, DeferredImages):
+            placed = jax.device_put(arr.pixels,
+                                    self._input_sharding(arr.pixels.ndim))
+            _profiler.incr_counter("io_batches_finished_on_device")
+            return arr.finish_placed(placed, tgt.data.dtype)
+        val = arr.data if isinstance(arr, nd.NDArray) else \
+            jnp.asarray(np.asarray(arr))
         if val.dtype != tgt.data.dtype:
             val = val.astype(tgt.data.dtype)
-        if self._mesh is not None:
-            if val.ndim == 0:
-                # rank-0 inputs have no batch dim to shard (bind-time
-                # validation skips them the same way) — replicate
-                from ..parallel.mesh import replicate
-                val = replicate(self._mesh, val)
-            elif self._batch_sharding is not None:
-                # unified layout: the batch shards over BOTH data-parallel
-                # axes (data, fsdp) — validated at bind
-                val = jax.device_put(val, self._batch_sharding)
-            elif "data" in self._mesh.axis_names:
-                from ..parallel.mesh import shard_batch
-                val = shard_batch(self._mesh, val)
-            else:
-                # pure tensor-parallel mesh: the batch is replicated
-                from ..parallel.mesh import replicate
-                val = replicate(self._mesh, val)
-        else:
-            val = jax.device_put(val, self._context[0].jax_device)
-        return val
+        return jax.device_put(val, self._input_sharding(val.ndim))
+
+    @staticmethod
+    def _batch_data(data_batch):
+        """The batch's data inputs as they are to be placed: the deferred
+        form where the batch carries one (reading ``.data`` would finish
+        it on the host first)."""
+        return getattr(data_batch, "deferred", None) or data_batch.data
 
     def _load_batch(self, data_batch):
         """Place batch data/labels into the bound args; with a mesh, inputs
@@ -1300,7 +1315,7 @@ class Module(BaseModule):
         the device-prefetch stage already placed (``_mx_placed``) are
         swapped in without touching the device."""
         ex = self._exec
-        data = data_batch.data
+        data = self._batch_data(data_batch)
         labels = data_batch.label or []
         placed = getattr(data_batch, "_mx_placed", None)
 
@@ -1349,7 +1364,8 @@ class Module(BaseModule):
 
         def place_batch(data_batch):
             placed = {}
-            for name, arr in zip(self._data_names, data_batch.data or []):
+            for name, arr in zip(self._data_names,
+                                 self._batch_data(data_batch) or []):
                 val = self._place_value(name, arr)
                 if val is not None:
                     placed[name] = val

@@ -21,6 +21,10 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libmxnative.so")
 _SOURCES = ["recordio.cc", "image.cc", "pipeline.cc"]
 _DEPS = _SOURCES + ["mxnative.h"]  # staleness check includes the header
+# What this binding was written against: MXNATIVE_ABI of mxnative.h. A
+# library that answers another number (or none) is rebuilt, never bound:
+# the struct and the signatures below would not be its own.
+_ABI = 2
 
 _lock = threading.Lock()
 _lib = None
@@ -37,9 +41,6 @@ class MXPipeConfig(ctypes.Structure):
         ("resize", ctypes.c_int),
         ("rand_crop", ctypes.c_int),
         ("rand_mirror", ctypes.c_int),
-        ("mean", ctypes.c_float * 3),
-        ("std_", ctypes.c_float * 3),
-        ("scale", ctypes.c_float),
         ("seed", ctypes.c_uint64),
         ("num_threads", ctypes.c_int),
         ("queue_depth", ctypes.c_int),
@@ -47,24 +48,26 @@ class MXPipeConfig(ctypes.Structure):
     ]
 
 
-def _build() -> bool:
-    """Compile libmxnative.so if missing or older than sources/header.
+def _build(dirpath: str = _DIR, force: bool = False) -> bool:
+    """Compile libmxnative.so if missing or older than sources/header
+    (or whatever its age, with ``force``).
 
     Compiles to a process-unique temp path and renames into place so
     concurrent importers (multi-process data parallel, pytest workers)
     never observe a half-written .so.
     """
-    deps = [os.path.join(_DIR, s) for s in _DEPS]
-    if os.path.exists(_SO) and all(
-            os.path.getmtime(_SO) >= os.path.getmtime(s) for s in deps):
+    so = os.path.join(dirpath, "libmxnative.so")
+    deps = [os.path.join(dirpath, s) for s in _DEPS]
+    if not force and os.path.exists(so) and all(
+            os.path.getmtime(so) >= os.path.getmtime(s) for s in deps):
         return True
-    tmp = "%s.%d.tmp" % (_SO, os.getpid())
-    srcs = [os.path.join(_DIR, s) for s in _SOURCES]
+    tmp = "%s.%d.tmp" % (so, os.getpid())
+    srcs = [os.path.join(dirpath, s) for s in _SOURCES]
     cmd = ["g++", "-std=c++17", "-O2", "-fPIC", "-shared", "-pthread",
            "-o", tmp] + srcs + ["-ljpeg", "-lpng"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=180)
-        os.replace(tmp, _SO)   # atomic on POSIX
+        os.replace(tmp, so)   # atomic on POSIX
         return True
     except Exception:
         try:
@@ -72,6 +75,40 @@ def _build() -> bool:
         except OSError:
             pass
         return False
+
+
+def _abi_of(handle: ctypes.CDLL):
+    """The library's own ABI number; None from one built before it had
+    any."""
+    try:
+        fn = handle.mxnative_abi
+    except AttributeError:
+        return None
+    fn.restype = ctypes.c_int
+    fn.argtypes = []
+    return fn()
+
+
+def _load(dirpath: str = _DIR):
+    """Build if stale, open, and bind — or None. A library that is new
+    enough by its mtime and yet speaks another ABI (a checkout moved
+    under a built tree, a copied working tree) is unloaded, rebuilt from
+    the sources beside it and opened again."""
+    so = os.path.join(dirpath, "libmxnative.so")
+    for force in (False, True):
+        if not _build(dirpath, force=force):
+            return None
+        try:
+            handle = ctypes.CDLL(so)
+        except OSError:
+            return None
+        if _abi_of(handle) == _ABI:
+            return _bind(handle)
+        # dlopen finds an open library by its path: close this one, or
+        # the rebuilt file would never be mapped
+        import _ctypes
+        _ctypes.dlclose(handle._handle)
+    return None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -114,8 +151,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
                                        ctypes.POINTER(ctypes.c_int64),
                                        ctypes.c_int64]
     lib.mxpipe_next.restype = ctypes.c_int
-    lib.mxpipe_next.argtypes = [ctypes.c_void_p,
-                                ctypes.POINTER(ctypes.c_float),
+    lib.mxpipe_next.argtypes = [ctypes.c_void_p, u8p,
                                 ctypes.POINTER(ctypes.c_float),
                                 ctypes.POINTER(ctypes.c_int)]
     lib.mxpipe_error.restype = ctypes.c_char_p
@@ -138,10 +174,7 @@ def lib():
             enabled = bool(int(_config.get("MXNET_USE_NATIVE_IO")))
         except Exception:
             pass
-        if enabled and _build():
-            try:
-                _lib = _bind(ctypes.CDLL(_SO))
-            except OSError:
-                _lib = None
+        if enabled:
+            _lib = _load()
         _tried = True
         return _lib

@@ -5,7 +5,7 @@
 // decode/augment/batch thread pool).  The compute path is JAX/XLA; this
 // library owns the host-side IO hot loop: record container codec, JPEG/PNG
 // decode, augmentation, and a threaded prefetch pipeline that assembles
-// ready float32 NCHW batches off the Python thread (no GIL).
+// ready uint8 NCHW batches off the Python thread (no GIL).
 //
 // Exposed over a flat C ABI (ctypes binding in mxnet_tpu/native/__init__.py)
 // the way the reference exposes its core over include/mxnet/c_api.h.
@@ -17,6 +17,15 @@
 #ifdef __cplusplus
 extern "C" {
 #endif
+
+// Bumped whenever a struct or a signature below changes.  The binding
+// (native/__init__.py) rebuilds a libmxnative.so that answers another
+// number, or has no such symbol: an mtime check alone would load a stale
+// library against the new MXPipeConfig.
+#ifndef MXNATIVE_ABI
+#define MXNATIVE_ABI 2
+#endif
+int mxnative_abi(void);
 
 // ---------------------------------------------------------------- recordio
 // dmlc recordio framing: uint32 magic 0xced7230a, uint32 lrecord
@@ -54,9 +63,10 @@ void mximg_resize(const uint8_t* src, int sh, int sw, int c,
                   uint8_t* dst, int dh, int dw);
 
 // ---------------------------------------------------------------- pipeline
-// Fused decode → augment → normalize → batch pipeline with worker threads
-// and a bounded ready-batch queue (reference: iter_image_recordio_2.cc
-// thread pool + iter_prefetcher.h double buffering).
+// Fused decode → augment → batch pipeline with worker threads and a
+// bounded ready-batch queue (reference: iter_image_recordio_2.cc thread
+// pool + iter_prefetcher.h double buffering).  Batches are the decoder's
+// uint8 pixels, cropped and mirrored; normalisation is the taker's.
 typedef struct {
   int batch_size;
   int target_h, target_w, target_c;  // output CHW shape
@@ -64,9 +74,6 @@ typedef struct {
   int resize;          // short-side resize before crop; <=0 disables
   int rand_crop;       // else center crop
   int rand_mirror;
-  float mean[3];
-  float std_[3];
-  float scale;
   uint64_t seed;
   int num_threads;
   int queue_depth;     // max ready batches buffered
@@ -78,9 +85,9 @@ void* mxpipe_create(void* rec, const MXPipeConfig* cfg);
 // Begin an epoch visiting records in `order` (indices into the rec handle).
 void mxpipe_start_epoch(void* handle, const int64_t* order, int64_t n);
 // Copy the next ready batch into caller buffers.
-//   data: batch*c*h*w float32   label: batch*label_width float32
+//   data: batch*c*h*w uint8   label: batch*label_width float32
 // Returns 0 ok, 1 epoch done, -1 error (message via mxpipe_error).
-int mxpipe_next(void* handle, float* data, float* label, int* pad);
+int mxpipe_next(void* handle, uint8_t* data, float* label, int* pad);
 const char* mxpipe_error(void* handle);
 void mxpipe_close(void* handle);
 

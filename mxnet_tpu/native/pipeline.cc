@@ -1,12 +1,16 @@
-// Fused decode → augment → normalize → batch pipeline.
+// Fused decode → augment → batch pipeline.
 //
 // TPU-native equivalent of the reference's ImageRecordIter v2 internals
 // (src/io/iter_image_recordio_2.cc:513-566 thread pool +
 // iter_batchloader.h batching + iter_prefetcher.h double buffering):
 // worker threads each claim a whole batch of records, decode and augment
-// them into a float32 NCHW buffer, and a bounded reorder queue hands
+// them into a uint8 NCHW buffer, and a bounded reorder queue hands
 // batches to the consumer in epoch order.  Runs entirely off the Python
 // thread — ctypes releases the GIL for the duration of mxpipe_next.
+//
+// A batch leaves as the pixels the decoder made: the affine finish
+// (mean, std, scale, dtype) is owed by whoever takes the batch — the
+// chip, one byte a value across the wire (io.py DeferredImages).
 //
 // Determinism: every record draws from an RNG seeded by
 // (seed, epoch, position-in-epoch), so augmentation is reproducible
@@ -33,7 +37,7 @@ struct IRHeader {
 };
 
 struct Batch {
-  std::vector<float> data;
+  std::vector<uint8_t> data;
   std::vector<float> label;
   int pad = 0;
 };
@@ -50,6 +54,9 @@ struct Pipe {
   std::mutex mu;
   std::condition_variable cv_ready, cv_space;
   std::map<int64_t, Batch> ready;  // batch seq -> ready batch
+  // pixel buffers handed back by mxpipe_next: a batch is tens of MB, which
+  // malloc maps fresh and the kernel faults in page by page every time
+  std::vector<std::vector<uint8_t>> spare;
   int64_t next_deliver = 0;
   bool stop = false;
   uint64_t generation = 0;  // bumped per epoch so stale workers park
@@ -97,8 +104,8 @@ const uint8_t* ParseHeader(const uint8_t* buf, int64_t len, int label_width,
   return p;
 }
 
-// Decode + augment one record into dst (CHW float32).
-bool ProcessOne(Pipe* pp, int64_t rec_idx, uint64_t rng_seed, float* dst,
+// Decode + augment one record into dst (CHW uint8).
+bool ProcessOne(Pipe* pp, int64_t rec_idx, uint64_t rng_seed, uint8_t* dst,
                 float* label_out) {
   const MXPipeConfig& c = pp->cfg;
   const uint8_t* buf;
@@ -151,20 +158,17 @@ bool ProcessOne(Pipe* pp, int64_t rec_idx, uint64_t rng_seed, float* dst,
   }
   bool mirror = c.rand_mirror && (rng() & 1);
 
-  // normalize + HWC->CHW in one pass
+  // crop + mirror + HWC->CHW in one pass
   const int TH = c.target_h, TW = c.target_w, TC = c.target_c;
   for (int k = 0; k < TC; ++k) {
-    float mean = c.mean[k < 3 ? k : 2], stdv = c.std_[k < 3 ? k : 2];
-    float inv = c.scale / (stdv == 0.f ? 1.f : stdv);
-    float* out_plane = dst + (size_t)k * TH * TW;
+    uint8_t* out_plane = dst + (size_t)k * TH * TW;
     for (int y = 0; y < TH; ++y) {
       const uint8_t* row = cur + ((size_t)(y0 + y) * w + x0) * ch + k;
-      float* orow = out_plane + (size_t)y * TW;
+      uint8_t* orow = out_plane + (size_t)y * TW;
       if (mirror) {
-        for (int x = 0; x < TW; ++x)
-          orow[x] = (row[(size_t)(TW - 1 - x) * ch] - mean) * inv;
+        for (int x = 0; x < TW; ++x) orow[x] = row[(size_t)(TW - 1 - x) * ch];
       } else {
-        for (int x = 0; x < TW; ++x) orow[x] = (row[(size_t)x * ch] - mean) * inv;
+        for (int x = 0; x < TW; ++x) orow[x] = row[(size_t)x * ch];
       }
     }
   }
@@ -183,6 +187,13 @@ void WorkerLoop(Pipe* pp, uint64_t gen) {
     int64_t b = pp->next_claim.fetch_add(1);
     if (b >= pp->n_batches) return;
     Batch out;
+    {
+      std::lock_guard<std::mutex> l(pp->mu);
+      if (!pp->spare.empty()) {
+        out.data = std::move(pp->spare.back());
+        pp->spare.pop_back();
+      }
+    }
     out.data.resize(img_sz * c.batch_size);
     out.label.resize((size_t)c.label_width * c.batch_size);
     int64_t start = b * c.batch_size;
@@ -198,7 +209,7 @@ void WorkerLoop(Pipe* pp, uint64_t gen) {
     }
     for (int64_t i = n; i < c.batch_size; ++i) {  // pad: repeat last sample
       std::memcpy(out.data.data() + img_sz * i,
-                  out.data.data() + img_sz * (n - 1), img_sz * sizeof(float));
+                  out.data.data() + img_sz * (n - 1), img_sz);
       std::memcpy(out.label.data() + (size_t)c.label_width * i,
                   out.label.data() + (size_t)c.label_width * (n - 1),
                   (size_t)c.label_width * sizeof(float));
@@ -227,6 +238,8 @@ void WorkerLoop(Pipe* pp, uint64_t gen) {
 }  // namespace
 
 extern "C" {
+
+int mxnative_abi(void) { return MXNATIVE_ABI; }
 
 void* mxpipe_create(void* rec, const MXPipeConfig* cfg) {
   if (!rec || !cfg || cfg->batch_size <= 0) return nullptr;
@@ -267,7 +280,7 @@ void mxpipe_start_epoch(void* handle, const int64_t* order, int64_t n) {
     pp->workers.emplace_back(WorkerLoop, pp, gen);
 }
 
-int mxpipe_next(void* handle, float* data, float* label, int* pad) {
+int mxpipe_next(void* handle, uint8_t* data, float* label, int* pad) {
   Pipe* pp = static_cast<Pipe*>(handle);
   std::unique_lock<std::mutex> l(pp->mu);
   if (pp->next_deliver >= pp->n_batches) return 1;
@@ -282,9 +295,12 @@ int mxpipe_next(void* handle, float* data, float* label, int* pad) {
   pp->next_deliver++;
   l.unlock();
   pp->cv_space.notify_all();
-  std::memcpy(data, b.data.data(), b.data.size() * sizeof(float));
+  std::memcpy(data, b.data.data(), b.data.size());
   std::memcpy(label, b.label.data(), b.label.size() * sizeof(float));
   *pad = b.pad;
+  l.lock();
+  if ((int)pp->spare.size() < pp->cfg.queue_depth + pp->cfg.num_threads)
+    pp->spare.push_back(std::move(b.data));
   return 0;
 }
 

@@ -286,8 +286,246 @@ def test_image_record_iter_spans_tell_wait_from_placement(tmp_path, native):
                                       "io_batch_wait"]
     wait, place, _last = mine
     assert wait.t_end <= place.t_start and wait.attrs == {}
-    assert place.attrs["bytes"] >= batch.data[0].size * 4
+    # the native path hands over the decoders' uint8, a byte a value,
+    # the Python path finished float32
+    size = batch.data[0].size
+    if native:
+        assert size <= place.attrs["bytes"] < 2 * size
+    else:
+        assert place.attrs["bytes"] >= 4 * size
     assert place.category == "io"
+
+
+# -------------------------------------------- the batch and its owed finish
+
+def _png_rec(tmp_path, n=10, side=12):
+    pytest.importorskip("cv2")
+    path = str(tmp_path / "img.rec")
+    rec = mx.recordio.MXRecordIO(path, "w")
+    rng = np.random.RandomState(0)
+    for i in range(n):
+        img = (rng.rand(side, side, 3) * 255).astype(np.uint8)
+        rec.write(mx.recordio.pack_img(
+            mx.recordio.IRHeader(0, float(i % 3), i, 0), img, img_fmt=".png"))
+    rec.close()
+    return path
+
+
+_NORM = dict(mean_r=123.68, mean_g=116.28, mean_b=103.53, std_r=58.395,
+             std_g=57.12, std_b=57.375)
+
+
+def _image_iter(path, native, cls=None, **kw):
+    if not native:
+        kw["max_random_scale"] = 1.0000001     # the Python path's trigger
+    it = (cls or mx.io.ImageRecordIter)(
+        path_imgrec=path, data_shape=(3, 8, 8), batch_size=4, **kw)
+    if native and it._native is None:
+        pytest.skip("the native pipeline did not build here")
+    assert (it._native is not None) == native
+    return it
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float16"])
+@pytest.mark.parametrize("native", [True, False])
+def test_image_record_iter_batch_contract(tmp_path, native, dtype):
+    """provide_data, shapes, dtypes, labels and pad are the same on both
+    paths; only the native one defers its finish, and reading the data
+    on the host does not spend the deferred form."""
+    it = _image_iter(_png_rec(tmp_path), native, dtype=dtype, **_NORM)
+    desc, = it.provide_data
+    assert (desc.name, desc.shape, desc.dtype) == \
+        ("data", (4, 3, 8, 8), np.dtype(dtype))
+    batches = list(it)
+    assert [b.pad for b in batches] == [0, 0, 2]
+    for b in batches:
+        assert (b.deferred is not None) == native
+        assert b.provide_data == it.provide_data
+        data = b.data[0]
+        assert isinstance(data, mx.nd.NDArray)
+        assert data.shape == (4, 3, 8, 8) and data.dtype == np.dtype(dtype)
+        assert b.data[0] is data               # finished once
+        assert b.label[0].shape == (4,)
+        assert (b.deferred is not None) == native
+        assert "(4, 3, 8, 8)" in str(b)
+    assert_almost_equal(batches[0].label[0], np.array([0.0, 1.0, 2.0, 0.0]))
+    if native:
+        b = batches[0]
+        b.data = [mx.nd.zeros((4, 3, 8, 8))]   # whoever assigns it owns it
+        assert b.deferred is None and b.data[0].asnumpy().max() == 0
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float16", "uint8"])
+def test_deferred_finish_on_device_equals_host(tmp_path, dtype):
+    """The same finish twice: numpy's on the host (``batch.data``) and
+    the jitted one where the pixels were placed."""
+    import jax
+    # (a negative value has no uint8: that case halves the pixels only)
+    kw = dict(scale=0.5) if dtype == "uint8" else dict(scale=1 / 3., **_NORM)
+    it = _image_iter(_png_rec(tmp_path), True, dtype=dtype, rand_crop=True,
+                     rand_mirror=True, **kw)
+    for batch in it:
+        owed, = batch.deferred
+        assert owed.pixels.dtype == np.uint8 and owed.mean.shape == (1, 3, 1, 1)
+        placed = jax.device_put(owed.pixels, jax.devices("cpu")[1])
+        for to in (dtype, "float32"):
+            got = owed.finish_placed(placed, np.dtype(to))
+            assert got.dtype == np.dtype(to)
+            assert got.devices() == placed.devices()
+            want = batch.data[0].asnumpy().astype(to)
+            if dtype == "uint8":
+                np.testing.assert_array_equal(np.asarray(got), want)
+            else:
+                np.testing.assert_allclose(
+                    np.asarray(got), want,
+                    rtol=1e-3 if dtype == "float16" else 1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("native", [True, False])
+def test_image_record_uint8_iter(tmp_path, native):
+    """ImageRecordUInt8Iter is the deferred form with nothing owed but
+    the pixels themselves."""
+    it = _image_iter(_png_rec(tmp_path), native,
+                     cls=mx.io.ImageRecordUInt8Iter)
+    assert it.provide_data[0].dtype == np.uint8
+    batch = next(it)
+    data = batch.data[0].asnumpy()
+    assert data.dtype == np.uint8 and data.shape == (4, 3, 8, 8)
+    if native:
+        owed, = batch.deferred
+        assert owed.mean is None and owed.inv is None
+        np.testing.assert_array_equal(data, owed.pixels)
+        import jax
+        placed = jax.device_put(owed.pixels)
+        assert owed.finish_placed(placed, np.dtype("uint8")) is placed
+        np.testing.assert_array_equal(
+            np.asarray(owed.finish_placed(placed, np.dtype("float32"))),
+            owed.pixels.astype(np.float32))
+
+
+def _conv_symbol():
+    data = mx.sym.Variable("data")
+    net = mx.sym.Convolution(data, kernel=(3, 3), num_filter=4, name="conv")
+    net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.FullyConnected(mx.sym.Flatten(net), num_hidden=3, name="fc")
+    return mx.sym.SoftmaxOutput(net, name="softmax")
+
+
+class _Finished(mx.io.DataIter):
+    """The same batches with nothing owed: finished float32, as the
+    iterator handed them over before it deferred."""
+
+    def __init__(self, inner):
+        super().__init__(inner.batch_size)
+        self._inner = inner
+        self.provide_data = inner.provide_data
+        self.provide_label = inner.provide_label
+
+    def reset(self):
+        self._inner.reset()
+
+    def next(self):
+        b = self._inner.next()
+        return mx.io.DataBatch(data=[mx.nd.array(b.data[0].asnumpy())],
+                               label=b.label, pad=b.pad,
+                               provide_data=b.provide_data,
+                               provide_label=b.provide_label)
+
+
+def _fit_two_steps(it, prefetch):
+    from mxnet_tpu import config as cfg, profiler
+    cfg.set("MXNET_TPU_DEVICE_PREFETCH", prefetch)
+    try:
+        rng = np.random.RandomState(11)
+        start = {"conv_weight": (4, 3, 3, 3), "conv_bias": (4,),
+                 "fc_weight": (3, 144), "fc_bias": (3,)}
+        start = {k: mx.nd.array(rng.normal(0, 0.1, v))
+                 for k, v in start.items()}
+        mod = mx.mod.Module(_conv_symbol(), context=mx.cpu(0))
+        with profiler.counter_delta() as d:
+            mod.fit(it, num_epoch=1, optimizer="sgd", arg_params=start,
+                    optimizer_params={"learning_rate": 0.05})
+            counts = {k: d.get(k) for k in ("io_batches_finished_on_device",
+                                            "loop_prefetch_placed")}
+        args, _aux = mod.get_params()
+        return {k: v.asnumpy() for k, v in args.items()}, counts
+    finally:
+        cfg.reset("MXNET_TPU_DEVICE_PREFETCH")
+
+
+@pytest.mark.parametrize("prefetch", [2, 0, "the_users_own"])
+def test_fit_from_deferred_batches_equals_fit_from_float32(tmp_path, prefetch):
+    """Two ``Module.fit`` steps: the batch finished on the device it was
+    placed on trains as the finished float32 batch does, through the
+    prefetch stage, through ``_load_batch`` alone, and through a
+    ``PrefetchingIter`` the user wrapped himself (which hands the
+    deferred form on); every batch of the deferred form counts, none of
+    the other."""
+    path = _png_rec(tmp_path, n=8)
+    kw = dict(rand_crop=True, rand_mirror=True, seed=4, scale=0.5, **_NORM)
+    wrap = (lambda it: it) if prefetch != "the_users_own" \
+        else mx.io.PrefetchingIter
+    depth = 0 if prefetch == 0 else 2
+    owed_it = wrap(_image_iter(path, True, **kw))
+    done_it = wrap(_Finished(_image_iter(path, True, **kw)))
+    try:
+        owed, c_owed = _fit_two_steps(owed_it, depth)
+        done, c_done = _fit_two_steps(done_it, depth)
+    finally:
+        for it in (owed_it, done_it):
+            getattr(it, "close", lambda: None)()
+    assert c_owed["io_batches_finished_on_device"] == 2
+    assert c_done["io_batches_finished_on_device"] == 0
+    assert c_owed["loop_prefetch_placed"] == c_done["loop_prefetch_placed"] \
+        == (2 if prefetch == 2 else 0)
+    assert set(owed) == set(done)
+    for k in owed:
+        np.testing.assert_allclose(owed[k], done[k], rtol=1e-5, atol=1e-6,
+                                   err_msg=k)
+
+
+def test_placing_deferred_batches_compiles_once(tmp_path):
+    """The finish is one program a (shape, dtype, device): the first batch
+    compiles it, whether or not somebody read ``batch.data`` on the host
+    first, and no later batch compiles anything."""
+    from mxnet_tpu import profiler
+    it = _image_iter(_png_rec(tmp_path, n=16), True, rand_crop=True, **_NORM)
+    mod = mx.mod.Module(_conv_symbol(), context=mx.cpu(0))
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params()
+    place = mod._device_placer()
+    first = next(it)
+    host = first.data[0].asnumpy()             # read on the host first
+    with profiler.counter_delta() as d:
+        placed = place(first)._mx_placed["data"]
+        assert d.get("io_batches_finished_on_device") == 1
+    np.testing.assert_allclose(np.asarray(placed), host, rtol=1e-6, atol=1e-6)
+    mod.forward(first, is_train=False)         # the model's own program
+    with profiler.counter_delta() as d:
+        for batch in it:
+            mod.forward(place(batch), is_train=False)
+        assert d.get("io_batches_finished_on_device") == 3
+        assert d.get("obs_compile_count") == 0
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_deferred_batch_lands_where_a_float32_batch_would(tmp_path, chips):
+    """One device or a data-parallel mesh: the pixels take the sharding
+    branch the finished batch takes, and the finish leaves them there."""
+    it = _image_iter(_png_rec(tmp_path), True, rand_mirror=True, **_NORM)
+    ctx = [mx.cpu(i) for i in range(chips)]
+    mod = mx.mod.Module(_conv_symbol(), context=ctx if chips > 1 else ctx[0])
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params()
+    batch = next(it)
+    owed = mod._place_value("data", batch.deferred[0])
+    done = mod._place_value("data", batch.data[0])
+    assert owed.dtype == done.dtype == np.float32
+    assert owed.sharding.is_equivalent_to(done.sharding, 4)
+    assert len(owed.sharding.device_set) == chips
+    np.testing.assert_allclose(np.asarray(owed), np.asarray(done),
+                               rtol=1e-6, atol=1e-6)
+    assert mod._place_value("nobody", batch.deferred[0]) is None
 
 
 # ----------------------------------------------- recordio index validation
