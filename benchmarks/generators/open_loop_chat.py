@@ -7,10 +7,14 @@ burstiness of a Poisson process stays and the offered load does not vary
 with the seed. The N prompt lengths and the N answer lengths are the N
 stratified quantiles of their clipped log-normal distributions, the same
 two multisets for every seed; the seed sets how they pair, their order
-and the token ids. A lead-in is sent at once before the window, its
-answers as long as what is left of requests caught in flight, and a
-lead-out keeps arriving after it until the window's requests finish; none
-of those is counted.
+and the token ids. A mix that gives ``schedule_seed`` draws the gaps, the
+pairing and the order from that number instead: every run then offers one
+schedule of arrivals and sizes, and its seed sets the token ids alone.
+Where the tail follows how many requests are in flight and how long their
+contexts are, another order is other work. A lead-in is sent at once
+before the window, its answers as long as what is left of requests caught
+in flight, and a lead-out keeps arriving after it until the window's
+requests finish; none of those is counted.
 """
 import math
 from statistics import NormalDist
@@ -63,14 +67,23 @@ def lead_in_lengths(traffic, prompts, answers):
             for j in range(k)]
 
 
+def schedule_rng(traffic, rng):
+    """What draws the gaps, the pairing and the order: the run's own
+    ``rng``, or one from the mix's ``schedule_seed`` (none: the run's)."""
+    if traffic.get("schedule_seed") is None:
+        return rng
+    return np.random.Generator(np.random.PCG64(int(traffic["schedule_seed"])))
+
+
 def plan(traffic, cfg, seed, seconds):
     rng = np.random.Generator(np.random.PCG64(int(seed)))
     vocab = int(cfg["vocab_size"])
     n = arrivals_count(traffic, seconds)
     prompts = quantile_lengths(traffic["prompt"], n)
     answers = quantile_lengths(traffic["answer"], n)
-    due = due_times(rng, n, seconds)
-    p_order, a_order = rng.permutation(n), rng.permutation(n)
+    order = schedule_rng(traffic, rng)
+    due = due_times(order, n, seconds)
+    p_order, a_order = order.permutation(n), order.permutation(n)
 
     def ids(length):
         return rng.integers(0, vocab, int(length)).astype(np.int32)

@@ -19,7 +19,9 @@ of six seeds within 0.9 ms and both outliers among them as outliers (28.0
 and 28.0 where the chip read 28.15 and 30.80); its upper tail is lighter
 than the chip's. Prints the median, the deviation from
 seed to seed, and how often a set of six, less its farthest run, spreads
-by less than --gate.
+by less than --gate. A mix that names its ``schedule_seed`` offers every
+seed one plan, so it reads no deviation; take the key out of a copy of
+the mix to see what the seed would move.
 """
 import argparse
 import os

@@ -157,12 +157,13 @@ def test_a_made_up_cell_mix_and_metric_are_found_as_files(tmp_path):
     bench["per_layer"].append(metric)
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     # no file that the benchmark had was edited, and its own test of
-    # BENCHMARK.json against the files passes on the copy
+    # BENCHMARK.json against the files passes on the copy, each cell's
+    # check of its own entries with it
     after = _tree_digest(b)
     assert {k: v for k, v in after.items() if k in before} == before
-    os.makedirs(tmp_path / "tests" / "benchmark")
-    shutil.copy(os.path.join(ROOT, "tests", "benchmark", "test_spec.py"),
-                tmp_path / "tests" / "benchmark")
+    shutil.copytree(os.path.join(ROOT, "tests", "benchmark"),
+                    tmp_path / "tests" / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(ROOT, "PERF.md"), tmp_path)
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",

@@ -1,13 +1,16 @@
-"""The cell ``sala_serve_longdoc_chat``: its entries stand at the end of the
-benchmark's lists, its traffic is ISSUE 33's, its lead-in holds the long
-sessions first, ``correct`` follows them and holds what their decode steps
-selected and read to the reference's, the float8 control fails where the
-program and the stated precision pass, a part of the mathematics left out
-of the program or a faulty read of the selected blocks comes out not
-correct, and its counters read in a traced rehearsal.
+"""The cell ``sala_serve_longdoc_chat``: its entries in ``BENCHMARK.json``,
+found by name (``check_entries``), and its traffic are those it was added
+with, its lead-in holds the long sessions first, ``correct`` follows them
+and holds what their decode steps selected and read to the reference's,
+the float8 control fails where the program and the stated precision pass,
+a part of the mathematics left out of the program or a faulty read of the
+selected blocks comes out not correct, and its counters read in a traced
+rehearsal.
 
-``test_harness.py::test_every_cell_rehearses`` and ``test_spec.py`` pick
-the cell up from ``BENCHMARK.json`` like any other."""
+``test_spec.py`` checks that cells are appended in order, runs
+``check_entries`` on the benchmark with a cell appended after this one,
+and, with ``test_harness.py::test_every_cell_rehearses``, picks the cell
+up like any other."""
 import json
 import os
 import subprocess
@@ -36,28 +39,36 @@ def _cell(rehearse=False):
     return spec.Cell(REPO, CELL, rehearse=rehearse)
 
 
-def test_the_cell_and_its_configuration_are_the_benchmarks_last_entries():
-    bench = _bench()
-    config, cell = bench["configs"][-1], bench["workloads"][-1]
-    assert cell["name"] == CELL and cell["config"] == config["name"] \
-        == "minicpm-sala-1chip"
+def test_the_cell_and_its_configuration_are_the_issues():
+    check_entries(_bench())
+
+
+def check_entries(bench):
+    """The cell and its configuration, found by name: the traffic and
+    ``reduced`` it was added with, the four per-layer metrics that are the
+    cell's own in the order it brought them, each moving ``tpot_p90_ms``,
+    which it reports, and the generic serving metrics that the other two
+    serving cells report. Where the entries stand is ``test_spec.py``'s to
+    check."""
+    cell, = [w for w in bench["workloads"] if w["name"] == CELL]
+    config, = [c for c in bench["configs"] if c["name"] == cell["config"]]
+    assert config["name"] == "minicpm-sala-1chip"
     assert cell["chips"] == 1
     assert cell["traffic"] == "serve_longdoc_chat_steady"
     assert config["reduced"] == ["num_hidden_layers", "mixer_types",
                                  "max_position_embeddings"]
-    reports = [m for m in bench["end_to_end"] + bench["per_layer"]
-               if CELL in m.get("workloads", [])]
-    assert all(m["workloads"][-1] == CELL for m in reports)
-    own = [m["name"] for m in reports if m["workloads"] == [CELL]]
-    assert own == [m["name"] for m in bench["per_layer"][-len(own):]] == OWN
+    reports = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]
+               if CELL in m.get("workloads", [])}
+    assert [m["name"] for m in bench["per_layer"]
+            if m["workloads"] == [CELL]] == OWN
     assert all(m["moves"] == "tpot_p90_ms" for m in bench["per_layer"]
                if m["name"] in OWN)
-    assert "tpot_p90_ms" in [m["name"] for m in reports]
+    assert "tpot_p90_ms" in reports
     # the generic serving metrics, as the second family's cell has them
     other = {m["name"] for m in bench["per_layer"]
              if "sarvam105_serve_reason" in m["workloads"]
              and "opt13_serve_chat" in m["workloads"]}
-    assert other <= {m["name"] for m in reports}
+    assert other <= reports
 
 
 def test_the_configuration_is_the_catalogs_with_the_issues_cut():

@@ -4,8 +4,11 @@ stated dtype against float8 products), a token altered in the sampler
 comes out not correct, and its readers read a stretch recorded on the
 chip.
 
-``test_harness.py::test_every_cell_rehearses`` and ``test_spec.py`` pick
-the cell up from ``BENCHMARK.json`` like any other."""
+Its entries in ``BENCHMARK.json`` are found by name (``check_entries``);
+``test_spec.py`` checks that cells are appended in order, runs
+``check_entries`` on the benchmark with a cell appended after this one,
+and, with ``test_harness.py::test_every_cell_rehearses``, picks the cell
+up like any other."""
 import json
 import os
 import subprocess
@@ -21,6 +24,9 @@ CELL = "sarvam105_serve_reason"
 CONTROL = os.path.join("benchmarks", "tools", "control.py")
 FAULTY = os.path.join("tests", "benchmark", "faulty_run.py")
 MARKS = ["bench.token.first", "bench.token.next"]
+OWN = ["decode.step_mfu.mla_moe", "decode.step_roofline.mla_moe",
+       "moe.experts_hit_share", "mla.keys_resident_share",
+       "kernel.expert_matmul_roofline"]
 
 
 def _bench():
@@ -33,26 +39,27 @@ def _tiny_cell():
     return spec.Cell(REPO, CELL, rehearse=True)
 
 
-def test_the_cell_and_its_configuration_are_the_benchmarks_last_entries():
-    """New entries stand at the end of their lists; the accepted metrics
-    the cell reports carry its name at the end of their ``workloads``;
-    ``reduced`` is ISSUE 28's list."""
-    bench = _bench()
-    config, cell = bench["configs"][-1], bench["workloads"][-1]
-    assert cell["name"] == CELL and cell["config"] == config["name"]
+def test_the_cell_and_its_configuration_are_the_issues():
+    check_entries(_bench())
+
+
+def check_entries(bench):
+    """The cell and its configuration, found by name: the traffic and
+    ``reduced`` it was added with, the five per-layer metrics that are the
+    cell's own in the order it brought them, and ``tpot_p90_ms`` among what
+    it reports. Where the entries stand is ``test_spec.py``'s to check."""
+    cell, = [w for w in bench["workloads"] if w["name"] == CELL]
+    config, = [c for c in bench["configs"] if c["name"] == cell["config"]]
+    assert config["name"] == "sarvam-105b-l9-ep8"
     assert cell["chips"] == 1 and cell["traffic"] == "serve_reason_steady"
     assert config["reduced"] == [
         "num_hidden_layers", "num_experts", "vocab_held",
         "max_position_embeddings"]
-    reports = [m for m in bench["end_to_end"] + bench["per_layer"]
+    reports = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]
                if CELL in m.get("workloads", [])]
-    assert all(m["workloads"][-1] == CELL for m in reports)
-    own = [m["name"] for m in reports if m["workloads"] == [CELL]]
-    assert own == [m["name"] for m in bench["per_layer"][-len(own):]] == [
-        "decode.step_mfu.mla_moe", "decode.step_roofline.mla_moe",
-        "moe.experts_hit_share", "mla.keys_resident_share",
-        "kernel.expert_matmul_roofline"]
-    assert "tpot_p90_ms" in [m["name"] for m in reports]
+    assert [m["name"] for m in bench["per_layer"]
+            if m["workloads"] == [CELL]] == OWN
+    assert "tpot_p90_ms" in reports
 
 
 @pytest.mark.parametrize("group", ["prompt", "answer", "rate", "server"])
@@ -275,7 +282,9 @@ def test_the_grouped_products_roofline_on_the_recorded_stretch():
 def test_the_schedulers_replay_on_the_cells_own_plans():
     """``tools/replay_scheduler.py``: with a step of constant cost and
     prefill at none every turn's gap is that cost; with the costs read on
-    the chip the tail lies above the mean and follows the seed."""
+    the chip the tail lies above the mean, is the same for every seed on
+    the mix's own schedule, and follows the seed where the seed draws the
+    schedule."""
     from benchmarks.drivers import serve_arch
     from benchmarks.lib import spec
     from benchmarks.tools import replay_scheduler as tool
@@ -283,9 +292,9 @@ def test_the_schedulers_replay_on_the_cells_own_plans():
     generator = spec.load_module("generators", cell.traffic["kind"])
     cfg = serve_arch.held_vocabulary(cell.config)
 
-    def read(seed, **cost):
-        plan = generator.plan(cell.traffic, cfg, seed, 51.0)
-        return tool.replay(cell.traffic, plan, 51.0, cost)
+    def read(seed, traffic=cell.traffic, **cost):
+        plan = generator.plan(traffic, cfg, seed, 51.0)
+        return tool.replay(traffic, plan, 51.0, cost)
     flat = dict(step_ms=20.0, per_sequence_ms=0.0, bucket_ms=0.0,
                 chunk_ms=0.0, chunk_per_1024_ms=0.0)
     assert read(2 ** 31 + 3, **flat) == pytest.approx((20.0, 20.0))
@@ -294,6 +303,9 @@ def test_the_schedulers_replay_on_the_cells_own_plans():
     tail, mean = read(2 ** 31 + 3, **chip)
     assert 20.0 < mean < tail < 35.0
     assert read(2 ** 31 + 3, **chip) == (tail, mean)
-    assert read(2 ** 31 + 4, **chip) != (tail, mean)
+    assert "schedule_seed" in cell.traffic
+    assert read(2 ** 31 + 4, **chip) == (tail, mean)
+    free = {k: v for k, v in cell.traffic.items() if k != "schedule_seed"}
+    assert read(2 ** 31 + 3, free, **chip) != read(2 ** 31 + 4, free, **chip)
     assert tool.spread_less_farthest([25.0, 25.1, 25.2, 25.3, 25.4, 31.0]) \
         < tool.spread_less_farthest([25.0, 25.1, 25.2, 25.3, 30.0, 31.0])

@@ -1,4 +1,9 @@
-"""``BENCHMARK.json`` against the data files it names."""
+"""``BENCHMARK.json`` against the data files it names, and appended to
+only: a cell, its configuration and its metrics go at the end, and each
+cell's own test module checks its entries by name (``check_entries``)."""
+import copy
+import glob
+import importlib
 import json
 import os
 import re
@@ -38,6 +43,10 @@ def test_keys_and_sizes(bench):
 
 
 def test_names_units_and_whys(bench):
+    check_names_units_and_whys(bench)
+
+
+def check_names_units_and_whys(bench):
     names = []
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
         for entry in bench[group]:
@@ -118,6 +127,10 @@ def test_every_cell_resolves(bench):
 
 
 def test_every_per_layer_metric_names_cells_that_report_what_it_moves(bench):
+    check_per_layer_metrics(bench)
+
+
+def check_per_layer_metrics(bench):
     cells = _cells(bench)
     e2e = {m["name"]: m.get("workloads", cells) for m in bench["end_to_end"]}
     layers = {}
@@ -137,6 +150,106 @@ def test_every_per_layer_metric_names_cells_that_report_what_it_moves(bench):
             assert any("mfu" in o["name"] and o["moves"] == m["moves"]
                        and set(m["workloads"]) <= set(o["workloads"])
                        for o in bench["per_layer"]), m["name"]
+
+
+def test_cells_are_appended_in_order(bench):
+    check_appended_in_order(bench)
+
+
+def check_appended_in_order(bench):
+    """What appending leaves true: every metric lists its cells in the
+    order ``workloads`` holds them, and the configurations stand in the
+    order of their first cells. A cell put ahead of another while its name
+    is appended to the lists breaks the first; a configuration put ahead
+    of an older one breaks the second."""
+    cells = _cells(bench)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        listed = m.get("workloads", [])
+        assert set(listed) <= set(cells), m["name"]
+        at = [cells.index(w) for w in listed]
+        assert at == sorted(set(at)), \
+            "%s lists its cells out of cell order" % m["name"]
+    first = {}
+    for i, w in enumerate(bench["workloads"]):
+        first.setdefault(w["config"], i)
+    assert set(first) >= {c["name"] for c in bench["configs"]}
+    at = [first[c["name"]] for c in bench["configs"]]
+    assert at == sorted(at), "configurations out of their first cells' order"
+
+
+PROBE = "appended_probe"
+
+
+def _with_probe(bench, where):
+    """A deep copy of ``bench`` with a serving cell added as a later PR
+    adds one: its name at the end of ``tpot_p90_ms``'s list and of every
+    per-layer metric that all serving cells report, one per-layer metric
+    of its own, and the cell itself at the end (``where`` "end"), at the
+    end with a configuration of its own ("end_with_its_configuration"),
+    before the last cell that reports ``tpot_p90_ms`` ("before_the_last"),
+    or at the end with its configuration put first
+    ("configuration_first")."""
+    out = copy.deepcopy(bench)
+    config = out["configs"][0]["name"]
+    if where in ("end_with_its_configuration", "configuration_first"):
+        config = "appended-probe"
+        entry = {"name": config, "source": "https://example.org/" + config,
+                 "file": "benchmarks/configs/%s.json" % config,
+                 "reduced": [], "why": "a configuration appended by a test"}
+        out["configs"].insert(0 if where == "configuration_first"
+                              else len(out["configs"]), entry)
+    used = {w["traffic"] for w in out["workloads"] if w["config"] == config}
+    traffic = [w["traffic"] for w in out["workloads"]
+               if w["traffic"] not in used][0]
+    tpot, = [m for m in out["end_to_end"] if m["name"] == "tpot_p90_ms"]
+    serving = set(tpot["workloads"])
+    at = len(out["workloads"])
+    if where == "before_the_last":
+        at = _cells(out).index(tpot["workloads"][-1])
+    out["workloads"].insert(at, {
+        "name": PROBE, "config": config, "traffic": traffic, "chips": 1,
+        "why": "a serving cell appended by a test"})
+    generic = [m for m in out["per_layer"] if serving <= set(m["workloads"])]
+    assert generic
+    for m in [tpot] + generic:
+        m["workloads"].append(PROBE)
+    out["per_layer"].append({
+        "name": PROBE + ".share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": generic[0]["layer"],
+        "moves": "tpot_p90_ms", "workloads": [PROBE]})
+    return out
+
+
+def _cells_own_checks():
+    """``check_entries`` of every cell's test module
+    (``test_<cell>_cell.py``), found as files, as a later cell's is."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    names = sorted(os.path.basename(p)[:-3] for p in glob.glob(
+        os.path.join(here, "test_*_cell.py")))
+    checks = [getattr(importlib.import_module(n), "check_entries", None)
+              for n in names]
+    return [c for c in checks if c is not None]
+
+
+@pytest.mark.parametrize("where", ["end", "end_with_its_configuration",
+                                   "before_the_last", "configuration_first"])
+def test_a_cell_appended_at_the_end_leaves_the_checks_green(bench, where):
+    """The next cell, appended, passes every check of the file's shape and
+    every cell's own; put anywhere but at the end, it fails the order
+    check alone."""
+    probed = _with_probe(bench, where)
+    assert PROBE in _cells(probed) and PROBE not in _cells(bench)
+    own = _cells_own_checks()
+    assert own
+    for check in [check_names_units_and_whys, check_per_layer_metrics] + own:
+        check(probed)
+    if where.startswith("end"):
+        check_appended_in_order(probed)
+    else:
+        match = ("out of cell order" if where == "before_the_last"
+                 else "first cells' order")
+        with pytest.raises(AssertionError, match=match):
+            check_appended_in_order(probed)
 
 
 def test_a_missing_file_or_module_is_named():

@@ -42,6 +42,8 @@ def test_same_count_and_same_lengths_for_every_seed(mix):
 
 
 def test_the_seed_sets_order_pairing_and_ids(mix):
+    """Without ``schedule_seed`` the run's seed draws the schedule too."""
+    mix = {k: v for k, v in mix.items() if k != "schedule_seed"}
     a, b = _plans(mix)
     assert [len(r["prompt"]) for r in a["window"]] \
         != [len(r["prompt"]) for r in b["window"]]
@@ -51,6 +53,30 @@ def test_the_seed_sets_order_pairing_and_ids(mix):
     for r, s in zip(a["window"], again["window"]):
         assert r["due"] == s["due"] and r["answer"] == s["answer"]
         assert np.array_equal(r["prompt"], s["prompt"])
+
+
+def test_a_schedule_seed_fixes_the_schedule_and_the_seed_draws_the_ids(mix):
+    """With ``schedule_seed`` every run's seed offers the same arrivals
+    with the same sizes in the same order; the ids are the seed's."""
+    fixed = dict(mix, schedule_seed=2 ** 31 + 77)
+    a, b = [open_loop_chat.plan(fixed, CFG, s, 51.0) for s in SEEDS]
+    for key in ("window", "lead_in"):
+        assert [(r.get("due"), len(r["prompt"]), r["answer"]) for r in a[key]] \
+            == [(r.get("due"), len(r["prompt"]), r["answer"]) for r in b[key]]
+        assert not any(np.array_equal(r["prompt"], s["prompt"])
+                       for r, s in zip(a[key], b[key]))
+    out_a, out_b = a["lead_out"], b["lead_out"]
+    for _ in range(3):
+        r, s = next(out_a), next(out_b)
+        assert (r["due"], len(r["prompt"]), r["answer"]) \
+            == (s["due"], len(s["prompt"]), s["answer"])
+    # another schedule seed is another schedule of the same sizes
+    other = open_loop_chat.plan(dict(mix, schedule_seed=2 ** 31 + 78), CFG,
+                                SEEDS[0], 51.0)
+    assert [r["due"] for r in other["window"]] \
+        != [r["due"] for r in a["window"]]
+    assert sorted(r["answer"] for r in other["window"]) \
+        == sorted(r["answer"] for r in a["window"])
 
 
 def test_lengths_keep_to_the_mix(mix):
