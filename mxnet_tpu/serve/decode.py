@@ -53,7 +53,9 @@ cache plane, routed experts beside a shared one), whose prompts prefill in
 chunks, each appended to the cache and attending over it;
 ``serve/sparse_linear.py`` the third (block-sparse attention over selected
 key blocks beside lightning layers whose recurrent state is a plane a
-slot). ``docs/architecture/serving_families.md`` says what a family owes
+slot); ``serve/window_moe.py`` the fourth (full and windowed grouped-query
+attention, the windows a ring a slot, routed experts behind them).
+``docs/architecture/serving_families.md`` says what a family owes
 the engine.
 
 The executable set is exactly |prompt buckets| + |decode buckets| (the
@@ -576,8 +578,9 @@ def family_for(model, n_heads: Optional[int] = None,
             raise ValueError("GenerativeServer needs n_heads (the dense "
                              "decoder) or arch (a described block)")
         return DenseDecoder(extract_params(model), n_heads)
-    from . import mla_moe, sparse_linear    # they import this module
-    for family in (mla_moe, sparse_linear):
+    # they import this module
+    from . import mla_moe, sparse_linear, window_moe
+    for family in (mla_moe, sparse_linear, window_moe):
         if family.serves(arch):
             return family.make(model, arch)
     raise ValueError("GenerativeServer serves no model_type %r"

@@ -496,3 +496,70 @@ def test_the_third_familys_prefill_chunk_fits_beside_the_weights(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == _nbytes(state)
     assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+
+
+def _window_moe_family(one_chip, slots=36, max_seq=36864):
+    """The fourth family at the cell's own shapes: the configuration's
+    seven layers at published widths, bfloat16, 36 slots of 36864."""
+    import json
+    import os
+    import jax
+    import jax.numpy as jnp
+    from benchmarks.builders import mimo_window_moe as builder
+    from mxnet_tpu.serve.window_moe import WindowMoeDecoder
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "mimo-v2-flash-l7-ep16.json")) as f:
+        cfg = json.load(f)
+    params = {n: sd(*shape)
+              for n, (shape, _m, _s) in builder.leaf_specs(cfg).items()}
+    family = WindowMoeDecoder(params, builder.architecture(cfg))
+    state = tuple(sd(*p.shape(slots, max_seq), dtype=jnp.dtype(p.dtype))
+                  for p in family.planes(max_seq, 128, False))
+    return family, params, state, sd
+
+
+def test_the_fourth_familys_decode_reads_the_full_caches_with_the_kernel(
+        one_chip, for_the_chip):
+    """The decode step at the one bucket the cell's window runs at: the
+    full layers' planes and the rings aliased whole, the grouped-query
+    kernel called, next to no temporaries (no plane is laid out again),
+    and behind the slots' tokens the step's assignments and experts
+    hit."""
+    import jax.numpy as jnp
+    slots, s_b = 36, 36864
+    family, params, state, sd = _window_moe_family(one_chip)
+    assert [s.shape for s in state] == [
+        (2, slots, s_b, 768), (2, slots, s_b, 512), (5, slots, 128, 1536),
+        (5, slots, 128, 1024)]
+    assert family.kernel_reads(s_b)
+    compiled = family.build_decode(s_b).lower(
+        params, state, sd(slots, dtype=jnp.int32),
+        sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(state)
+    assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
+    assert "gqa_decode_attention" in compiled.as_text()
+    picked, logits, _state_out = compiled.out_info
+    assert (picked.shape, str(picked.dtype)) == ((slots + 2,), "int32")
+    assert (logits.shape, str(logits.dtype)) == ((slots, 19072), "float32")
+
+
+@pytest.mark.parametrize("c_b,ctx_b", [(128, 4096), (1024, 36864)])
+def test_the_fourth_familys_prefill_chunk_fits_beside_the_cache(
+        one_chip, for_the_chip, c_b, ctx_b):
+    """A chunk over a context: every plane aliased, and the temporaries
+    under half a gigabyte beside 13.8 GB of weights and cache. A full
+    layer's keys split into heads of 192 lanes had the compiler lay the
+    whole 3.8 GB K plane out again (16.76 GB of 15.75)."""
+    import jax.numpy as jnp
+    family, params, state, sd = _window_moe_family(one_chip)
+    compiled = family.build_prefill((c_b, ctx_b)).lower(
+        params, state, sd(c_b, dtype=jnp.int32), sd(dtype=jnp.int32),
+        sd(dtype=jnp.int32), sd(dtype=jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(state)
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
