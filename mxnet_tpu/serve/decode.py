@@ -32,6 +32,14 @@ and the engine fetches ``picked`` alone. The logits leave the device only
 for a sequence that samples (``temperature > 0``): the scheduler says so
 step by step.
 
+A decode step is dispatched and collected apart
+(:meth:`DecodeEngine.decode_dispatch`, :meth:`DecodeEngine.decode_collect`),
+so that step N+1 can run while the host collects step N. Its input
+tokens need not reach the host first: the engine runs the family's
+decode program behind one ``where`` that takes, for a slot the host
+gives no token (``-1``), the token the previous step picked, still on
+the device.
+
 The cache is ``(layers, slots, max_seq, H*d)`` (``kv_cache.py`` says
 why): no program takes a layer's slab out of it or puts one back. The
 float32 cache on one device is read where it lies by the Pallas kernel
@@ -84,7 +92,7 @@ from ..obs import compiles as _obs_compiles
 
 __all__ = ["DecodeConfig", "DecodeEngine", "DenseDecoder", "extract_params",
            "config_from_params", "sample_token", "PickedRow",
-           "greedy_tokens", "family_for"]
+           "greedy_tokens", "family_for", "DecodeFlight"]
 
 _LN_EPS = 1e-5          # ops/nn.py layer_norm default
 
@@ -587,6 +595,25 @@ def family_for(model, n_heads: Optional[int] = None,
                      % (arch.get("model_type"),))
 
 
+class DecodeFlight:
+    """A decode step dispatched and not yet collected: the device's
+    ``picked`` and, if they were asked for, its logits; the step's bucket,
+    and the positions and active mask it ran with, the engine's own
+    copies. A row the scheduler no longer wants (its sequence ended while
+    the step ran) is cleared from ``active`` before the collect, so that
+    no family hook counts or keeps it."""
+
+    __slots__ = ("picked", "logits", "bucket", "pos", "active")
+
+    def __init__(self, picked, logits, bucket: int, pos: np.ndarray,
+                 active: np.ndarray):
+        self.picked = picked
+        self.logits = logits
+        self.bucket = bucket
+        self.pos = pos
+        self.active = active
+
+
 class DecodeEngine:
     """The program table: builds and dispatches the
     per-bucket prefill/decode executables of one ``family`` over one
@@ -624,6 +651,8 @@ class DecodeEngine:
         self._multi_device = cache._sharding is not None
         # the logits' width, for rows that are not fetched
         self.vocab = int(self.params["lm_head_weight"].shape[0])
+        # the slots' tokens the last decode step picked, on the device
+        self._picked_last = None
         self.family.bind(self)
 
     def executable_bound(self) -> int:
@@ -684,31 +713,92 @@ class DecodeEngine:
         return int(np.asarray(picked)), \
             (np.asarray(out) if logits else None)
 
+    def _build_step(self, s_b: int):
+        """The family's decode program at ``s_b`` behind the merge of the
+        step's input tokens: the host's where it gave one, else what the
+        previous step picked for the slot. One program, named as the
+        family's is (``jit_fn``), the state donated; it returns the
+        slots' tokens apart too, for the next step to take."""
+        import jax
+        import jax.numpy as jnp
+        program = self.family.build_decode(s_b)
+        slots = self.cache.max_slots
+
+        def fn(params, state, last, tokens, pos, active):
+            tokens = jnp.where(tokens < 0, last, tokens)
+            picked, logits, state = program(params, state, tokens, pos,
+                                            active)
+            return picked, logits, state, picked[:slots]
+
+        return jax.jit(fn, donate_argnums=(1,))
+
+    def _no_tokens(self):
+        """The first step's stand-in for the previous step's tokens, placed
+        as a step's output will be: beside the state (committed to its
+        device, or replicated over its mesh), so that the second step
+        finds its program compiled for the same placement."""
+        import jax
+        import jax.numpy as jnp
+        zeros = jnp.zeros((self.cache.max_slots,), jnp.int32)
+        leaf = self.cache.state()[0]
+        if not leaf.committed:
+            return zeros
+        where = leaf.sharding
+        if hasattr(where, "mesh"):
+            from jax.sharding import NamedSharding, PartitionSpec
+            where = NamedSharding(where.mesh, PartitionSpec())
+        return jax.device_put(zeros, where)
+
+    def decode_dispatch(self, tokens: np.ndarray, pos: np.ndarray,
+                        active: np.ndarray, logits: bool = False
+                        ) -> DecodeFlight:
+        """Dispatch one decode step over the whole slot array and return
+        it in flight, without waiting for the device. ``tokens[s]`` is
+        slot ``s``'s input token, or ``-1`` for the one the previous step
+        picked for it (still on the device); ``pos[s]`` its write position
+        (current length); inactive slots pass 0/False. ``logits`` keeps
+        the ``(slots, V)`` logits for the collect (a resident sequence
+        samples)."""
+        pos = np.array(pos, np.int32)
+        active = np.array(active, bool)
+        needed = int(pos[active].max()) + 1 if active.any() else 1
+        s_b = self.seq_bucket(needed)
+        if self._picked_last is None:
+            self._picked_last = self._no_tokens()
+        with _profiler.span("gen_decode_dispatch", "serve"):
+            picked, out, new_state, self._picked_last = self._dispatch(
+                "decode", s_b, self._build_step,
+                (self.params, self.cache.state(), self._picked_last,
+                 np.asarray(tokens, np.int32), pos, active))
+        self.cache.set_state(new_state)
+        return DecodeFlight(picked, out if logits else None, s_b, pos,
+                            active)
+
+    def decode_collect(self, flight: DecodeFlight
+                       ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+        """The ``(slots,)`` greedy tokens of a step in flight, on host,
+        and its ``(slots, V)`` logits where they were asked for, else
+        None; the family counts the step from the flight's own positions
+        and active rows."""
+        # the fetch is the step's device fence: what the scheduler waits
+        # here is what is left of the step's device time and the copy of
+        # ``picked``, the slots' integers (what a family puts behind them,
+        # counts or what a step selected, rides in it: no second
+        # transfer). The logits stay on the device unless someone samples.
+        with _profiler.span("gen_logits_fetch", "serve"):
+            picked = np.asarray(flight.picked)
+            out = None if flight.logits is None \
+                else np.asarray(flight.logits)
+        if out is not None:
+            _profiler.incr_counter(self.name + "_decode_logits_fetched")
+        return self.family.step_picked(picked, flight.bucket, flight.pos,
+                                       flight.active), out
+
     def decode_step(self, tokens: np.ndarray, pos: np.ndarray,
                     active: np.ndarray, logits: bool = False
                     ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-        """One decode step over the whole slot array; returns the
-        ``(slots,)`` greedy tokens on host and, where ``logits`` asks for
-        them (a resident sequence samples), the ``(slots, V)`` logits,
-        else None. ``pos[s]`` is the write position (current length) of
-        slot ``s``; inactive slots pass 0/False."""
-        needed = int(pos[active].max()) + 1 if active.any() else 1
-        s_b = self.seq_bucket(needed)
-        with _profiler.span("gen_decode_dispatch", "serve"):
-            picked, out, new_state = self._dispatch(
-                "decode", s_b, self.family.build_decode,
-                (self.params, self.cache.state(),
-                 np.asarray(tokens, np.int32), np.asarray(pos, np.int32),
-                 np.asarray(active, bool)))
-        self.cache.set_state(new_state)
-        # the fetch is the step's device fence: what the scheduler waits
-        # here is the step's device time and the copy of ``picked``, the
-        # slots' integers (what a family puts behind them, counts or what
-        # a step selected, rides in it: no second transfer). The logits
-        # stay on the device unless someone samples.
-        with _profiler.span("gen_logits_fetch", "serve"):
-            picked = np.asarray(picked)
-            out = np.asarray(out) if logits else None
-        if logits:
-            _profiler.incr_counter(self.name + "_decode_logits_fetched")
-        return self.family.step_picked(picked, s_b, pos, active), out
+        """One decode step dispatched and collected: the ``(slots,)``
+        greedy tokens on host and, where ``logits`` asks for them, the
+        ``(slots, V)`` logits, else None."""
+        return self.decode_collect(
+            self.decode_dispatch(tokens, pos, active, logits))

@@ -818,7 +818,8 @@ class _GenRequest:
 
 class _ActiveSeq:
     __slots__ = ("slot", "handle", "pos", "generated", "max_new_tokens",
-                 "eos_id", "temperature", "rng", "token", "t_last", "flow")
+                 "eos_id", "temperature", "rng", "token", "t_last", "flow",
+                 "queued")
 
     def __init__(self, slot, handle, pos, max_new_tokens, eos_id,
                  temperature, seed, token, flow=None):
@@ -833,6 +834,16 @@ class _ActiveSeq:
         self.rng = np.random.default_rng(seed) if seed is not None else None
         self.token = token              # freshest sampled token
         self.t_last = monotonic()
+        # rows of steps dispatched and not yet collected: pos, generated
+        # and token count the delivered tokens only
+        self.queued = 0
+
+    def owed(self, max_seq: int) -> bool:
+        """Whether the sequence gets a row in the next step dispatched:
+        it is owed a token past those in flight, and has a position for
+        it."""
+        return (self.generated + self.queued < self.max_new_tokens
+                and self.pos + self.queued < max_seq)
 
 
 def _gen_loop(server_ref):
@@ -983,6 +994,9 @@ class GenerativeServer:
         self._cond = _lockcheck.Condition(self._lock)
         self._waiting: collections.deque = collections.deque()
         self._active: List[_ActiveSeq] = []
+        # the decode step dispatched and not yet collected, with the
+        # sequences it has rows for: (DecodeFlight, [_ActiveSeq])
+        self._inflight = None
         self._closed = False
         self._drain = True
         self._worker = threading.Thread(
@@ -1042,8 +1056,10 @@ class GenerativeServer:
     # --------------------------------------------------------- scheduler
     def _iteration(self):
         """One continuous-batching step: admit joins under the prefill
-        budget, one decode step over every resident sequence, evict the
-        finished. Runs only on the scheduler thread."""
+        budget, dispatch the next decode step over every resident
+        sequence still owed a token, collect the step before it, stream
+        its tokens and evict the finished. Runs only on the scheduler
+        thread."""
         with _profiler.span("gen_iteration", "serve",
                             active=len(self._active),
                             waiting=len(self._waiting)):
@@ -1061,8 +1077,6 @@ class GenerativeServer:
                 self._evict(seq, exc=None)
         with self._lock:
             active = list(self._active)
-        if not active:
-            return
         # capacity-exhausted sequences finish (truncated) BEFORE the
         # step: position max_seq does not exist in the cache
         for seq in active:
@@ -1070,9 +1084,7 @@ class GenerativeServer:
                 self._evict(seq, exc=None)
         with self._lock:
             active = list(self._active)
-        if not active:
-            return
-        if _faults.ARMED:
+        if active and _faults.ARMED:
             try:
                 _faults.fire("serve.decode", default_kind="raise")
             except _faults.FaultInjected as exc:
@@ -1086,36 +1098,119 @@ class GenerativeServer:
                     "decoding" % (victim.slot, exc)))
                 with self._lock:
                     active = list(self._active)
-                if not active:
-                    return
-        tokens = np.zeros((self.cache.max_slots,), np.int32)
-        pos = np.zeros((self.cache.max_slots,), np.int32)
-        mask = np.zeros((self.cache.max_slots,), bool)
-        for seq in active:
-            tokens[seq.slot] = seq.token
-            pos[seq.slot] = seq.pos
-            mask[seq.slot] = True
-        bucket = self.engine.seq_bucket(int(pos.max()) + 1) \
-            if _profiler.spans_enabled() else 0
-        # the token is chosen on the device; the logits leave it only
-        # if a resident sequence samples
+        if not active:
+            # a step in flight whose rows all ended is collected now:
+            # nothing is in flight while the server is empty
+            self._catch_up()
+            return
+        # Step N+1 goes out before step N's tokens come back: the host
+        # knows every position without them, and the device takes each
+        # slot's token from what step N picked. Not while a sequence
+        # samples: its next token is drawn on the host from step N's
+        # logits, so each step is collected as soon as it is dispatched.
+        # (Nothing is in flight then: a sampling sequence joined through
+        # a prefill, which collects the step in flight first.)
         sampling = any(seq.temperature > 0.0 for seq in active)
+        behind, self._inflight = self._inflight, None
+        rows = [seq for seq in active if seq.owed(self.cache.max_seq)]
+        # the bucket of the step dispatched, else of the one collected
+        bucket = 0
+        if _profiler.spans_enabled():
+            bucket = self.engine.seq_bucket(
+                max(seq.pos + seq.queued for seq in rows) + 1) if rows \
+                else behind[0].bucket
         try:
             with _profiler.span("gen_decode_step", "serve", bucket=bucket,
-                                active=len(active)):
-                picked, logits = self.engine.decode_step(
-                    tokens, pos, mask, logits=sampling)
+                                active=len(rows)):
+                flight = self._launch(rows, sampling, behind is not None) \
+                    if rows else None
+                if sampling:        # collected as soon as dispatched
+                    behind, flight = flight, None
+                got = self._fetch(behind) if behind is not None else None
         except Exception as exc:                            # noqa: BLE001
-            # a REAL decode failure cannot be attributed to one row —
-            # every resident sequence fails legibly and frees its pages
-            for seq in active:
-                self._evict(seq, exc=ServeError(
-                    "decode step failed for resident batch: %r" % (exc,)))
+            self._fail_batch(active, exc)
             return
+        self._inflight = flight
+        if got is not None:
+            self._deliver(*got)
+        with self._lock:
+            empty = not self._active
+        if empty:
+            self._catch_up()
+
+    def _launch(self, rows: List[_ActiveSeq], sampling: bool, ahead: bool):
+        """Dispatch one decode step with a row for each of ``rows``: the
+        host's token where it has the sequence's freshest, else the one
+        the step in flight picks on the device (``-1``). ``ahead``: a step
+        is in flight, and the one dispatched is counted as going ahead of
+        its collect."""
+        slots = self.cache.max_slots
+        tokens = np.zeros((slots,), np.int32)
+        pos = np.zeros((slots,), np.int32)
+        mask = np.zeros((slots,), bool)
+        for seq in rows:
+            tokens[seq.slot] = -1 if seq.queued else seq.token
+            pos[seq.slot] = seq.pos + seq.queued
+            mask[seq.slot] = True
+            seq.queued += 1
+        flight = self.engine.decode_dispatch(tokens, pos, mask,
+                                             logits=sampling)
+        if ahead:
+            _profiler.incr_counter(self.name + "_decode_steps_ahead")
+        return flight, rows
+
+    def _fetch(self, inflight):
+        """Collect a step in flight: its rows whose sequences ended while
+        it ran are cleared first, so that no family hook sees them.
+        Returns the rows still served, the step's tokens and its logits
+        (None unless someone samples)."""
+        flight, rows = inflight
+        with self._lock:
+            resident = set(self._active)
+        live = [seq for seq in rows if seq in resident]
+        for seq in rows:
+            seq.queued -= 1
+        for seq in set(rows).difference(live):
+            flight.active[seq.slot] = False
+        picked, logits = self.engine.decode_collect(flight)
+        _profiler.incr_counter(self.name + "_decode_steps")
+        return live, picked, logits
+
+    def _catch_up(self):
+        """Collect the step in flight, if there is one, and stream its
+        tokens: before a prefill, and when no sequence is left to be
+        served by the steps that would follow."""
+        inflight, self._inflight = self._inflight, None
+        if inflight is None:
+            return
+        flight, rows = inflight
+        try:
+            with _profiler.span("gen_decode_step", "serve",
+                                bucket=flight.bucket, active=len(rows)):
+                got = self._fetch(inflight)
+        except Exception as exc:                            # noqa: BLE001
+            with self._lock:
+                active = list(self._active)
+            self._fail_batch(active, exc)
+            return
+        self._deliver(*got)
+
+    def _fail_batch(self, active: List[_ActiveSeq], exc: BaseException):
+        """A REAL decode failure cannot be attributed to one row — every
+        resident sequence fails legibly and frees its pages, and nothing
+        stays in flight."""
+        self._inflight = None
+        for seq in active:
+            self._evict(seq, exc=ServeError(
+                "decode step failed for resident batch: %r" % (exc,)))
+
+    def _deliver(self, live: List[_ActiveSeq], picked, logits):
+        """Stream a collected step's tokens to its live rows' sequences and
+        evict those that finished."""
         now = monotonic()
         finished = []
-        with _profiler.span("gen_sample", "serve", active=len(active)):
-            for seq in active:
+        with _profiler.span("gen_sample", "serve", active=len(live)):
+            for seq in live:
                 # a greedy sequence's row stayed on the device: the
                 # sampler gets the device's choice standing for it
                 row = logits[seq.slot] if seq.temperature > 0.0 \
@@ -1132,10 +1227,9 @@ class GenerativeServer:
                 if seq.generated >= seq.max_new_tokens or \
                         (seq.eos_id is not None and tok == seq.eos_id):
                     finished.append(seq)
-            _profiler.incr_counter(self.name + "_tokens", len(active))
+            _profiler.incr_counter(self.name + "_tokens", len(live))
         for seq in finished:
             self._evict(seq, exc=None)
-        _profiler.incr_counter(self.name + "_decode_steps")
 
     def _admit(self):
         """Join waiting requests into free slots under the prefill token
@@ -1184,6 +1278,9 @@ class GenerativeServer:
                 _profiler.record_span("gen_queue_wait", req.t_queued,
                                       time.perf_counter(), "serve",
                                       flow=req.flow)
+            # a prefill runs behind a collected step, never beside one in
+            # flight: every execution is followed by its own tokens
+            self._catch_up()
             try:
                 with _profiler.span("gen_prefill", "serve", flow=req.flow,
                                     bucket=bucket,
@@ -1330,6 +1427,9 @@ class GenerativeServer:
             # sampled (temperature > 0); a greedy step fetches its tokens
             "decode_logits_fetched": _profiler.get_counter(
                 self.name + "_decode_logits_fetched"),
+            # and those dispatched while the step before was in flight
+            "decode_steps_ahead": _profiler.get_counter(
+                self.name + "_decode_steps_ahead"),
             "active_sequences": active,
             "waiting": waiting,
             "evicted": _profiler.get_counter(self.name + "_evicted"),

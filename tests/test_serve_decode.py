@@ -27,6 +27,7 @@ from mxnet_tpu import io as io_mod
 from mxnet_tpu.serve import (DeadlineExceeded, GenerativeServer, QueueFull,
                              ServeError, ServerClosed)
 
+import _serve_ahead
 import _serve_pick
 
 VOCAB, LAYERS, DMODEL, HEADS, SEQ = 128, 2, 32, 2, 16
@@ -373,6 +374,83 @@ def test_a_sampling_request_among_greedy_ones(module):
          ([9, 2, 6, 5], {"max_new_tokens": 8})])
 
 
+# ------------------------------------ step N+1 dispatched before N's tokens
+
+def _ahead_server(module, name, slots=None):
+    kw = {} if slots is None else {"max_sequences": slots}
+    return _server(module, name="ahead" + name, **kw)
+
+
+def test_the_engine_takes_the_last_steps_tokens_on_the_device(module):
+    from mxnet_tpu.serve.decode import extract_params
+    params = extract_params(module)
+    _serve_ahead.check_engine_takes_the_last_steps_tokens(
+        _dense_engine(params, 4, "ahserial")[0],
+        _dense_engine(params, 4, "ahahead")[0],
+        {0: [3, 11, 7], 2: [5, 2], 3: [9]})
+
+
+def test_the_sharded_cache_takes_the_last_steps_tokens(module4):
+    """The same under the tp=4 cache: the first step's stand-in for the
+    previous tokens is replicated over the mesh, as every later step's
+    tokens are, so no step compiles a program twice (the first two steps
+    share a bucket)."""
+    from mxnet_tpu._fused import CompileCache
+    from mxnet_tpu.parallel import SpecLayout
+    from mxnet_tpu.serve.decode import (DecodeEngine, DenseDecoder,
+                                        extract_params)
+    from mxnet_tpu.serve.kv_cache import KVCache
+    lo = SpecLayout(tp=4).sized(8)
+    params = extract_params(module4)
+
+    def engine(name, mesh=None):
+        family = DenseDecoder(params, HEADS_TP)
+        cache = KVCache(family.planes(SEQ, 4, False), max_slots=4,
+                        max_seq=SEQ, page=4, name=name, mesh=mesh,
+                        layout=lo if mesh is not None else None)
+        return DecodeEngine(family, cache, CompileCache(name), name=name)
+
+    _serve_ahead.check_engine_takes_the_last_steps_tokens(
+        engine("ahtpserial"), engine("ahtpahead", lo.mesh()),
+        {0: [3, 11], 1: [5]})
+
+
+def test_greedy_tokens_ahead_are_the_serial_paths(module):
+    from mxnet_tpu.serve.decode import extract_params
+    _serve_ahead.check_ahead_equals_serial(
+        _ahead_server(module, "equal"),
+        _dense_engine(extract_params(module), 4, "ahequalserial")[0],
+        [[3, 1, 4], [1, 5], [9, 2, 6, 5], [7]])
+
+
+def test_max_new_tokens_and_max_seq_end_without_an_extra_row(module):
+    _serve_ahead.check_ends_without_an_extra_row(
+        lambda name: _ahead_server(module, name), [2, 7, 1], SEQ)
+
+
+def test_eos_streams_nothing_past_it_with_a_step_in_flight(module):
+    _serve_ahead.check_eos_streams_nothing_past_it(
+        lambda name, slots=None: _ahead_server(module, name, slots),
+        [7, 3], [4, 4, 1], [6, 2, 9])
+
+
+def test_a_sampling_sequence_turns_the_steps_serial(module):
+    _serve_ahead.check_a_sampler_turns_the_steps_serial(
+        lambda name: _ahead_server(module, name), [5, 8], [2, 9, 4])
+
+
+def test_steps_ahead_are_counted_where_they_are_dispatched(module,
+                                                           monkeypatch):
+    _serve_ahead.check_the_counter_counts_steps_behind_another(
+        lambda name: _ahead_server(module, name),
+        [[3, 1, 4], [1, 5], [9, 2]], monkeypatch)
+
+
+def test_cancel_drills_and_close_leave_nothing_in_flight(module):
+    _serve_ahead.check_drills_leave_nothing_in_flight(
+        lambda name: _ahead_server(module, name), [[3, 1, 4], [1, 5]])
+
+
 # ------------------------------------------------------- scheduler behavior
 
 def test_streaming_iterator_and_callback(module):
@@ -409,8 +487,10 @@ def test_eos_stops_generation(module):
 def test_a_request_is_followed_by_its_flow_through_the_scheduler(module):
     """With spans live, a request's queue wait, prefill and eviction
     share its flow id, and the scheduler's spans nest: every decode step
-    and every sampling pass inside one iteration, the step's dispatch and
-    logits fetch inside the step."""
+    and every sampling pass inside one iteration (or inside its admission,
+    which collects the step in flight before a prefill), a step's dispatch
+    and the fetch of the step before it inside the step span, one fetch
+    and one sampling pass for every step dispatched."""
     got = []
     profiler.set_span_listener(lambda *a: got.append(a))
     srv = _server(module, name="flowgen", max_sequences=2)
@@ -445,17 +525,28 @@ def test_a_request_is_followed_by_its_flow_through_the_scheduler(module):
     # the third request waited for a slot: two are resident at most
     assert max(r.t_end - r.t_start for r in waits) > 0
     steps = by_name["gen_decode_step"]
-    assert len(steps) >= 3 and len(by_name["gen_sample"]) == len(steps)
-    for r in steps + by_name["gen_sample"] + by_name["gen_admit"]:
+    dispatched = srv.stats()["decode_steps"]
+    assert dispatched >= 3 and len(by_name["gen_sample"]) == dispatched
+    for r in by_name["gen_admit"]:
         parent = by_id[r.parent]
         assert parent.name == "gen_iteration" and parent.parent is None
+    for r in steps + by_name["gen_sample"]:
+        parent = by_id[r.parent]
+        assert parent.name in ("gen_iteration", "gen_admit")
         assert parent.t_start <= r.t_start and r.t_end <= parent.t_end
     for r in steps:
-        assert r.attrs["active"] in (1, 2) and r.attrs["bucket"] > 0
+        assert r.attrs["active"] in (0, 1, 2) and r.attrs["bucket"] > 0
     for name in ("gen_decode_dispatch", "gen_logits_fetch"):
-        assert len(by_name[name]) == len(steps)
+        assert len(by_name[name]) == dispatched
         assert all(by_id[r.parent].name == "gen_decode_step"
                    for r in by_name[name])
+    # no step span is empty, and none holds two of either
+    inside = {r.id: [] for r in steps}
+    for name in ("gen_decode_dispatch", "gen_logits_fetch"):
+        for r in by_name[name]:
+            inside[r.parent].append(name)
+    assert all(held and len(set(held)) == len(held)
+               for held in inside.values())
     assert all(by_id[r.parent].name in ("gen_iteration", "gen_admit")
                for r in by_name["gen_evict"])
     # one span a sampling pass, whatever the number of sequences

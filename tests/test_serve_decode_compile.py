@@ -548,6 +548,30 @@ def test_the_fourth_familys_decode_reads_the_full_caches_with_the_kernel(
     assert (logits.shape, str(logits.dtype)) == ((slots, 19072), "float32")
 
 
+def test_the_engines_step_takes_the_last_tokens_and_moves_no_plane(
+        one_chip, for_the_chip):
+    """The program the engine dispatches, the fourth family's decode step
+    behind the merge of the host's tokens with the previous step's: at the
+    cell's shapes the planes are still aliased whole beside next to no
+    temporaries, and the slots' tokens come out apart for the next step."""
+    import jax.numpy as jnp
+    from mxnet_tpu.serve.decode import DecodeEngine
+    slots, s_b = 36, 36864
+    family, params, state, sd = _window_moe_family(one_chip)
+    engine = types.SimpleNamespace(
+        family=family, cache=types.SimpleNamespace(max_slots=slots))
+    compiled = DecodeEngine._build_step(engine, s_b).lower(
+        params, state, sd(slots, dtype=jnp.int32),
+        sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.int32),
+        sd(slots, dtype=jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(state)
+    assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
+    picked, _logits, _state_out, tokens = compiled.out_info
+    assert (picked.shape, str(picked.dtype)) == ((slots + 2,), "int32")
+    assert (tokens.shape, str(tokens.dtype)) == ((slots,), "int32")
+
+
 @pytest.mark.parametrize("c_b,ctx_b", [(128, 4096), (1024, 36864)])
 def test_the_fourth_familys_prefill_chunk_fits_beside_the_cache(
         one_chip, for_the_chip, c_b, ctx_b):

@@ -12,6 +12,7 @@ import os
 import numpy as np
 import pytest
 
+import _serve_ahead
 import _serve_pick
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -138,11 +139,11 @@ def test_an_empty_slot_is_routed_nowhere_and_masked(tiny):
     assert mx.profiler.get_counter("mmempty_prefill_chunks") == 1
 
 
-def _server(tiny, name):
+def _server(tiny, name, slots=SLOTS):
     import mxnet_tpu as mx
     _cfg, arch, params = tiny
     return mx.serve.GenerativeServer(
-        params, arch=arch, max_sequences=SLOTS, seq_buckets=BUCKETS,
+        params, arch=arch, max_sequences=slots, seq_buckets=BUCKETS,
         prefill_chunk=CHUNK, page=PAGE, name=name)
 
 
@@ -214,6 +215,60 @@ def test_a_sampling_request_among_greedy_ones(tiny):
         [(p[0], {"max_new_tokens": 9}),
          (p[1], {"max_new_tokens": 5, "temperature": 0.8, "seed": 5}),
          (p[2], {"max_new_tokens": 8})])
+
+
+# ------------------------------------ step N+1 dispatched before N's tokens
+
+def _ahead_server(tiny, name, slots=None):
+    return _server(tiny, "mmahead" + name, slots or SLOTS)
+
+
+def _prompts(tiny, seed, lengths):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, tiny[0]["vocab_held"], n) for n in lengths]
+
+
+def test_the_engine_takes_the_last_steps_tokens_on_the_device(tiny):
+    p = _prompts(tiny, 11, (21, 5, 40))
+    _serve_ahead.check_engine_takes_the_last_steps_tokens(
+        _engine(tiny, "mmahserial"), _engine(tiny, "mmahahead"),
+        {0: p[0], 1: p[1], 3: p[2]})
+
+
+def test_greedy_tokens_ahead_are_the_serial_paths(tiny):
+    _serve_ahead.check_ahead_equals_serial(
+        _ahead_server(tiny, "equal"), _engine(tiny, "mmahequalserial"),
+        _prompts(tiny, 12, (5, 40, 23, 9)))
+
+
+def test_max_new_tokens_and_max_seq_end_without_an_extra_row(tiny):
+    _serve_ahead.check_ends_without_an_extra_row(
+        lambda name: _ahead_server(tiny, name), _prompts(tiny, 13, (7,))[0],
+        MAX_SEQ)
+
+
+def test_eos_streams_nothing_past_it_with_a_step_in_flight(tiny):
+    _serve_ahead.check_eos_streams_nothing_past_it(
+        lambda name, slots=None: _ahead_server(tiny, name, slots),
+        *_prompts(tiny, 14, (6, 9, 12)))
+
+
+def test_a_sampling_sequence_turns_the_steps_serial(tiny):
+    _serve_ahead.check_a_sampler_turns_the_steps_serial(
+        lambda name: _ahead_server(tiny, name),
+        *_prompts(tiny, 15, (8, 5)))
+
+
+def test_steps_ahead_are_counted_where_they_are_dispatched(tiny,
+                                                           monkeypatch):
+    _serve_ahead.check_the_counter_counts_steps_behind_another(
+        lambda name: _ahead_server(tiny, name),
+        _prompts(tiny, 16, (5, 19, 7)), monkeypatch)
+
+
+def test_cancel_drills_and_close_leave_nothing_in_flight(tiny):
+    _serve_ahead.check_drills_leave_nothing_in_flight(
+        lambda name: _ahead_server(tiny, name), _prompts(tiny, 17, (6, 11)))
 
 
 def test_expert_counters_read_what_they_read_in_the_logits_row(tiny):

@@ -14,6 +14,8 @@ import os
 import numpy as np
 import pytest
 
+import _serve_ahead
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MAX_SEQ, SLOTS, CHUNK, PAGE = 288, 3, 16, 4
 BUCKETS = [32, 96, 288]
@@ -458,3 +460,66 @@ def test_served_through_the_generative_server(tiny):
         seq.append(tok)
     with pytest.raises(ValueError, match="serves no model_type"):
         mx.serve.GenerativeServer(params, arch=dict(arch, model_type="x"))
+
+
+# ------------------------------------ step N+1 dispatched before N's tokens
+
+def _ahead_server(tiny, name, slots=None):
+    import mxnet_tpu as mx
+    _cfg, arch, params = tiny
+    return mx.serve.GenerativeServer(
+        params, arch=dict(arch), max_sequences=slots or SLOTS,
+        seq_buckets=BUCKETS, prefill_chunk=CHUNK, page=PAGE,
+        name="slahead" + name)
+
+
+def _prompts(tiny, seed, lengths):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, tiny[0]["vocab_held"], n) for n in lengths]
+
+def _followed(srv, prompt):
+    return srv.engine.family.followed(prompt)
+
+
+
+def test_the_engine_takes_the_last_steps_tokens_on_the_device(tiny):
+    p = _prompts(tiny, 11, (21, 5))
+    _serve_ahead.check_engine_takes_the_last_steps_tokens(
+        _engine(tiny, "slaheadserial"), _engine(tiny, "slaheadahead"),
+        {0: p[0], 2: p[1]})
+
+
+def test_greedy_tokens_ahead_are_the_serial_paths(tiny):
+    _serve_ahead.check_ahead_equals_serial(
+        _ahead_server(tiny, "equal"), _engine(tiny, "slaheadequalserial"),
+        _prompts(tiny, 12, (5, 40, 23)))
+
+
+def test_max_new_tokens_and_max_seq_end_without_an_extra_row(tiny):
+    _serve_ahead.check_ends_without_an_extra_row(
+        lambda name: _ahead_server(tiny, name), _prompts(tiny, 13, (7,))[0],
+        MAX_SEQ)
+
+
+def test_eos_streams_nothing_past_it_with_a_step_in_flight(tiny):
+    _serve_ahead.check_eos_streams_nothing_past_it(
+        lambda name, slots=None: _ahead_server(tiny, name, slots),
+        *_prompts(tiny, 14, (6, 9, 12)), followed=_followed)
+
+
+def test_a_sampling_sequence_turns_the_steps_serial(tiny):
+    _serve_ahead.check_a_sampler_turns_the_steps_serial(
+        lambda name: _ahead_server(tiny, name),
+        *_prompts(tiny, 15, (8, 5)))
+
+
+def test_steps_ahead_are_counted_where_they_are_dispatched(tiny,
+                                                           monkeypatch):
+    _serve_ahead.check_the_counter_counts_steps_behind_another(
+        lambda name: _ahead_server(tiny, name),
+        _prompts(tiny, 16, (5, 19, 7)), monkeypatch)
+
+
+def test_cancel_drills_and_close_leave_nothing_in_flight(tiny):
+    _serve_ahead.check_drills_leave_nothing_in_flight(
+        lambda name: _ahead_server(tiny, name), _prompts(tiny, 17, (6, 11)))
