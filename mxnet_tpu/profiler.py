@@ -68,7 +68,8 @@ __all__ = [
     "incr_counter", "get_counter", "counters", "reset_counters",
     "counter_delta",
     "set_gauge", "get_gauge", "gauges", "reset_gauges",
-    "span", "record_span", "spans_enabled", "new_flow", "spans",
+    "span", "record_span", "spans_enabled", "xla_profiling", "new_flow",
+    "spans",
     "SpanRecord",
     "register_thread_lane", "set_span_listener", "blackbox",
     "Histogram", "histogram", "observe", "histograms", "reset_histograms",
@@ -334,6 +335,18 @@ def _xla_profiling() -> bool:
             return False
         st = _xla_profile_state = getattr(mod, "_profile_state", False)
     return getattr(st, "profile_session", None) is not None
+
+
+def xla_profiling() -> bool:
+    """Fast, lock-free: True while an XLA profile is starting, running or
+    being written (jax holds its profile lock to start and to export one,
+    and keeps its session from start to the end of the export). Code that
+    times itself on the host's clock leaves such stretches out: the
+    profile's own tracers slow the host there."""
+    if _xla_profiling():
+        return True
+    lock = getattr(_xla_profile_state or None, "lock", None)
+    return lock is not None and lock.locked()
 
 
 def spans_enabled() -> bool:

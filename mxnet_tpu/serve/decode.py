@@ -82,6 +82,7 @@ broadcast multiply per read — tolerance documented in the same test.
 """
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -603,7 +604,7 @@ class DecodeFlight:
     the step ran) is cleared from ``active`` before the collect, so that
     no family hook counts or keeps it."""
 
-    __slots__ = ("picked", "logits", "bucket", "pos", "active")
+    __slots__ = ("picked", "logits", "bucket", "pos", "active", "waited")
 
     def __init__(self, picked, logits, bucket: int, pos: np.ndarray,
                  active: np.ndarray):
@@ -612,6 +613,8 @@ class DecodeFlight:
         self.bucket = bucket
         self.pos = pos
         self.active = active
+        # seconds the collect blocked on the device's result
+        self.waited = 0.0
 
 
 class DecodeEngine:
@@ -653,6 +656,8 @@ class DecodeEngine:
         self.vocab = int(self.params["lm_head_weight"].shape[0])
         # the slots' tokens the last decode step picked, on the device
         self._picked_last = None
+        # programs built by a dispatch: one that changes this compiled
+        self.programs_built = 0
         self.family.bind(self)
 
     def executable_bound(self) -> int:
@@ -684,6 +689,7 @@ class DecodeEngine:
         fresh = prog is None
         if fresh:
             prog = builder(bucket)
+            self.programs_built += 1
         with _obs_compiles.scope(self.name, sig):
             out = prog(*args)
         if fresh:
@@ -786,13 +792,21 @@ class DecodeEngine:
         # counts or what a step selected, rides in it: no second
         # transfer). The logits stay on the device unless someone samples.
         with _profiler.span("gen_logits_fetch", "serve"):
+            t0 = time.perf_counter()
             picked = np.asarray(flight.picked)
             out = None if flight.logits is None \
                 else np.asarray(flight.logits)
+            flight.waited = time.perf_counter() - t0
         if out is not None:
             _profiler.incr_counter(self.name + "_decode_logits_fetched")
         return self.family.step_picked(picked, flight.bucket, flight.pos,
                                        flight.active), out
+
+    def decode_ready(self, flight: DecodeFlight) -> bool:
+        """Whether a step in flight has finished on the device, asked
+        without waiting or fetching: a step found ready when the next is
+        dispatched left the chip idle."""
+        return flight.picked.is_ready()
 
     def decode_step(self, tokens: np.ndarray, pos: np.ndarray,
                     active: np.ndarray, logits: bool = False

@@ -997,6 +997,20 @@ class GenerativeServer:
         # the decode step dispatched and not yet collected, with the
         # sequences it has rows for: (DecodeFlight, [_ActiveSeq])
         self._inflight = None
+        # the scheduler's own clock, always on (``_clock``): the start of
+        # the last decode dispatch where a counted period may run from it,
+        # whether an XLA profile ran at that dispatch, and an admission's
+        # stall open since the collect before its prefill; what the collect
+        # since the last dispatch waited
+        self._period_from = None
+        self._profiled = False
+        self._waited = 0.0
+        self._stall = None
+        for what in ("decode_periods", "decode_period_us",
+                     "decode_collect_wait_us", "decode_dispatch_late",
+                     "admit_stalls", "admit_stall_us"):
+            # present from the start: none counted reads 0, not nothing
+            _profiler.incr_counter("%s_%s" % (name, what), 0)
         self._closed = False
         self._drain = True
         self._worker = threading.Thread(
@@ -1069,6 +1083,8 @@ class GenerativeServer:
         from .. import faults as _faults
         with _profiler.span("gen_admit", "serve"):
             self._admit()
+        # a stall ends at the dispatch that follows it, or not at all
+        stall, self._stall = self._stall, None
         with self._lock:
             active = list(self._active)
         # cancelled handles evict at step granularity
@@ -1122,7 +1138,7 @@ class GenerativeServer:
         try:
             with _profiler.span("gen_decode_step", "serve", bucket=bucket,
                                 active=len(rows)):
-                flight = self._launch(rows, sampling, behind is not None) \
+                flight = self._launch(rows, sampling, behind, stall) \
                     if rows else None
                 if sampling:        # collected as soon as dispatched
                     behind, flight = flight, None
@@ -1138,12 +1154,13 @@ class GenerativeServer:
         if empty:
             self._catch_up()
 
-    def _launch(self, rows: List[_ActiveSeq], sampling: bool, ahead: bool):
+    def _launch(self, rows: List[_ActiveSeq], sampling: bool, behind, stall):
         """Dispatch one decode step with a row for each of ``rows``: the
         host's token where it has the sequence's freshest, else the one
-        the step in flight picks on the device (``-1``). ``ahead``: a step
-        is in flight, and the one dispatched is counted as going ahead of
-        its collect."""
+        the step in flight picks on the device (``-1``). ``behind``: the
+        step in flight, if there is one; the step dispatched is then
+        counted as going ahead of its collect. ``stall``: an admission's
+        stall that this dispatch ends (``_clock``)."""
         slots = self.cache.max_slots
         tokens = np.zeros((slots,), np.int32)
         pos = np.zeros((slots,), np.int32)
@@ -1153,11 +1170,55 @@ class GenerativeServer:
             pos[seq.slot] = seq.pos + seq.queued
             mask[seq.slot] = True
             seq.queued += 1
-        flight = self.engine.decode_dispatch(tokens, pos, mask,
-                                             logits=sampling)
-        if ahead:
+        engine = self.engine
+        late = behind is not None and engine.decode_ready(behind[0])
+        built = engine.programs_built
+        profiled = _profiler.xla_profiling()
+        t0 = time.perf_counter()
+        flight = engine.decode_dispatch(tokens, pos, mask, logits=sampling)
+        self._clock(t0, behind is not None, late, stall, profiled,
+                    engine.programs_built != built)
+        if behind is not None:
             _profiler.incr_counter(self.name + "_decode_steps_ahead")
         return flight, rows
+
+    def _clock(self, t0, ahead, late, stall, profiled, compiled):
+        """Time the scheduler on the host's clock at a decode dispatch that
+        started at ``t0``, in integer microseconds, always on.
+
+        A period runs from the start of one decode dispatch to the start
+        of the next. It is counted (``<name>_decode_periods``,
+        ``_decode_period_us``) only if the later dispatch went ahead (the
+        step before was in flight: no catch-up or prefill lies between),
+        neither dispatch compiled, and no XLA profile was starting,
+        running or being written at either end or at the dispatch before
+        (the first period after a profile is dropped too). Within it
+        ``_decode_collect_wait_us`` is what the collect of the step before
+        blocked on the device, and ``_decode_dispatch_late`` counts the
+        periods whose closing dispatch found the step in flight done: the
+        chip waited for the host. A ``stall`` (``_admit_stalls``,
+        ``_admit_stall_us``) runs from the collect before an admission's
+        prefill to this dispatch, under the same conditions."""
+        name = self.name
+        quiet = not profiled and not compiled
+        opened, self._period_from = self._period_from, \
+            t0 if quiet and not self._profiled else None
+        waited, self._waited = self._waited, 0.0
+        self._profiled = profiled
+        if not quiet:
+            return
+        if ahead and opened is not None:
+            _profiler.incr_counter(name + "_decode_periods")
+            _profiler.incr_counter(name + "_decode_period_us",
+                                   round((t0 - opened) * 1e6))
+            _profiler.incr_counter(name + "_decode_collect_wait_us",
+                                   round(waited * 1e6))
+            if late:
+                _profiler.incr_counter(name + "_decode_dispatch_late")
+        if stall is not None and stall[1] == self.engine.programs_built:
+            _profiler.incr_counter(name + "_admit_stalls")
+            _profiler.incr_counter(name + "_admit_stall_us",
+                                   round((t0 - stall[0]) * 1e6))
 
     def _fetch(self, inflight):
         """Collect a step in flight: its rows whose sequences ended while
@@ -1173,6 +1234,7 @@ class GenerativeServer:
         for seq in set(rows).difference(live):
             flight.active[seq.slot] = False
         picked, logits = self.engine.decode_collect(flight)
+        self._waited = flight.waited
         _profiler.incr_counter(self.name + "_decode_steps")
         return live, picked, logits
 
@@ -1279,7 +1341,12 @@ class GenerativeServer:
                                       time.perf_counter(), "serve",
                                       flow=req.flow)
             # a prefill runs behind a collected step, never beside one in
-            # flight: every execution is followed by its own tokens
+            # flight: every execution is followed by its own tokens. The
+            # gap this drains the pipeline for is a stall (``_clock``).
+            if self._inflight is not None:
+                self._stall = None if self._profiled or \
+                    _profiler.xla_profiling() else \
+                    (time.perf_counter(), self.engine.programs_built)
             self._catch_up()
             try:
                 with _profiler.span("gen_prefill", "serve", flow=req.flow,

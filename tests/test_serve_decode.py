@@ -451,6 +451,260 @@ def test_cancel_drills_and_close_leave_nothing_in_flight(module):
         lambda name: _ahead_server(module, name), [[3, 1, 4], [1, 5]])
 
 
+# ------------------------------------------------ the scheduler's own clock
+
+CLOCK = ("decode_periods", "decode_period_us", "decode_collect_wait_us",
+         "decode_dispatch_late", "admit_stalls", "admit_stall_us")
+PAUSE = 0.2         # a prefill made this slow stands out of any step
+
+
+def _clock(srv):
+    c = profiler.counters()
+    return {k: c.get("%s_%s" % (srv.name, k), 0) for k in CLOCK + (
+        "decode_steps_ahead",)}
+
+
+def _since(srv, before):
+    now = _clock(srv)
+    return {k: now[k] - before[k] for k in now}
+
+
+def _warm_server(module, name):
+    """A server whose every prompt and decode bucket is compiled: a lone
+    prompt of one token decoded to the last position."""
+    srv = _ahead_server(module, name)
+    srv.submit_generate([1], max_new_tokens=SEQ - 1).result(timeout=600)
+    return srv
+
+
+class _Dispatches:
+    """The server's decode dispatches as the test sees them: the reading
+    of the host's clock the scheduler took last before each (its own
+    start of the dispatch), whether a step was in flight then, and its
+    bucket; and what each collect waited, by the dispatch before it."""
+
+    def __init__(self, srv, monkeypatch):
+        from mxnet_tpu.serve import server as server_mod
+        self.at, self.ahead, self.buckets, self.waits = [], [], [], []
+        self.out = 0
+        eng, last = srv.engine, [None]
+        dispatch, collect = eng.decode_dispatch, eng.decode_collect
+
+        class SchedulerTime:
+            def __getattr__(self, name):
+                return getattr(time, name)
+
+            def perf_counter(self):
+                t = time.perf_counter()
+                if threading.current_thread() is srv._worker:
+                    last[0] = t
+                return t
+
+        def counting_dispatch(*a, **kw):
+            self.at.append(last[0])
+            self.ahead.append(self.out > 0)
+            self.out += 1
+            flight = dispatch(*a, **kw)
+            self.buckets.append(flight.bucket)
+            return flight
+
+        def counting_collect(flight):
+            self.out -= 1
+            got = collect(flight)
+            self.waits.append((len(self.at) - 1, flight.waited))
+            return got
+
+        monkeypatch.setattr(server_mod, "time", SchedulerTime())
+        monkeypatch.setattr(eng, "decode_dispatch", counting_dispatch)
+        monkeypatch.setattr(eng, "decode_collect", counting_collect)
+
+    def periods(self, compiled=()):
+        """The gaps between two dispatches whose later one went ahead,
+        neither of them at an index in ``compiled``."""
+        return [b - a for i, (a, b) in enumerate(zip(self.at, self.at[1:]))
+                if self.counted(i, compiled)]
+
+    def counted(self, i, compiled=()):
+        """Whether the gap from dispatch ``i`` to the next is a period."""
+        return i + 1 < len(self.at) and self.ahead[i + 1] \
+            and i not in compiled and i + 1 not in compiled
+
+    def waited(self):
+        """What the collects inside the periods waited."""
+        return sum(w for i, w in self.waits if self.counted(i))
+
+
+def _slow_prefill(eng, monkeypatch):
+    prefill = eng.prefill
+
+    def slow(*a, **kw):
+        time.sleep(PAUSE)
+        return prefill(*a, **kw)
+
+    monkeypatch.setattr(eng, "prefill", slow)
+
+
+def test_the_clock_counts_a_period_for_each_step_dispatched_ahead(
+        module, monkeypatch):
+    """A period is the gap from one dispatch to the next that goes ahead
+    of the step before: one for each step ahead, summed to the
+    microsecond, and none across an admission, whose prefill lies in a
+    stall instead."""
+    srv = _warm_server(module, "clk_periods")
+    seen = _Dispatches(srv, monkeypatch)
+    _slow_prefill(srv.engine, monkeypatch)
+    before = _clock(srv)
+    try:
+        for h in _serve_ahead.stagger(srv, [[3, 1, 4], [1, 5], [9, 2]],
+                                      12):
+            h.result(timeout=600)
+    finally:
+        srv.close()
+    got = _since(srv, before)
+    want = seen.periods()
+    assert got["decode_periods"] == len(want) == got["decode_steps_ahead"]
+    assert len(want) >= 10 and max(want) < PAUSE
+    # each period rounded to the microsecond, and what each one's collect
+    # waited, none where a period holds no collect
+    assert abs(got["decode_period_us"] - 1e6 * sum(want)) <= len(want)
+    assert abs(got["decode_collect_wait_us"] - 1e6 * seen.waited()) \
+        <= len(want)
+    assert 0 < got["decode_collect_wait_us"] < got["decode_period_us"]
+    assert 0 <= got["decode_dispatch_late"] <= got["decode_periods"]
+    # the first prompt found nothing in flight; each later one a step
+    assert got["admit_stalls"] <= 2
+    assert got["admit_stall_us"] >= got["admit_stalls"] * PAUSE * 1e6
+
+
+def test_a_compile_breaks_the_chain(module, monkeypatch):
+    """A dispatch that builds its program ends no counted period and
+    opens none: a fresh server over a lone prompt crosses every decode
+    bucket, and the periods counted are the steps ahead less those."""
+    srv = _ahead_server(module, "clk_compile")
+    seen = _Dispatches(srv, monkeypatch)
+    before = _clock(srv)
+    try:
+        srv.submit_generate([1], max_new_tokens=SEQ - 1).result(timeout=600)
+    finally:
+        srv.close()
+    got = _since(srv, before)
+    compiled = {i for i, b in enumerate(seen.buckets)
+                if b not in seen.buckets[:i]}
+    assert len(compiled) == len(srv.engine.seq_buckets) > 1
+    assert got["decode_steps_ahead"] == SEQ - 3
+    assert got["decode_periods"] == len(seen.periods(compiled)) \
+        < got["decode_steps_ahead"]
+
+
+def test_a_step_found_done_at_dispatch_is_late(module, monkeypatch):
+    """The engine says whether a step in flight is done without waiting
+    (a collected one is); the scheduler counts a period late when its
+    closing dispatch found the step before done, and asking changes no
+    token."""
+    from mxnet_tpu.serve.decode import extract_params
+    eng = _dense_engine(extract_params(module), 2, "clk_ready")[0]
+    slot_tok = eng.prefill(np.asarray([3, 1]), 0)[0]
+    flight = eng.decode_dispatch(np.asarray([slot_tok, 0], np.int32),
+                                 np.asarray([2, 0], np.int32),
+                                 np.asarray([True, False]))
+    eng.decode_collect(flight)
+    assert eng.decode_ready(flight) and flight.waited >= 0
+    prompts, got = [[3, 1, 4], [1, 5], [9, 2, 6, 5]], {}
+    for ready in (None, True, False):
+        srv = _warm_server(module, "clk_late%s" % ready)
+        if ready is not None:
+            monkeypatch.setattr(srv.engine, "decode_ready",
+                                lambda flight, r=ready: r)
+        before = _clock(srv)
+        try:
+            tokens = [h.result(timeout=600) for h in
+                      _serve_ahead.stagger(srv, prompts, 10)]
+        finally:
+            srv.close()
+        got[ready] = (tokens, _since(srv, before))
+    assert got[True][0] == got[False][0] == got[None][0]
+    late = {r: (c["decode_dispatch_late"], c["decode_periods"])
+            for r, (_t, c) in got.items()}
+    assert late[True][0] == late[True][1] > 0
+    assert late[False][0] == 0 < late[False][1]
+    assert 0 <= late[None][0] <= late[None][1]
+
+
+def test_an_admission_behind_a_step_in_flight_is_one_stall(module,
+                                                           monkeypatch):
+    """Three prompts that join in one gap drain the step in flight once:
+    one stall, from the collect before the first prefill to the next
+    dispatch, holding all three prefills. The first prompt, admitted
+    into an empty server, stalls nothing."""
+    srv = _warm_server(module, "clk_stall")
+    eng = srv.engine
+    _slow_prefill(eng, monkeypatch)
+    hold, held, gate = [False], threading.Event(), threading.Event()
+    collect = eng.decode_collect
+
+    def gated(flight):
+        if hold[0]:
+            hold[0] = False
+            held.set()
+            gate.wait(60)
+        return collect(flight)
+
+    monkeypatch.setattr(eng, "decode_collect", gated)
+    before = _clock(srv)
+    try:
+        first = srv.submit_generate([3, 1, 4], max_new_tokens=12)
+        while len(first.tokens_so_far()) < 2:
+            time.sleep(0.001)
+        hold[0] = True
+        assert held.wait(60)
+        joins = [srv.submit_generate(p, max_new_tokens=4)
+                 for p in ([1, 5], [9, 2], [7])]
+        gate.set()
+        for h in [first] + joins:
+            h.result(timeout=600)
+    finally:
+        gate.set()
+        srv.close()
+    got = _since(srv, before)
+    assert got["admit_stalls"] == 1
+    assert got["admit_stall_us"] >= 3 * PAUSE * 1e6
+
+
+def test_no_period_is_counted_while_an_xla_profile_runs(module, tmp_path):
+    """Nothing is counted while ``jax.profiler`` runs, and the first
+    period after it is dropped too; ``xla_profiling`` is true while jax
+    holds its profile lock (a profile starting or being written)."""
+    import jax
+    from jax._src import profiler as jax_profiler
+    srv = _warm_server(module, "clk_profile")
+    before = _clock(srv)
+    try:
+        assert not profiler.xla_profiling()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert profiler.xla_profiling()
+            for h in _serve_ahead.stagger(srv, [[3, 1, 4], [1, 5]], 8):
+                h.result(timeout=600)
+        finally:
+            jax.profiler.stop_trace()
+        assert not profiler.xla_profiling()
+        with jax_profiler._profile_state.lock:
+            assert profiler.xla_profiling()
+        during = _since(srv, before)
+        assert during["decode_steps_ahead"] > 0
+        assert all(during[k] == 0 for k in CLOCK)
+        lone = []
+        for _ in range(2):
+            before = _clock(srv)
+            srv.submit_generate([2, 7], max_new_tokens=8).result(
+                timeout=600)
+            lone.append(_since(srv, before))
+    finally:
+        srv.close()
+    assert [c["decode_steps_ahead"] for c in lone] == [6, 6]
+    assert [c["decode_periods"] for c in lone] == [5, 6]
+
+
 # ------------------------------------------------------- scheduler behavior
 
 def test_streaming_iterator_and_callback(module):
